@@ -50,9 +50,9 @@ def legacy_generate(params, cfg, prompts, *, max_new_tokens=12,
     from repro.serving.sampler import _pad_caches
     prompts = jnp.asarray(prompts, jnp.int32)
     b, lp = prompts.shape
-    logits, caches = M.prefill(params, cfg, {"tokens": prompts})
+    last, caches = M.prefill(params, cfg, {"tokens": prompts})
     caches = _pad_caches(caches, lp + max_new_tokens, lp)
-    last = logits[:, -1].astype(jnp.float32)
+    last = last.astype(jnp.float32)
     outs, step_logits = [], []
     done = jnp.zeros((b,), bool)
     key = rng if rng is not None else jax.random.PRNGKey(0)

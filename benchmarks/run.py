@@ -13,6 +13,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from benchmarks import (
     bench_ablation, bench_adaptation, bench_budget, bench_kernels,
     bench_pareto, bench_portfolio, bench_predictive, bench_roofline,
@@ -44,6 +46,7 @@ def main(argv=None) -> int:
                     help="comma-separated bench names")
     args = ap.parse_args(argv)
     names = list(BENCHES) if not args.only else args.only.split(",")
+    enable_compile_cache()
 
     bundle = None
     if any(n in NEEDS_BUNDLE for n in names):

@@ -209,9 +209,9 @@ def _register_defaults() -> None:
         npg = _ceil_div(L, page_size)
         ids = jax.ShapeDtypeStruct((B * npg,), jnp.int32)
         _, pcaches = jax.eval_shape(
-            lambda p, t, i: sampler._paged_prefill(
-                p, cfg, t, n_pages_total, page_size, i),
-            s["params"], s["tokens"], ids)
+            lambda p, t, i, n: sampler._paged_prefill(
+                p, cfg, t, n_pages_total, page_size, i, n),
+            s["params"], s["tokens"], ids, s["pos"])
         spec = PagedSpec(page_size=page_size, kv_cap=kv_cap, kernel=kernel)
         table = jax.ShapeDtypeStruct((B, width), jnp.int32)
         return s, pcaches, spec, table, ids

@@ -10,11 +10,11 @@ path relies on (``kernels/decode_attention.py``):
   ``PrefetchScalarGridSpec`` (the page table / lengths the paged kernel
   prefetches);
 - index maps are pure address arithmetic: no calls inside the lambda;
-- rank-1 block shapes (per-row scalars like lengths) carry an explicit
-  ``memory_space`` annotation (SMEM) — the default vector-memory layout
-  traps on TPU for sub-tile scalars;
+- no rank-1 block shapes: per-row scalars (lengths, page tables) ride
+  in SMEM through ``PrefetchScalarGridSpec`` — the TPU lowering refuses
+  a sub-tile rank-1 block whatever its ``memory_space``;
 - ``interpret=True`` is never hardcoded (pass it through so TPU runs
-  compile; see the ``_interpret()`` backend probe in ``kernels/ops.py``).
+  compile; see ``kernels/backend.resolve_interpret``).
 
 Grid/block divisibility and index-map *bounds* against ``PagedSpec``
 depend on runtime shapes, so they are enforced by layer 2: the jaxpr pass
@@ -67,8 +67,8 @@ def _block_specs(node: Optional[ast.AST]) -> List[ast.Call]:
 class PallasContractRule(Rule):
     id = "pallas-kernel-contract"
     description = ("pallas_call grid/BlockSpec contract: index-map arity, "
-                   "pure index maps, SMEM annotations on rank-1 blocks, "
-                   "no hardcoded interpret mode")
+                   "pure index maps, no rank-1 blocks (scalar prefetch "
+                   "instead), no hardcoded interpret mode")
 
     def check(self, ctx: ModuleContext) -> Iterator[Finding]:
         for node in ast.walk(ctx.tree):
@@ -131,13 +131,12 @@ class PallasContractRule(Rule):
                         "call inside a BlockSpec index map — index maps "
                         "must be pure address arithmetic")
                     break
-        rank = _tuple_len(shape)
-        if rank == 1 and _kw(spec, "memory_space") is None:
+        if _tuple_len(shape) == 1:
             yield ctx.finding(
                 self.id, spec,
-                "rank-1 BlockSpec without memory_space= — per-row scalars "
-                "belong in SMEM (pltpu.SMEM), the default vector layout "
-                "traps on sub-tile blocks")
+                "rank-1 BlockSpec — per-row scalars belong in SMEM via "
+                "PrefetchScalarGridSpec(num_scalar_prefetch=...); the TPU "
+                "lowering refuses sub-tile rank-1 blocks")
 
     triggers = (
         """\
@@ -167,18 +166,20 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-def good(x, kernel, interpret):
-    return pl.pallas_call(
-        kernel,
+def good(x, lens, kernel, interpret):
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(4, 2, 8),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, i: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, 8, 16), lambda b, h, i: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, 8, 16), lambda b, h, i, n: (b, h, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 16), lambda b, h, i: (b, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, 16), lambda b, h, i, n: (b, h, 0)),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
-    )(x)
+    )(lens, x)
 """,
     )

@@ -79,7 +79,7 @@ def _jaxpr_self_test() -> List[str]:
         return y.astype(jnp.float64)
 
     # enable_x64 scoped to the trace so the f64 survives canonicalisation
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         bad = jax.make_jaxpr(poisoned)(x)
     msgs = " ".join(f.message for f in check_closed_jaxpr("poisoned", bad))
     if "pure_callback" not in msgs:

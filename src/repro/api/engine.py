@@ -24,6 +24,7 @@ query set costs O(Q) new estimator calls instead of an O(Q x M) recompute.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from collections import deque
 from typing import (
@@ -49,6 +50,8 @@ from repro.data.worldsim import PoolModel, World
 if TYPE_CHECKING:
     from repro.serving.feedback import FeedbackMonitor
     from repro.serving.scheduler import MicrobatchScheduler
+
+log = logging.getLogger(__name__)
 
 FALLBACK_LEN_HAT = 512.0    # tokens charged when the estimate is malformed
 
@@ -201,9 +204,20 @@ class _StreamControl:
         the scheduler, quarantining rows past their retry budget.  Keys no
         longer unresolved (already answered degraded — e.g. a deadline
         expiry racing the in-flight decode) are dropped: their requests
-        were served exactly once already."""
+        were served exactly once already.
+
+        ``exc`` not raised by the stream's ``FaultInjector`` is a real
+        failure: it is counted in ``unexpected_failures``, and the first
+        one is kept in ``first_failure`` and logged with its traceback."""
+        from repro.serving.faults import InjectedFault
         stats = self.sched.stats
         stats.retries += 1
+        if exc is not None and not isinstance(exc, InjectedFault):
+            stats.unexpected_failures += 1
+            if not stats.first_failure:
+                stats.first_failure = f"{type(exc).__name__}: {exc}"
+                log.error("serve-path failure not injected by a FaultPlan "
+                          "(rows retried, then degraded)", exc_info=exc)
         worst = 0
         for key, prompt in rows:
             if key not in self.unresolved:

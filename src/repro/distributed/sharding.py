@@ -75,7 +75,10 @@ def _param_spec(mesh: Mesh, path: Tuple[str, ...], shape: Tuple[int, ...]
     if name == "router":
         return pad([_fit(mesh, shape[-2], fsdp), None])
     if name == "embed":
-        return P(_fit(mesh, shape[0], "model"), _fit(mesh, shape[1], fsdp))
+        # vocab rows FSDP-sharded, hidden on model: the token gather's
+        # output takes its batch sharding from the tokens (on ``fsdp``), so
+        # the hidden dim must not sit on ``fsdp`` too
+        return P(_fit(mesh, shape[0], fsdp), _fit(mesh, shape[1], "model"))
     if name in _COL_PARALLEL and len(shape) >= 2:
         return pad([_fit(mesh, shape[-2], fsdp),
                     _fit(mesh, shape[-1], "model")])

@@ -6,8 +6,9 @@ token attends to S cached keys.  The grid walks KV blocks sequentially per
 heads lives in VMEM scratch, so the cache streams HBM->VMEM exactly once
 — the roofline-optimal traffic for this op.
 
-Masking supports a per-batch valid length (``cache_len``) and an optional
-sliding window (both used by the ring-buffer serving caches).
+Masking supports a per-batch valid length (``cache_len``, scalar-prefetched
+into SMEM like the paged kernel's table) and an optional sliding window
+(both used by the ring-buffer serving caches).
 
 ``paged_decode_attention`` is the block-paged variant backing the KV pool
 (`serving/kv_pool.py`): the cache lives as (n_pages, hkv, page_size, hd)
@@ -19,7 +20,8 @@ the implementation via the ``KernelType`` enum (``KernelTypeMapping`` in
 ``kernels/ops.py`` maps it to this kernel or the XLA gather path).
 
 Validated against ``ref.attention`` / ``ops.decode_attention`` in
-interpret mode.
+interpret mode; both kernels compile for TPU v5e
+(``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -31,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -44,6 +48,7 @@ class KernelType(enum.Enum):
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    acc_ref, *, scale: float, window: int, softcap: float,
                    block_k: int, seq_k: int):
+    ib = pl.program_id(0)
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -53,16 +58,18 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, d)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-    v = v_ref[0, 0].astype(jnp.float32)                  # (bk, dv)
+    # the MXU multiplies the storage dtype exactly and accumulates in
+    # f32; the scale applies to the f32 logits
+    q = q_ref[0, 0]                                      # (g, d)
+    k = k_ref[0, 0]                                      # (bk, d)
+    v = v_ref[0, 0]                                      # (bk, dv)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (g, bk)
+                            preferred_element_type=jnp.float32) * scale
     if softcap > 0.0:
         s = softcap * jnp.tanh(s / softcap)
 
-    valid = len_ref[0]
+    valid = len_ref[ib]
     kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     mask = (kpos < valid) & (kpos < seq_k)
     if window > 0:
@@ -76,7 +83,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     alpha = jnp.where(m_prev <= NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
@@ -88,7 +96,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      cache_len, *, window: int = 0, softcap: float = 0.0,
                      scale: Optional[float] = None, block_k: int = 512,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: Optional[bool] = None) -> jax.Array:
     """q: (b, hq, 1, d); caches: (b, hkv, S, d[v]); cache_len: (b,) or
     scalar valid lengths.  Returns (b, hq, 1, dv)."""
     b, hq, _, d = q.shape
@@ -110,24 +118,31 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     kernel = functools.partial(
         _decode_kernel, scale=scale, window=window, softcap=softcap,
         block_k=block_k, seq_k=S)
-    out = pl.pallas_call(
-        kernel,
+    # the per-row lengths ride in SMEM through scalar prefetch: a rank-1
+    # (1,) block of them is refused by the TPU lowering's tiling rule
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, hkv, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b_, h, ik: (b_,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, g, d), lambda b_, h, ik: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, ik: (b_, h, ik, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h, ik: (b_, h, ik, 0)),
+            pl.BlockSpec((1, 1, g, d), lambda b_, h, ik, lens: (b_, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda b_, h, ik, lens: (b_, h, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, dv),
+                         lambda b_, h, ik, lens: (b_, h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, dv), lambda b_, h, ik: (b_, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, g, dv),
+                               lambda b_, h, ik, lens: (b_, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, dv), jnp.float32),
         ],
-        interpret=interpret,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
+        interpret=resolve_interpret(interpret, "decode_attention"),
     )(cache_len, qg[:, :, 0], k_cache, v_cache)
     return out.reshape(b, hq, 1, dv)
 
@@ -152,12 +167,14 @@ def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (g, d)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (page, d)
-    v = v_ref[0, 0].astype(jnp.float32)                  # (page, dv)
+    # the MXU multiplies the storage dtype exactly and accumulates in
+    # f32; the scale applies to the f32 logits
+    q = q_ref[0, 0]                                      # (g, d)
+    k = k_ref[0, 0]                                      # (page, d)
+    v = v_ref[0, 0]                                      # (page, dv)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (g, page)
+                            preferred_element_type=jnp.float32) * scale
     if softcap > 0.0:
         s = softcap * jnp.tanh(s / softcap)
 
@@ -173,7 +190,8 @@ def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     alpha = jnp.where(m_prev <= NEG_INF, 0.0, jnp.exp(m_prev - m_safe))
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(iw == n_w - 1)
@@ -187,7 +205,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            *, page_size: int, kv_cap: int,
                            softcap: float = 0.0,
                            scale: Optional[float] = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: Optional[bool] = None) -> jax.Array:
     """Block-paged decode attention.
 
     q: (b, hq, 1, d); k_pages/v_pages: (n_pages, hkv, page_size, d[v])
@@ -235,6 +253,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret, "paged_decode_attention"),
     )(table_flat, cache_len, qg, k_pages, v_pages)
     return out.reshape(b, hq, 1, dv)

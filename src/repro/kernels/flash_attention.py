@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -35,12 +37,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, d)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
-    v = v_ref[0, 0].astype(jnp.float32)                  # (bk, d)
+    # the MXU multiplies the storage dtype exactly and accumulates in
+    # f32; the scale applies to the f32 logits
+    q = q_ref[0, 0]                                      # (bq, d)
+    k = k_ref[0, 0]                                      # (bk, d)
+    v = v_ref[0, 0]                                      # (bk, d)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (bq, bk)
+                            preferred_element_type=jnp.float32) * scale
     if softcap > 0.0:
         s = softcap * jnp.tanh(s / softcap)
 
@@ -64,7 +68,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
@@ -78,7 +83,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     softcap: float = 0.0, scale: Optional[float] = None,
                     q_offset: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """q: (b, hq, sq, d); k, v: (b, hkv, sk, d).  Returns (b, hq, sq, d)."""
     b, hq, sq, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
@@ -124,6 +129,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, dv), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret, "flash_attention"),
     )(q, k, v)
     return out[:, :, :sq]

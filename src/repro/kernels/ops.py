@@ -1,8 +1,9 @@
 """Dispatching wrappers around the Pallas kernels and their XLA twins.
 
 Model code calls these entry points.  ``impl`` selects:
-  - "pallas": the Pallas TPU kernel (interpret=True on CPU) — the hardware
-    target; exercised by kernel tests and benchmarks.
+  - "pallas": the Pallas TPU kernel — compiled on a TPU, interpreted
+    elsewhere (``kernels/backend.resolve_interpret``, the one backend
+    probe); exercised by kernel tests and benchmarks.
   - "xla": a blocked, memory-safe pure-XLA implementation with the same
     streaming structure (online softmax over KV blocks / chunked SSD).  This
     is the default inside model forward passes so the multi-pod dry-run's
@@ -28,12 +29,6 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import topk_retrieval as _topk
 
-def _interpret() -> bool:
-    # single source of truth for backend detection (shared with direct
-    # kernel callers)
-    return _topk.default_interpret()
-
-
 # ---------------------------------------------------------------------------
 # Attention
 # ---------------------------------------------------------------------------
@@ -46,7 +41,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if impl == "pallas":
         return _fa.flash_attention(
             q, k, v, causal=causal, window=window, softcap=softcap,
-            scale=scale, q_offset=q_offset, interpret=_interpret())
+            scale=scale, q_offset=q_offset)
     # Unblocked path up to 4k x 4k: one fused logits tensor (sharded over
     # heads) beats the blocked scan under XLA, whose loop-invariant code
     # motion materializes every block's mask/logits at once (HC1-iter3,
@@ -206,7 +201,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
     if impl == "pallas":
         return _da.decode_attention(q, k_cache, v_cache, cache_len,
                                     window=window, softcap=softcap,
-                                    scale=scale, interpret=_interpret())
+                                    scale=scale)
     b, hq, _, d = q.shape
     hkv, S = k_cache.shape[1], k_cache.shape[2]
     dv = v_cache.shape[-1]
@@ -263,7 +258,7 @@ def _paged_decode_attention_pallas(q, k_pages, v_pages, cache_len,
                                    ) -> jax.Array:
     return _da.paged_decode_attention(
         q, k_pages, v_pages, cache_len, page_table, page_size=page_size,
-        kv_cap=kv_cap, softcap=softcap, scale=scale, interpret=_interpret())
+        kv_cap=kv_cap, softcap=softcap, scale=scale)
 
 
 # KernelType -> implementation, the dispatch idiom shared with the other
@@ -300,8 +295,7 @@ def ssd(x, dt, A, B, C, *, chunk: int = 128,
     if impl == "pallas":
         if init_state is not None:
             raise NotImplementedError("pallas ssd starts from zero state")
-        return _ssd.ssd_scan(x, dt, A, B, C, chunk=chunk,
-                             interpret=_interpret())
+        return _ssd.ssd_scan(x, dt, A, B, C, chunk=chunk)
     return ref.ssd(x, dt, A, B, C, chunk=chunk, init_state=init_state)
 
 
@@ -316,7 +310,6 @@ def topk_retrieval(queries, anchors, k: int, *, impl: str = "xla",
                    ) -> Tuple[jax.Array, jax.Array]:
     if impl == "pallas":
         return _topk.topk_retrieval(
-            queries, anchors, k, interpret=_interpret(),
-            anchors_prenormalized=anchors_prenormalized)
+            queries, anchors, k, anchors_prenormalized=anchors_prenormalized)
     return ref.topk_retrieval(queries, anchors, k,
                               anchors_prenormalized=anchors_prenormalized)

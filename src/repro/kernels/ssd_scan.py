@@ -5,12 +5,17 @@ implementations use warp-level scans; here each chunk's intra-chunk work is
 expressed as MXU matmuls over a VMEM-resident (chunk x chunk) decay matrix,
 and the inter-chunk recurrence is carried in VMEM scratch across the
 innermost grid dimension (chunks are visited sequentially per (batch, head)).
+The in-chunk cumulative decay is itself a matmul against a triangular
+matrix of ones (Mosaic has no ``cumsum`` lowering).  Every matmul runs at
+``HIGHEST`` precision: the scan's inputs are float32, and the MXU's
+default one bf16 pass would round them to 8 bits.
 
 Inputs are per-head: the grid is (batch, heads, num_chunks); BlockSpecs
 stream one chunk of x/dt/B/C per step.  Chunk length should be a multiple of
 128 for MXU alignment (the interpret-mode tests also sweep small chunks).
 
-Validated against ``ref.ssd`` in interpret mode.
+Validated against ``ref.ssd`` in interpret mode; compiles for TPU v5e
+(``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -21,6 +26,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _ssd_kernel(x_ref, dt_ref, a_log_ref, b_ref, c_ref, y_ref, state_out_ref,
@@ -38,29 +47,40 @@ def _ssd_kernel(x_ref, dt_ref, a_log_ref, b_ref, c_ref, y_ref, state_out_ref,
     B = b_ref[0].astype(jnp.float32)             # (t, n)
     C = c_ref[0].astype(jnp.float32)             # (t, n)
 
-    a = dt * A[0, 0]                             # (t, 1) log decay <= 0
+    a = dt * A                                   # (t, 1) log decay <= 0
     xdt = x * dt                                 # discretized input
 
-    # cumulative decays
-    a_cum = jnp.cumsum(a, axis=0)                # (t, 1)
-    a_total = a_cum[-1, 0]
-
-    # intra-chunk decay matrix L[s, t] = exp(sum_{t<k<=s} a_k), t <= s
-    seg = a_cum - a_cum.reshape(1, chunk)        # (s, t) = a_cum[s] - a_cum[t]
+    # cumulative decays as triangular matmuls: tri[s, t] = (t <= s), so
+    # tri @ a is the column cumsum and a^T @ tri^T the same sums as a row
     srow = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     tcol = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(srow >= tcol, jnp.exp(seg), 0.0)
+    causal = srow >= tcol
+    tri = causal.astype(jnp.float32)
+    a_cum = jax.lax.dot_general(tri, a, (((1,), (0,)), ((), ())),
+                                precision=_HIGHEST,
+                                preferred_element_type=jnp.float32)  # (t, 1)
+    a_cum_row = jax.lax.dot_general(a, tri, (((0,), (1,)), ((), ())),
+                                    precision=_HIGHEST,
+                                    preferred_element_type=jnp.float32)  # (1, t)
+    a_total = jnp.sum(a, axis=0, keepdims=True)  # (1, 1)
+
+    # intra-chunk decay matrix L[s, t] = exp(sum_{t<k<=s} a_k), t <= s
+    seg = a_cum - a_cum_row                      # (s, t) = a_cum[s] - a_cum[t]
+    L = jnp.where(causal, jnp.exp(seg), 0.0)
 
     # y_diag = (C B^T * L) @ xdt
     cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
+                             precision=_HIGHEST,
                              preferred_element_type=jnp.float32)  # (s, t)
     y = jax.lax.dot_general(cb * L, xdt, (((1,), (0,)), ((), ())),
+                            precision=_HIGHEST,
                             preferred_element_type=jnp.float32)   # (s, p)
 
     # inter-chunk: y += (C decayed) @ h_entry^T   with h_entry (p, n)
     h_entry = state_ref[...]                                       # (p, n)
     c_dec = C * jnp.exp(a_cum)                                     # (s, n)
     y += jax.lax.dot_general(c_dec, h_entry, (((1,), (1,)), ((), ())),
+                             precision=_HIGHEST,
                              preferred_element_type=jnp.float32)   # (s, p)
 
     y_ref[0, 0, ...] = y.astype(y_ref.dtype)
@@ -69,6 +89,7 @@ def _ssd_kernel(x_ref, dt_ref, a_log_ref, b_ref, c_ref, y_ref, state_out_ref,
     decay_states = jnp.exp(a_total - a_cum)                        # (t, 1)
     upd = jax.lax.dot_general(xdt * decay_states, B,
                               (((0,), (0,)), ((), ())),
+                              precision=_HIGHEST,
                               preferred_element_type=jnp.float32)  # (p, n)
     state_ref[...] = state_ref[...] * jnp.exp(a_total) + upd
 
@@ -79,7 +100,8 @@ def _ssd_kernel(x_ref, dt_ref, a_log_ref, b_ref, c_ref, y_ref, state_out_ref,
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              C: jax.Array, *, chunk: int = 128,
-             interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+             interpret: Optional[bool] = None
+             ) -> Tuple[jax.Array, jax.Array]:
     """Pallas SSD over full sequences.
 
     x: (b, l, h, p); dt: (b, l, h); A: (h,); B, C: (b, l, n).
@@ -116,6 +138,6 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret, "ssd_scan"),
     )(xt, dtt, a_log, B, C)
     return y.transpose(0, 2, 1, 3), final_state

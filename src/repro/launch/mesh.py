@@ -7,22 +7,38 @@ links, TP kept inside a pod).
 
 Defined as functions so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax import).
+
+Every mesh here has ``Auto`` axes: parameters and batches carry
+``NamedSharding`` placements and XLA inserts the FSDP all-gathers and
+reductions (``distributed/sharding.py``).  ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which every gather and matmul of the model would
+have to name its output sharding.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """A mesh over ``devices`` (default: all) whose axes are all Auto."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1x1 mesh over the real local device (tests/examples)."""
     n = jax.local_device_count()
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 def make_serve_mesh(n_data: int = 0, n_model: int = 1):
@@ -36,4 +52,4 @@ def make_serve_mesh(n_data: int = 0, n_model: int = 1):
     the first jax import).
     """
     n = n_data or max(1, jax.local_device_count() // n_model)
-    return jax.make_mesh((n, n_model), ("data", "model"))
+    return make_mesh((n, n_model), ("data", "model"))

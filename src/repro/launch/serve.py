@@ -39,6 +39,7 @@ from repro.api import (
     ScopeEngine, SetBudgetPolicy)
 from repro.core.estimator import ReasoningEstimator
 from repro.data.datasets import build_scope_data
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import build_world, estimator_config
 from repro.models import model as M
 from repro.training import checkpoint
@@ -161,6 +162,7 @@ def main(argv=None):
                          "requires --stream-ticks")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = estimator_config(args.size)
     world, data, lib, retr = build_world(600, 250, args.seed)

@@ -29,6 +29,7 @@ from repro.core import serialization
 from repro.core.evaluation import predictive_metrics
 from repro.data.datasets import build_scope_data, stratified_anchors
 from repro.data.worldsim import World
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.training import checkpoint
 from repro.training.grpo import GRPOConfig, GRPOTrainer
@@ -102,6 +103,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     t0 = time.time()
     cfg = estimator_config(args.size)

@@ -3,7 +3,7 @@
   init_params(key, cfg)                      -> params pytree
   forward_train(params, cfg, batch)          -> (logits, aux)
   loss_fn(params, cfg, batch)                -> (loss, metrics)
-  prefill(params, cfg, batch, max_len)       -> (logits, caches)
+  prefill(params, cfg, batch, lengths)       -> (last logits, caches)
   decode_step(params, cfg, token, caches, pos) -> (logits, caches)
   init_cache(cfg, batch_size, max_len)       -> zeroed cache pytree
 
@@ -13,6 +13,7 @@ Batch dict keys: "tokens" (b, s) int32; optional "labels" (b, s) int32
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -29,7 +30,14 @@ from repro.models.common import (
 # ---------------------------------------------------------------------------
 # Init
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnums=(1,))
 def init_params(key, cfg: ModelConfig) -> Dict:
+    """Seeded random params in ``cfg.dtype``.
+
+    Jitted so each leaf is drawn and cast inside one fusion: a bf16 model
+    is built in bf16 and never holds its float32 image (8 GB against
+    16 GB at scope-qwen3-4b, the whole of a v5e's HBM).
+    """
     plan = tf.build_plan(cfg)
     ks = jax.random.split(key, len(plan) + 5)
     dt = dtype_of(cfg.dtype)
@@ -156,7 +164,9 @@ def _encode(params, cfg: ModelConfig, enc_features):
 # Full-sequence forward (train / prefill)
 # ---------------------------------------------------------------------------
 def _forward_full(params, cfg: ModelConfig, batch: Dict, *,
-                  want_cache: bool = False):
+                  want_cache: bool = False, head_at=None):
+    """``head_at`` (b,) int32 applies the LM head at one position per row
+    only — (b, 1, V) logits instead of (b, s, V)."""
     tokens = batch["tokens"]
     b, s = tokens.shape
     h = _embed(params, cfg, tokens, batch)
@@ -177,6 +187,9 @@ def _forward_full(params, cfg: ModelConfig, batch: Dict, *,
                                         want_cache=want_cache)
         aux_total = aux_total + aux
         caches.append(cache)
+    if head_at is not None:
+        idx = jnp.asarray(head_at, jnp.int32).reshape(b, 1, 1)
+        h = jnp.take_along_axis(h, idx, axis=1)
     return _logits(params, cfg, h), aux_total, tuple(caches)
 
 
@@ -203,10 +216,21 @@ def loss_fn(params, cfg: ModelConfig, batch: Dict):
     return loss, {"ce": ce, "aux": aux, "acc": acc}
 
 
-def prefill(params, cfg: ModelConfig, batch: Dict):
-    """Full forward returning per-layer caches sized to the prompt."""
-    logits, _, caches = _forward_full(params, cfg, batch, want_cache=True)
-    return logits, caches
+def prefill(params, cfg: ModelConfig, batch: Dict, lengths=None):
+    """Full forward returning the logits at each row's last valid prompt
+    position, (b, V), and per-layer caches sized to the prompt.
+
+    ``lengths`` (b,) gives the true length of each right-padded row (None
+    = every row fills the width).  Only the sampled position goes through
+    the LM head: the (b, L, V) logits of a whole prompt batch would cost
+    more HBM than the KV cache at a 152k vocabulary.
+    """
+    b, s = batch["tokens"].shape
+    last = (jnp.full((b,), s - 1, jnp.int32) if lengths is None
+            else jnp.asarray(lengths, jnp.int32) - 1)
+    logits, _, caches = _forward_full(params, cfg, batch, want_cache=True,
+                                      head_at=last)
+    return logits[:, 0], caches
 
 
 # ---------------------------------------------------------------------------

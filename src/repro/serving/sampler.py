@@ -62,16 +62,12 @@ COMPILE_COUNTS: "Counter[str]" = Counter()
 
 
 @functools.partial(jax.jit, static_argnums=(1,))
-def _prefill(params, cfg: ModelConfig, tokens):
+def _prefill(params, cfg: ModelConfig, tokens, lens):
+    """Prefill returning each row's last *valid* prompt logits (b, V) f32
+    (row i's at position ``lens[i] - 1``) and the prompt-sized caches."""
     COMPILE_COUNTS["prefill"] += 1          # traced once per compilation
-    return M.prefill(params, cfg, {"tokens": tokens})
-
-
-@jax.jit
-def _gather_last(logits, lens):
-    """Per-row last *valid* prompt logits: logits[i, lens[i] - 1]."""
-    idx = (lens - 1).astype(jnp.int32)[:, None, None]
-    return jnp.take_along_axis(logits, idx, axis=1)[:, 0].astype(jnp.float32)
+    logits, caches = M.prefill(params, cfg, {"tokens": tokens}, lens)
+    return logits.astype(jnp.float32), caches
 
 
 # Explicit seq-axis contract for decode caches, keyed by leaf name.  The
@@ -221,7 +217,8 @@ def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
     tests), the fusion only removes per-boundary launch overhead.
     """
     COMPILE_COUNTS["refill_scan_decode"] += 1   # traced once per compile
-    logits, new_caches = M.prefill(params, cfg, {"tokens": refill_prompts})
+    last_new, new_caches = M.prefill(params, cfg, {"tokens": refill_prompts},
+                                     refill_lens)
     new_caches = jax.tree_util.tree_map_with_path(_grow_to, new_caches,
                                                   caches)
 
@@ -232,8 +229,6 @@ def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
                          old)
 
     caches = jax.tree.map(merge, caches, new_caches)
-    idx = (refill_lens - 1).astype(jnp.int32)[:, None, None]
-    last_new = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
     last_logits = jnp.where(refill_mask[:, None],
                             last_new.astype(jnp.float32), last_logits)
     positions = jnp.where(refill_mask, refill_lens.astype(jnp.int32),
@@ -287,29 +282,30 @@ def _scatter_prefill_caches(caches, storage_of, page_ids, page_size: int):
 
 @functools.partial(jax.jit, static_argnums=(1, 3, 4))
 def _paged_prefill(params, cfg: ModelConfig, tokens, n_pages_total: int,
-                   page_size: int, page_ids):
+                   page_size: int, page_ids, lens):
     """Prefill + scatter into **fresh** paged storage.
 
     ``page_ids`` (b * npg,) maps each row's prompt page blocks to the
     physical pages its table owns (trash for inactive rows / pad blocks).
     Storage is (count, n_pages_total, hkv, page_size, hd) per leaf with
-    the trash page at index n_pages_total - 1.
+    the trash page at index n_pages_total - 1.  Returns the rows' last
+    valid logits (b, V) f32, as ``_prefill``.
     """
     COMPILE_COUNTS["paged_prefill"] += 1    # traced once per compilation
-    logits, caches = M.prefill(params, cfg, {"tokens": tokens})
+    logits, caches = M.prefill(params, cfg, {"tokens": tokens}, lens)
 
     def storage_of(path, leaf):
         count, _, hkv, _, hd = leaf.shape
         return jnp.zeros((count, n_pages_total, hkv, page_size, hd),
                          leaf.dtype)
 
-    return logits, _scatter_prefill_caches(caches, storage_of, page_ids,
-                                           page_size)
+    return logits.astype(jnp.float32), _scatter_prefill_caches(
+        caches, storage_of, page_ids, page_size)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 3))
 def _paged_refill_prefill(params, cfg: ModelConfig, tokens, page_size: int,
-                          page_ids, caches):
+                          page_ids, caches, lens):
     """Prefill + scatter into **existing** paged storage (unfused refill).
 
     Refilled rows' destinations are freshly allocated pages and everything
@@ -317,7 +313,7 @@ def _paged_refill_prefill(params, cfg: ModelConfig, tokens, page_size: int,
     analogue of the dense per-row cache merge.
     """
     COMPILE_COUNTS["paged_refill_prefill"] += 1
-    logits, new = M.prefill(params, cfg, {"tokens": tokens})
+    logits, new = M.prefill(params, cfg, {"tokens": tokens}, lens)
 
     flat_cache = {}
 
@@ -330,8 +326,8 @@ def _paged_refill_prefill(params, cfg: ModelConfig, tokens, page_size: int,
     def storage_of(path, leaf):
         return flat_cache[jax.tree_util.keystr(path)]
 
-    return logits, _scatter_prefill_caches(new, storage_of, page_ids,
-                                           page_size)
+    return logits.astype(jnp.float32), _scatter_prefill_caches(
+        new, storage_of, page_ids, page_size)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 5, 6, 7, 8))
@@ -358,7 +354,8 @@ def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
     prompts, scatter their page blocks into the pool storage (masked-out
     rows scatter to trash), reset the masked rows, then run the segment."""
     COMPILE_COUNTS["paged_refill_scan_decode"] += 1
-    logits, new = M.prefill(params, cfg, {"tokens": refill_prompts})
+    last_new, new = M.prefill(params, cfg, {"tokens": refill_prompts},
+                              refill_lens)
 
     flat_cache = {}
 
@@ -371,8 +368,6 @@ def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
         new, lambda path, leaf: flat_cache[jax.tree_util.keystr(path)],
         refill_page_ids, spec.page_size)
 
-    idx = (refill_lens - 1).astype(jnp.int32)[:, None, None]
-    last_new = jnp.take_along_axis(logits, idx, axis=1)[:, 0]
     last_logits = jnp.where(refill_mask[:, None],
                             last_new.astype(jnp.float32), last_logits)
     positions = jnp.where(refill_mask, refill_lens.astype(jnp.int32),
@@ -481,21 +476,17 @@ def prefill_state(params, cfg: ModelConfig, prompts, *,
         row_lens = np.full((b,), lp, np.int64) if lens is None else lens
         for i in np.flatnonzero(active):
             paged.admit_row(int(i), int(row_lens[i]))
+    positions = (jnp.full((b,), lp, jnp.int32) if lens is None
+                 else jnp.asarray(lens, jnp.int32))
+    if paged is not None:
         npg = _ceil_div(lp, paged.page_size)
         ids = jnp.asarray(paged.prompt_page_ids(active, npg).reshape(-1))
-        logits, caches = _paged_prefill(params, cfg, prompts,
-                                        kv_pool.n_pages + 1,
-                                        paged.page_size, ids)
+        last, caches = _paged_prefill(params, cfg, prompts,
+                                      kv_pool.n_pages + 1,
+                                      paged.page_size, ids, positions)
     else:
-        logits, caches = _prefill(params, cfg, prompts)
+        last, caches = _prefill(params, cfg, prompts, positions)
         caches = _pad_caches(caches, max_len, lp)
-
-    if lens is None:
-        last = logits[:, -1].astype(jnp.float32)
-        positions = jnp.full((b,), lp, jnp.int32)
-    else:
-        positions = jnp.asarray(lens, jnp.int32)
-        last = _gather_last(logits, positions)
     return DecodeState(caches, last, positions,
                        done=jnp.zeros((b,), bool), key=rng,
                        max_len=max_len, used=lp, paged=paged)
@@ -654,6 +645,9 @@ def refill_slots(params, cfg: ModelConfig, state: DecodeState,
         raise ValueError(f"prompt_lens shape {lens.shape} != ({r},)")
     _check_refill_lens(cfg, state, width, lens)
     ridx = jnp.asarray(rows)
+    # filler prompt rows (j >= r) are all-PAD: any in-range length does
+    plens = jnp.asarray(np.concatenate([lens, np.full(p - r, width)]),
+                        jnp.int32)
     if state.paged is not None:
         pg = state.paged
         for j, row in enumerate(rows):
@@ -668,24 +662,21 @@ def refill_slots(params, cfg: ModelConfig, state: DecodeState,
         ids = np.full((p, npg), pg.pool.trash_page, np.int32)
         for j, row in enumerate(rows):
             ids[j] = pg.table[row, :npg]
-        logits, merged = _paged_refill_prefill(
+        last, merged = _paged_refill_prefill(
             params, cfg, jnp.asarray(arr), pg.page_size,
-            jnp.asarray(ids.reshape(-1)), state.caches)
+            jnp.asarray(ids.reshape(-1)), state.caches, plens)
     else:
-        logits, caches = _prefill(params, cfg, jnp.asarray(arr))
+        last, caches = _prefill(params, cfg, jnp.asarray(arr), plens)
         caches = _pad_caches(caches, state.max_len, width)
         merged = jax.tree.map(
             lambda full, new: full.at[:, ridx].set(
                 new[:, :r].astype(full.dtype)),
             state.caches, caches)
-    plens = jnp.asarray(lens, jnp.int32)
-    # gather over the first r (real) prefilled rows only
-    last = _gather_last(logits[:r], plens)              # (r, V) f32
     return dataclasses.replace(
         state,
         caches=merged,
-        last_logits=state.last_logits.at[ridx].set(last),
-        positions=state.positions.at[ridx].set(plens),
+        last_logits=state.last_logits.at[ridx].set(last[:r]),
+        positions=state.positions.at[ridx].set(plens[:r]),
         done=state.done.at[ridx].set(False),
         used=max(state.used, int(lens.max())))
 

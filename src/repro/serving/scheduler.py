@@ -171,6 +171,11 @@ class SchedulerStats:
     failed_pairs: int = 0           # prompts answered FAILED (no fallback)
     injected_faults: int = 0        # FaultInjector events that fired
     kv_exhausted_rows: int = 0      # rows failed by KV pool exhaustion
+    # failures no FaultPlan injected (a compile error, an out-of-memory, a
+    # bug): retried like any other, but counted, and the first one's
+    # "Type: message" kept, so a device fault cannot pass as a degrade
+    unexpected_failures: int = 0
+    first_failure: str = ""
     # two-tier routing ledger (folded in by the engine per request, before
     # submission): ``tier0_answered`` pairs were served by the pre-router
     # head and never entered this scheduler; ``escalated`` pairs continued
@@ -281,6 +286,8 @@ class SchedulerStats:
                            "failed": self.failed_pairs,
                            "injected": self.injected_faults,
                            "kv_exhausted_rows": self.kv_exhausted_rows,
+                           "unexpected": self.unexpected_failures,
+                           "first_failure": self.first_failure,
                            "degraded_fraction":
                                round(self.degraded_fraction, 4)},
                 "tiers": {"tier0_answered": self.tier0_answered,

@@ -167,7 +167,7 @@ def test_jaxpr_pass_flags_poisoned_toy_jit():
             np.sin, jax.ShapeDtypeStruct(v.shape, v.dtype), v)
         return y.astype(jnp.float64)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         bad = jax.make_jaxpr(poisoned)(x)
     msgs = " ".join(f.message for f in check_closed_jaxpr("bad", bad))
     assert "pure_callback" in msgs and "float64" in msgs

@@ -47,9 +47,15 @@ def test_prefill_then_decode_continuation():
                               cfg.vocab_size)
     full_logits, _ = M.forward_train(params, cfg, {"tokens": toks})
 
+    # prefill applies the LM head at each row's last prompt position only
     logits_p, caches = M.prefill(params, cfg, {"tokens": toks[:, :lp]})
     np.testing.assert_allclose(np.asarray(logits_p, np.float32),
-                               np.asarray(full_logits[:, :lp], np.float32),
+                               np.asarray(full_logits[:, lp - 1], np.float32),
+                               atol=5e-5, rtol=1e-3)
+    lens = jnp.asarray([lp, lp - 2], jnp.int32)
+    logits_r, _ = M.prefill(params, cfg, {"tokens": toks[:, :lp]}, lens)
+    np.testing.assert_allclose(np.asarray(logits_r[1], np.float32),
+                               np.asarray(full_logits[1, lp - 3], np.float32),
                                atol=5e-5, rtol=1e-3)
     caches = _pad_caches(caches, lp + extra, lp)
     for t in range(extra):

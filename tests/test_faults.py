@@ -2,13 +2,15 @@
 retry/requeue, SLO deadlines, and graceful degradation to retrieval
 priors — unit coverage of serving.faults plus stream-level integration
 through both serve runtimes."""
+import logging
+
 import numpy as np
 import pytest
 
 from repro.api import EngineConfig, RouteRequest, ScopeEngine
 from repro.api.cache import CachedPrediction, PredictionCache
 from repro.core.estimator import (
-    FallbackEstimator, ParsedBatch, ReasoningEstimator)
+    FallbackEstimator, ParsedBatch, Prediction, ReasoningEstimator)
 from repro.core.status import STATUS_DEGRADED, STATUS_FAILED, STATUS_OK
 from repro.data.datasets import build_scope_data
 from repro.serving.faults import (
@@ -269,6 +271,7 @@ def test_dispatch_fault_retries_to_fault_free_parity(chaos_engine):
                          fault_plan=FaultPlan([FaultSpec("dispatch", 0)]))
     st = sched.stats
     assert st.injected_faults == 1 and st.retries == 1
+    assert st.unexpected_failures == 0 and st.first_failure == ""
     assert st.requeued > 0 and st.quarantined == 0
     assert st.deadline_expired == 0 and st.degraded == 0
     assert (_cat(got, "status") == STATUS_OK).all()
@@ -277,6 +280,50 @@ def test_dispatch_fault_retries_to_fault_free_parity(chaos_engine):
                                       err_msg=f)
     np.testing.assert_allclose(_cat(got, "p_hat"), _cat(ref, "p_hat"),
                                atol=1e-6, rtol=1e-6)
+
+
+class _FlakyEstimator:
+    """Raises a failure no FaultPlan injected on its first call, then
+    answers every prompt the same well-formed way."""
+
+    def __init__(self):
+        self.calls = 0
+
+    def predict(self, prompts, rng=None, **kw):
+        self.calls += 1
+        if self.calls == 1:
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+        return [Prediction(y_hat=1, len_hat=64.0, well_formed=True,
+                           p_conf=0.75, pred_tokens=6, rationale_len=4)
+                for _ in prompts]
+
+
+def test_uninjected_dispatch_error_is_counted_and_kept(world, retriever,
+                                                       library, caplog):
+    """A real (non-injected) dispatch error is retried like an injected
+    one, but the faults ledger counts it and keeps its message, and it is
+    logged once — it can never pass silently as a degrade."""
+    data = build_scope_data(world, n_queries=40, seed=9)
+    engine = ScopeEngine.build(EngineConfig(
+        estimator=_FlakyEstimator(), retriever=retriever, library=library,
+        models_meta={m: world.models[m] for m in data.models},
+        max_retries=1))
+    qs = [data.queries[int(q)] for q in data.test_qids[:2]]
+    sched = MicrobatchScheduler(BucketConfig(batch_sizes=(1, 2, 4, 8)))
+    with caplog.at_level(logging.ERROR, logger="repro.api.engine"):
+        pools = list(engine.predict_stream([RouteRequest(qs)],
+                                           scheduler=sched))
+    st = sched.stats
+    assert st.injected_faults == 0 and st.retries == 1
+    assert st.unexpected_failures == 1
+    assert st.first_failure == "RuntimeError: RESOURCE_EXHAUSTED: out of HBM"
+    faults = st.as_dict()["faults"]
+    assert faults["unexpected"] == 1
+    assert faults["first_failure"] == st.first_failure
+    assert st.quarantined == 0 and st.degraded == 0
+    assert (_cat(pools, "status") == STATUS_OK).all()
+    logged = [r for r in caplog.records if "not injected" in r.getMessage()]
+    assert len(logged) == 1 and logged[0].exc_info is not None
 
 
 def test_quarantine_answers_from_retrieval_priors(chaos_engine):

@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.api import EngineConfig, FixedAlphaPolicy, RouteRequest, ScopeEngine
+from repro.core.contract import assert_cross_shape
 from repro.core.estimator import Prediction
 from repro.data.datasets import build_scope_data
 from repro.serving.sampler import _pad_caches
@@ -162,9 +163,11 @@ def test_stream_matches_batch_predict_and_cache_stats(stream_setup):
     pools = list(e_stream.predict_stream((RouteRequest(t) for t in ticks),
                                          scheduler=sched))
     assert len(pools) == len(ticks)
+    # the ticks retrieve in other batch shapes than the one-shot predict:
+    # the cross-shape contract (decisions exact, floats to ulp)
     for field in ("p_hat", "y_hat", "len_hat", "cost_hat", "well_formed",
                   "pred_overhead", "sims", "idx"):
-        np.testing.assert_array_equal(
+        assert_cross_shape(
             np.concatenate([getattr(p, field) for p in pools]),
             getattr(pool, field), err_msg=field)
     M = len(data.models)
@@ -302,16 +305,19 @@ sched = MicrobatchScheduler(BucketConfig(batch_sizes=(4, 8)))
 ticks = [queries[:1], queries[1:4]]
 pools = list(engine.predict_stream((RouteRequest(t) for t in ticks),
                                    scheduler=sched))
-p_hat = np.concatenate([p.p_hat for p in pools])
-cost = np.concatenate([p.cost_hat for p in pools])
+cat = lambda f: np.concatenate([getattr(p, f) for p in pools]).tolist()
+embed = engine.estimator.params["embed"]
 print(json.dumps({
     "devices": jax.local_device_count(),
     "mesh_data": int(mesh.devices.shape[0]),
-    "identical": bool(np.array_equal(p_hat, ref.p_hat)
-                      and np.array_equal(cost, ref.cost_hat)),
+    "embed_devices": len(embed.sharding.device_set),
+    "got": {f: cat(f) for f in ("p_hat", "y_hat", "cost_hat", "well_formed")},
+    "want": {f: getattr(ref, f).tolist()
+             for f in ("p_hat", "y_hat", "cost_hat", "well_formed")},
     "hits_misses": [[p.cache_hits, p.cache_misses] for p in pools],
     "n_models": len(data.models),
     "microbatches": sched.stats.microbatches,
+    "faults": sched.stats.as_dict()["faults"],
 }))
 """
 
@@ -323,7 +329,16 @@ def test_stream_predict_sharded_multi_device_matches_single():
     assert out.returncode == 0, out.stderr[-2000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["devices"] == 4 and res["mesh_data"] == 4
-    assert res["identical"], "sharded stream diverged from 1-device predict"
+    assert res["embed_devices"] == 4, "params not spread over the mesh"
+    # every pair decoded for real: a device failure that degraded the
+    # whole stream to retrieval priors must not pass as a match
+    faults = res["faults"]
+    assert faults["degraded_fraction"] == 0.0, faults
+    assert faults["retries"] == 0 and faults["unexpected"] == 0, faults
+    # 4 devices vs 1: another shape, so the cross-shape contract
+    for f, want in res["want"].items():
+        assert_cross_shape(np.asarray(res["got"][f]), np.asarray(want),
+                           err_msg=f"sharded stream vs 1-device: {f}")
     M_ = res["n_models"]
     assert res["hits_misses"] == [[0, 1 * M_], [0, 3 * M_]]
     assert res["microbatches"] > 0

@@ -74,10 +74,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.models import model as M
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.models.common import activation_mesh
 
 cfg = get_config("internlm2-1.8b").reduced(d_model=256, num_heads=4)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 params = M.init_params(jax.random.PRNGKey(0), cfg)
 pspecs = shd.param_specs(mesh, params)
 ns = lambda s: NamedSharding(mesh, s)

@@ -6,6 +6,7 @@ import pytest
 
 from repro.api import EngineConfig, RouteRequest, ScopeEngine
 from repro.api.cache import CachedPrediction, PredictionCache
+from repro.core.contract import assert_cross_shape
 from repro.core.estimator import ReasoningEstimator
 from repro.core.status import STATUS_DEGRADED, STATUS_FAILED, STATUS_OK
 from repro.data.datasets import build_scope_data
@@ -117,11 +118,13 @@ def test_head_deterministic_and_pad_invariant(head):
     assert len(a) == 7
     assert (a.conf >= 0.5).all() and (a.conf <= 1.0).all()
     np.testing.assert_array_equal(a.y_hat, (a.p >= 0.5).astype(int))
-    # the same rows padded into a larger batch produce identical rows
+    # the same rows padded into a larger pair bucket: another shape, so
+    # the cross-shape contract — identical decisions, floats to ulp
     qf2, af2, mf2, mid2 = _rand_pairs(40, seed=1)
     qf2[:7], af2[:7], mf2[:7], mid2[:7] = qf, af, mf, mid
     c = head.predict_pairs(qf2, af2, mf2, mid2)
-    np.testing.assert_allclose(a.p, c.p[:7], rtol=0, atol=0)
+    assert_cross_shape(c.p[:7], a.p, err_msg="p")
+    assert_cross_shape(c.y_hat[:7], a.y_hat, err_msg="y_hat")
 
 
 def test_head_one_compile_per_bucket(head):
