@@ -1,0 +1,5 @@
+"""Set-up seconds: process start until the window opens (host clock)."""
+
+
+def read(run):
+    return run.setup_s
