@@ -33,6 +33,7 @@ from typing import (
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.cache import (
     CachedBatch, CachedPrediction, PredictionCache, query_key)
@@ -453,7 +454,10 @@ class ScopeEngine:
     def _prepare(self, request: RouteRequest, use_cache: bool
                  ) -> "_PredictState":
         """Everything before the estimator: retrieval, cache probe, and the
-        serialized prompts for the missing (query, model) pairs."""
+        serialized prompts for the missing (query, model) pairs.  A
+        non-empty request runs under the ``scope.prepare`` profiler span,
+        with ``scope.retrieve``, ``scope.cache_probe`` and
+        ``scope.serialize`` inside it."""
         cfg = self.config
         models = (list(request.models) if request.models is not None
                   else self.registry.routable())
@@ -467,6 +471,13 @@ class ScopeEngine:
                                  np.zeros((Q, M)), np.zeros((Q, M)),
                                  np.zeros((0, 2), int), [], use_cache,
                                  status=np.zeros((Q, M), np.int8))
+        with TraceAnnotation("scope.prepare"):
+            return self._prepare_pairs(request, models, queries, use_cache)
+
+    def _prepare_pairs(self, request: RouteRequest, models: List[str],
+                       queries: List, use_cache: bool) -> "_PredictState":
+        cfg = self.config
+        Q, M = len(queries), len(models)
         for m in models:
             if m not in self.registry:
                 raise KeyError(f"model {m!r} is not registered; "
@@ -478,7 +489,8 @@ class ScopeEngine:
         embs = request.query_embs
         if embs is None:
             embs = np.stack([q.embedding for q in queries])
-        sims, idx = self.retriever.retrieve(embs, cfg.k)
+        with TraceAnnotation("scope.retrieve"):
+            sims, idx = self.retriever.retrieve(embs, cfg.k)
 
         # -- batched cache probe: one pass per model column ------------
         version = cfg.estimator_version
@@ -491,15 +503,16 @@ class ScopeEngine:
         prompt_tok = np.zeros((Q, M))
         status = np.full((Q, M), STATUS_OK, np.int8)
         if use_cache:
-            for mi, m in enumerate(models):
-                col: CachedBatch = self.cache.get_many(qkeys, m, version)
-                hit[:, mi] = col.mask
-                y_hat[:, mi] = col.y_hat
-                len_hat[:, mi] = col.len_hat
-                wf[:, mi] = col.well_formed
-                p_conf[:, mi] = col.p_conf
-                prompt_tok[:, mi] = col.prompt_tokens
-                status[:, mi] = np.where(col.mask, col.status, STATUS_OK)
+            with TraceAnnotation("scope.cache_probe"):
+                for mi, m in enumerate(models):
+                    col: CachedBatch = self.cache.get_many(qkeys, m, version)
+                    hit[:, mi] = col.mask
+                    y_hat[:, mi] = col.y_hat
+                    len_hat[:, mi] = col.len_hat
+                    wf[:, mi] = col.well_formed
+                    p_conf[:, mi] = col.p_conf
+                    prompt_tok[:, mi] = col.prompt_tokens
+                    status[:, mi] = np.where(col.mask, col.status, STATUS_OK)
 
         missing = np.argwhere(~hit)                     # (n, 2) row-major
         prompts: List[List[int]] = []
@@ -507,18 +520,19 @@ class ScopeEngine:
         if cfg.tier0 is not None and len(missing):
             from repro.models.tier0 import pair_features
             feats = []
-        for qi, mi in missing:
-            m = models[mi]
-            meta = self.registry.meta(m)
-            midx = self.registry.index(m)
-            fp = self.library.get(m)
-            prompts.append(serialization.serialize_prompt(
-                meta, midx, self.library.anchor_set, fp,
-                sims[qi], idx[qi], queries[qi]))
-            if feats is not None:
-                feats.append(pair_features(
+        with TraceAnnotation("scope.serialize"):
+            for qi, mi in missing:
+                m = models[mi]
+                meta = self.registry.meta(m)
+                midx = self.registry.index(m)
+                fp = self.library.get(m)
+                prompts.append(serialization.serialize_prompt(
                     meta, midx, self.library.anchor_set, fp,
                     sims[qi], idx[qi], queries[qi]))
+                if feats is not None:
+                    feats.append(pair_features(
+                        meta, midx, self.library.anchor_set, fp,
+                        sims[qi], idx[qi], queries[qi]))
         st = _PredictState(models, queries, qkeys, sims, idx, hit, y_hat,
                            len_hat, wf, p_conf, prompt_tok, missing,
                            prompts, use_cache, status=status)
@@ -887,6 +901,7 @@ class ScopeEngine:
             batch = control.corrupt(batch)
             fill(mb.tags, batch)
             if budget:
+                sched.stats.prefill_rows += mb.tokens.shape[0]
                 sched.stats.slot_steps_total += mb.tokens.shape[0] * budget
                 sched.stats.slot_steps_active += int(
                     batch.pred_tokens[: mb.n_real].sum())
@@ -1016,8 +1031,10 @@ class ScopeEngine:
         def drain_completed():
             while pending and pending[0].remaining == 0:
                 entry = pending.popleft()
-                yield self._finalize(entry.state, entry.parsed(),
-                                     put_cache=False)
+                with TraceAnnotation("scope.finalize"):
+                    pool = self._finalize(entry.state, entry.parsed(),
+                                          put_cache=False)
+                yield pool
 
         for request in requests:
             st = self._prepare(request, use_cache)
@@ -1132,7 +1149,8 @@ class ScopeEngine:
 
     def decide(self, pool: PoolPredictions, policy: RoutingPolicy
                ) -> PolicyDecision:
-        return policy.decide(pool, self)
+        with TraceAnnotation("scope.decide"):
+            return policy.decide(pool, self)
 
     def _assemble(self, policy_name: str, decision: PolicyDecision,
                   pool: PoolPredictions, query_ids: Sequence[int], *,

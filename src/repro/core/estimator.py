@@ -360,10 +360,14 @@ class SlotRun:
         self._pending: Optional[tuple] = None
         self._inflight: Optional[tuple] = None      # (gen, dec) futures
         # decode-slot accounting (token granularity; folded into
-        # SchedulerStats by ``account``)
+        # SchedulerStats by ``fold`` at each boundary and ``account``)
         self.slot_steps_total = 0
         self.slot_steps_active = 0
         self.refill_steps = 0               # active steps on refilled rows
+        # rows the prefills computed: the opening batch, then the whole
+        # batch again for every launch that carries a refill
+        self.prefill_rows = b
+        self._folded = (0, 0, 0, 0)
 
     # -- slot bookkeeping ----------------------------------------------
     @property
@@ -510,6 +514,8 @@ class SlotRun:
         self.state, g, d = sampler.decode_segment(
             self.est.params, self.est.cfg, self.state, self.segment_len,
             refill=self._pending)
+        if self._pending is not None:
+            self.prefill_rows += self.batch
         self._pending = None
         self._inflight = (g, d)
         self.steps_run += self.segment_len
@@ -580,11 +586,23 @@ class SlotRun:
         ``launch`` between the two to overlap host parsing with decode."""
         return self.parse_completed(self.sync())
 
+    def fold(self, stats) -> None:
+        """Add this run's slot-step and prefill-row counters to
+        ``SchedulerStats``: what they gained since the last fold."""
+        now = (self.slot_steps_total, self.slot_steps_active,
+               self.refill_steps, self.prefill_rows)
+        total, active, refill, rows = (a - b for a, b in
+                                       zip(now, self._folded, strict=True))
+        stats.slot_steps_total += total
+        stats.slot_steps_active += active
+        stats.refill_steps_saved += refill
+        stats.prefill_rows += rows
+        self._folded = now
+
     def account(self, stats) -> None:
-        """Fold this run's decode-slot counters into ``SchedulerStats``."""
-        stats.slot_steps_total += self.slot_steps_total
-        stats.slot_steps_active += self.slot_steps_active
-        stats.refill_steps_saved += self.refill_steps
+        """Fold what is left of this run's counters into
+        ``SchedulerStats`` when it retires, and its KV footprint."""
+        self.fold(stats)
         if self.paged:
             pool = self.kv_pool
             stats.kv_page_size = pool.page_size

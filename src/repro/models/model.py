@@ -121,29 +121,32 @@ def _sinusoid_at(pos, d: int):
 # Embedding / head
 # ---------------------------------------------------------------------------
 def _embed(params, cfg: ModelConfig, tokens, batch: Dict):
-    h = params["embed"][tokens]
-    if cfg.scale_embeddings:
-        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
-    if cfg.rope_kind == "none" and not cfg.is_attention_free():
-        s = tokens.shape[1]
-        h = h + sinusoidal_positions(s, cfg.d_model, h.dtype)[None]
-    if cfg.num_stub_patches > 0 and "image_embeds" in batch:
-        img = batch["image_embeds"] @ params["vision_proj"]
-        npatch = img.shape[1]
-        h = jnp.concatenate([img.astype(h.dtype), h[:, npatch:]], axis=1)
-    return h
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens]
+        if cfg.scale_embeddings:
+            h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+        if cfg.rope_kind == "none" and not cfg.is_attention_free():
+            s = tokens.shape[1]
+            h = h + sinusoidal_positions(s, cfg.d_model, h.dtype)[None]
+        if cfg.num_stub_patches > 0 and "image_embeds" in batch:
+            img = batch["image_embeds"] @ params["vision_proj"]
+            npatch = img.shape[1]
+            h = jnp.concatenate([img.astype(h.dtype), h[:, npatch:]], axis=1)
+        return h
 
 
 def _logits(params, cfg: ModelConfig, h):
-    h = rmsnorm(params["final_norm"], h, cfg.rmsnorm_eps)
-    if cfg.tie_embeddings:
-        logits = h @ params["embed"].T
-    else:
-        logits = h @ params["lm_head"]
-    logits = shard_activation(logits, "batch", None, "vocab")
-    if cfg.final_logit_softcap > 0.0:
-        logits = softcap(logits.astype(jnp.float32), cfg.final_logit_softcap)
-    return logits
+    with jax.named_scope("lm_head"):
+        h = rmsnorm(params["final_norm"], h, cfg.rmsnorm_eps)
+        if cfg.tie_embeddings:
+            logits = h @ params["embed"].T
+        else:
+            logits = h @ params["lm_head"]
+        logits = shard_activation(logits, "batch", None, "vocab")
+        if cfg.final_logit_softcap > 0.0:
+            logits = softcap(logits.astype(jnp.float32),
+                             cfg.final_logit_softcap)
+        return logits
 
 
 def _encode(params, cfg: ModelConfig, enc_features):
@@ -250,11 +253,12 @@ def decode_step(params, cfg: ModelConfig, token, caches, pos, *, paged=None):
     Returns (logits (b, 1, V), new caches)."""
     b = token.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    h = params["embed"][token]
-    if cfg.scale_embeddings:
-        h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
-    if cfg.rope_kind == "none" and not cfg.is_attention_free():
-        h = h + _sinusoid_at(pos, cfg.d_model).astype(h.dtype)[:, None]
+    with jax.named_scope("embed"):
+        h = params["embed"][token]
+        if cfg.scale_embeddings:
+            h = h * jnp.asarray(cfg.d_model ** 0.5, h.dtype)
+        if cfg.rope_kind == "none" and not cfg.is_attention_free():
+            h = h + _sinusoid_at(pos, cfg.d_model).astype(h.dtype)[:, None]
     cos, sin = _cos_sin_decode(cfg, b, pos)
 
     plan = tf.build_plan(cfg)

@@ -11,6 +11,10 @@ Unit patterns handle heterogeneous stacks:
   deepseek-v2 -> segment ("mla",) x 1 (dense layer 0) + ("mla_moe",) x 26
 ``shared_attn`` blocks reuse one parameter set (closed over, Zamba2-style)
 but keep per-occurrence KV caches.
+
+An attention block's two halves run under ``jax.named_scope`` ``attn``
+(norm through the residual add) and ``mlp``, so a profile's ops name the
+half they belong to.
 """
 from __future__ import annotations
 
@@ -120,16 +124,17 @@ def block_full(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
                                       rmsnorm(p["norm"], h, cfg.rmsnorm_eps))
         return shard_activation(h + y, "batch", None, "residual"), cache, aux
 
-    x = rmsnorm(p["attn_norm"], h, cfg.rmsnorm_eps)
-    if _is_mla(kind):
-        y, kv = attn_mod.mla_full(p["attn"], cfg, x, cos, sin, kind=kind,
-                                  causal=causal)
-    else:
-        y, kv = attn_mod.gqa_full(p["attn"], cfg, x, cos, sin, kind=kind,
-                                  causal=causal)
-    if cfg.sandwich_norm:
-        y = rmsnorm(p["post_attn_norm"], y, cfg.rmsnorm_eps)
-    h = h + y
+    with jax.named_scope("attn"):
+        x = rmsnorm(p["attn_norm"], h, cfg.rmsnorm_eps)
+        if _is_mla(kind):
+            y, kv = attn_mod.mla_full(p["attn"], cfg, x, cos, sin, kind=kind,
+                                      causal=causal)
+        else:
+            y, kv = attn_mod.gqa_full(p["attn"], cfg, x, cos, sin, kind=kind,
+                                      causal=causal)
+        if cfg.sandwich_norm:
+            y = rmsnorm(p["post_attn_norm"], y, cfg.rmsnorm_eps)
+        h = h + y
     cache.update(kv)
 
     if "cross" in p and enc_out is not None:
@@ -138,14 +143,15 @@ def block_full(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
         h = h + attn_mod.cross_attend(p["cross"], cfg, xc, ckv)
         cache.update(ckv)
 
-    x2 = rmsnorm(p["mlp_norm"], h, cfg.rmsnorm_eps)
-    if _is_moe(kind):
-        y2, aux = moe_mod.moe_forward(p["moe"], cfg, x2)
-    else:
-        y2 = mlp_mod.mlp_forward(p["mlp"], cfg, x2)
-    if cfg.sandwich_norm:
-        y2 = rmsnorm(p["post_mlp_norm"], y2, cfg.rmsnorm_eps)
-    out = shard_activation(h + y2, "batch", None, "residual")
+    with jax.named_scope("mlp"):
+        x2 = rmsnorm(p["mlp_norm"], h, cfg.rmsnorm_eps)
+        if _is_moe(kind):
+            y2, aux = moe_mod.moe_forward(p["moe"], cfg, x2)
+        else:
+            y2 = mlp_mod.mlp_forward(p["mlp"], cfg, x2)
+        if cfg.sandwich_norm:
+            y2 = rmsnorm(p["post_mlp_norm"], y2, cfg.rmsnorm_eps)
+        out = shard_activation(h + y2, "batch", None, "residual")
     return out, cache, aux
 
 
@@ -165,22 +171,24 @@ def block_decode(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
         return h + y, new
 
     new_cache: Dict = {}
-    x = rmsnorm(p["attn_norm"], h, cfg.rmsnorm_eps)
-    if paged is not None:
-        if _is_mla(kind):
-            raise ValueError("paged decode does not support MLA layers")
-        spec, table = paged
-        y, kv = attn_mod.gqa_decode_paged(p["attn"], cfg, x, cos, sin,
-                                          cache, pos, table, spec, kind=kind)
-    elif _is_mla(kind):
-        y, kv = attn_mod.mla_decode(p["attn"], cfg, x, cos, sin, cache, pos,
-                                    kind=kind)
-    else:
-        y, kv = attn_mod.gqa_decode(p["attn"], cfg, x, cos, sin, cache, pos,
-                                    kind=kind)
-    if cfg.sandwich_norm:
-        y = rmsnorm(p["post_attn_norm"], y, cfg.rmsnorm_eps)
-    h = h + y
+    with jax.named_scope("attn"):
+        x = rmsnorm(p["attn_norm"], h, cfg.rmsnorm_eps)
+        if paged is not None:
+            if _is_mla(kind):
+                raise ValueError("paged decode does not support MLA layers")
+            spec, table = paged
+            y, kv = attn_mod.gqa_decode_paged(p["attn"], cfg, x, cos, sin,
+                                              cache, pos, table, spec,
+                                              kind=kind)
+        elif _is_mla(kind):
+            y, kv = attn_mod.mla_decode(p["attn"], cfg, x, cos, sin, cache,
+                                        pos, kind=kind)
+        else:
+            y, kv = attn_mod.gqa_decode(p["attn"], cfg, x, cos, sin, cache,
+                                        pos, kind=kind)
+        if cfg.sandwich_norm:
+            y = rmsnorm(p["post_attn_norm"], y, cfg.rmsnorm_eps)
+        h = h + y
     new_cache.update(kv)
 
     if "cross" in p:
@@ -189,14 +197,16 @@ def block_decode(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
         h = h + attn_mod.cross_attend(p["cross"], cfg, xc, ckv)
         new_cache.update(ckv)
 
-    x2 = rmsnorm(p["mlp_norm"], h, cfg.rmsnorm_eps)
-    if _is_moe(kind):
-        y2, _ = moe_mod.moe_forward(p["moe"], cfg, x2)
-    else:
-        y2 = mlp_mod.mlp_forward(p["mlp"], cfg, x2)
-    if cfg.sandwich_norm:
-        y2 = rmsnorm(p["post_mlp_norm"], y2, cfg.rmsnorm_eps)
-    return h + y2, new_cache
+    with jax.named_scope("mlp"):
+        x2 = rmsnorm(p["mlp_norm"], h, cfg.rmsnorm_eps)
+        if _is_moe(kind):
+            y2, _ = moe_mod.moe_forward(p["moe"], cfg, x2)
+        else:
+            y2 = mlp_mod.mlp_forward(p["mlp"], cfg, x2)
+        if cfg.sandwich_norm:
+            y2 = rmsnorm(p["post_mlp_norm"], y2, cfg.rmsnorm_eps)
+        h = h + y2
+    return h, new_cache
 
 
 # ---------------------------------------------------------------------------

@@ -36,6 +36,8 @@ import dataclasses
 from collections import deque
 from typing import Any, Callable, Deque, Iterable, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.serving.scheduler import Microbatch
 
 
@@ -53,8 +55,6 @@ def _parse(handle: Any) -> Any:
 class RuntimeStats:
     dispatched: int = 0
     parsed: int = 0
-    overlapped: int = 0      # parses that found the device already done
-    max_in_flight: int = 0
     failed: int = 0          # microbatches routed to on_failed
 
     def as_dict(self):
@@ -96,7 +96,6 @@ class ServeRuntime:
 
     def _parse_oldest(self) -> None:
         mb, handle = self._inflight.popleft()
-        ready = _is_ready(handle)
         try:
             result = _parse(handle)
         except Exception as exc:
@@ -105,7 +104,6 @@ class ServeRuntime:
             self.stats.failed += 1
             self._on_failed(mb, exc)
             return
-        self.stats.overlapped += int(ready)
         self.stats.parsed += 1
         self._on_parsed(mb, result)
 
@@ -133,8 +131,6 @@ class ServeRuntime:
                 continue
             self._inflight.append((mb, handle))
             self.stats.dispatched += 1
-            self.stats.max_in_flight = max(self.stats.max_in_flight,
-                                           len(self._inflight))
             while len(self._inflight) > self.max_pending:
                 self._parse_oldest()
 
@@ -198,8 +194,14 @@ class SlotRuntime:
     ``pump(final=True)`` flushes the scheduler and drains until every slot
     retires.  A queued prompt wider than the live state's slots is never
     force-fit: it waits for that state to retire and then opens (or joins)
-    its own microbatch.  Retired runs fold their decode-slot occupancy
-    counters into ``scheduler.stats``.
+    its own microbatch.  The live run folds its decode-slot and prefill-row
+    counters into ``scheduler.stats`` at every boundary, and the remainder
+    when it retires.
+
+    Each pump is a ``scope.pump`` profiler span, with ``scope.open``,
+    ``scope.sync``, ``scope.boundary`` (``scope.admit``, ``scope.launch``)
+    and ``scope.parse`` inside it, so a trace names the host work behind
+    every device-idle gap between two segments.
     """
 
     def __init__(self, open_slots: Callable[..., Any], scheduler, *,
@@ -306,6 +308,11 @@ class SlotRuntime:
         self._on_failed(failed, exc)
 
     def pump(self, final: bool = False) -> None:
+        with TraceAnnotation("scope.pump"):
+            self._pump(final)
+
+    def _pump(self, final: bool) -> None:
+        stats = self._sched.stats
         while True:
             if self._run is None:
                 self._open_queue.extend(
@@ -317,13 +324,15 @@ class SlotRuntime:
                 if self._kv_pool is not None:
                     kw = {"kv_pool": self._kv_pool,
                           "kv_kernel": self._kv_kernel}
-                self._run = self._open_slots(
-                    mb.tokens, lengths=mb.lengths, tags=mb.tags,
-                    segment_len=self._segment_len, horizon=self._horizon,
-                    rng=self._rng, **kw)
+                with TraceAnnotation("scope.open"):
+                    self._run = self._open_slots(
+                        mb.tokens, lengths=mb.lengths, tags=mb.tags,
+                        segment_len=self._segment_len, horizon=self._horizon,
+                        rng=self._rng, **kw)
                 # a partially-filled opening bucket's pad rows are free
                 # slots: refill them before the first segment launches
-                self._admit(self._run)
+                with TraceAnnotation("scope.admit"):
+                    self._admit(self._run)
             run = self._run
             # launch the first segment of a fresh state, sync the
             # in-flight one, refill the slots it drained, and launch the
@@ -333,24 +342,37 @@ class SlotRuntime:
             completed = []
             try:
                 if not run.in_flight:
-                    self._launch(run)
+                    with TraceAnnotation("scope.launch"):
+                        self._launch(run)
                 if run.in_flight:
-                    completed = run.sync()
+                    with TraceAnnotation("scope.sync"):
+                        completed = run.sync()
             except Exception as exc:
                 self._recover(run, completed, exc)
                 continue
-            self._admit(run)
-            try:
-                if not run.finished:
-                    self._launch(run)
-            except Exception as exc:
-                self._recover(run, completed, exc)
+            # the boundary: host work while the device waits for the next
+            # segment
+            failure = None
+            with TraceAnnotation("scope.boundary"):
+                with TraceAnnotation("scope.admit"):
+                    self._admit(run)
+                try:
+                    if not run.finished:
+                        with TraceAnnotation("scope.launch"):
+                            self._launch(run)
+                except Exception as exc:
+                    failure = exc
+            if failure is not None:
+                self._recover(run, completed, failure)
                 continue
             if completed:
-                self._on_parsed(*run.parse_completed(completed))
+                with TraceAnnotation("scope.parse"):
+                    self._on_parsed(*run.parse_completed(completed))
             if run.finished:
-                run.account(self._sched.stats)
+                run.account(stats)
                 self._run = None
                 continue                # maybe open the next state
+            # the live run's counters, so a snapshot between pumps is exact
+            run.fold(stats)
             if not final:
                 return                  # one segment per arrival

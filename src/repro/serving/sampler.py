@@ -29,6 +29,10 @@ EOS done-mask, and only what the estimator consumes crosses back to the
 host — generated token ids plus the YES/NO logit pair at each step.  The
 full ``(b, T, V)`` logits stack never leaves the device.
 
+The executables name their halves with ``jax.named_scope`` — ``prefill``,
+and per scan step ``decode`` with ``sample`` inside — so the ops of a
+profile carry the layer they belong to in their ``op_name`` metadata.
+
 ``COMPILE_COUNTS`` counts executable builds explicitly (incremented inside
 the traced bodies, once per compilation) — the serve path's "0 recompiles
 after warmup" gate reads it instead of sniffing jit internals.
@@ -66,8 +70,9 @@ def _prefill(params, cfg: ModelConfig, tokens, lens):
     """Prefill returning each row's last *valid* prompt logits (b, V) f32
     (row i's at position ``lens[i] - 1``) and the prompt-sized caches."""
     COMPILE_COUNTS["prefill"] += 1          # traced once per compilation
-    logits, caches = M.prefill(params, cfg, {"tokens": tokens}, lens)
-    return logits.astype(jnp.float32), caches
+    with jax.named_scope("prefill"):
+        logits, caches = M.prefill(params, cfg, {"tokens": tokens}, lens)
+        return logits.astype(jnp.float32), caches
 
 
 # Explicit seq-axis contract for decode caches, keyed by leaf name.  The
@@ -127,18 +132,21 @@ def _run_scan(params, cfg: ModelConfig, last_logits, caches, key,
 
     def step(carry, t):
         logits, kv, dn, k = carry
-        if temperature > 0.0:
-            k, sub = jax.random.split(k)
-            nxt = jax.random.categorical(sub, logits / temperature, axis=-1)
-        else:
-            nxt = jnp.argmax(logits, axis=-1)
-        nxt = jnp.where(dn, PAD, nxt).astype(jnp.int32)
-        dec = logits[:, dec_ix]                          # (b, 2)
-        if stop_at_eos:
-            dn = dn | (nxt == EOS)
-        new_logits, kv = M.decode_step(params, cfg, nxt[:, None], kv,
-                                       positions + t, paged=paged)
-        new_logits = new_logits[:, 0].astype(jnp.float32)
+        with jax.named_scope("decode"):
+            with jax.named_scope("sample"):
+                if temperature > 0.0:
+                    k, sub = jax.random.split(k)
+                    nxt = jax.random.categorical(sub, logits / temperature,
+                                                 axis=-1)
+                else:
+                    nxt = jnp.argmax(logits, axis=-1)
+                nxt = jnp.where(dn, PAD, nxt).astype(jnp.int32)
+                dec = logits[:, dec_ix]                  # (b, 2)
+                if stop_at_eos:
+                    dn = dn | (nxt == EOS)
+            new_logits, kv = M.decode_step(params, cfg, nxt[:, None], kv,
+                                           positions + t, paged=paged)
+            new_logits = new_logits[:, 0].astype(jnp.float32)
         return (new_logits, kv, dn, k), (nxt, dec)
 
     init = (last_logits, caches, done, key)
@@ -217,10 +225,6 @@ def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
     tests), the fusion only removes per-boundary launch overhead.
     """
     COMPILE_COUNTS["refill_scan_decode"] += 1   # traced once per compile
-    last_new, new_caches = M.prefill(params, cfg, {"tokens": refill_prompts},
-                                     refill_lens)
-    new_caches = jax.tree_util.tree_map_with_path(_grow_to, new_caches,
-                                                  caches)
 
     def merge(old, new):
         shape = [1] * old.ndim
@@ -228,12 +232,17 @@ def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
         return jnp.where(refill_mask.reshape(shape), new.astype(old.dtype),
                          old)
 
-    caches = jax.tree.map(merge, caches, new_caches)
-    last_logits = jnp.where(refill_mask[:, None],
-                            last_new.astype(jnp.float32), last_logits)
-    positions = jnp.where(refill_mask, refill_lens.astype(jnp.int32),
-                          positions)
-    done = jnp.where(refill_mask, False, done)
+    with jax.named_scope("prefill"):
+        last_new, new_caches = M.prefill(
+            params, cfg, {"tokens": refill_prompts}, refill_lens)
+        new_caches = jax.tree_util.tree_map_with_path(_grow_to, new_caches,
+                                                      caches)
+        caches = jax.tree.map(merge, caches, new_caches)
+        last_logits = jnp.where(refill_mask[:, None],
+                                last_new.astype(jnp.float32), last_logits)
+        positions = jnp.where(refill_mask, refill_lens.astype(jnp.int32),
+                              positions)
+        done = jnp.where(refill_mask, False, done)
     out = _run_scan(params, cfg, last_logits, caches, key, steps,
                     temperature, stop_at_eos, positions, done)
     return out + (positions,)
@@ -292,15 +301,16 @@ def _paged_prefill(params, cfg: ModelConfig, tokens, n_pages_total: int,
     valid logits (b, V) f32, as ``_prefill``.
     """
     COMPILE_COUNTS["paged_prefill"] += 1    # traced once per compilation
-    logits, caches = M.prefill(params, cfg, {"tokens": tokens}, lens)
 
     def storage_of(path, leaf):
         count, _, hkv, _, hd = leaf.shape
         return jnp.zeros((count, n_pages_total, hkv, page_size, hd),
                          leaf.dtype)
 
-    return logits.astype(jnp.float32), _scatter_prefill_caches(
-        caches, storage_of, page_ids, page_size)
+    with jax.named_scope("prefill"):
+        logits, caches = M.prefill(params, cfg, {"tokens": tokens}, lens)
+        return logits.astype(jnp.float32), _scatter_prefill_caches(
+            caches, storage_of, page_ids, page_size)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 3))
@@ -313,8 +323,6 @@ def _paged_refill_prefill(params, cfg: ModelConfig, tokens, page_size: int,
     analogue of the dense per-row cache merge.
     """
     COMPILE_COUNTS["paged_refill_prefill"] += 1
-    logits, new = M.prefill(params, cfg, {"tokens": tokens}, lens)
-
     flat_cache = {}
 
     def name_leaf(path, leaf):
@@ -326,8 +334,10 @@ def _paged_refill_prefill(params, cfg: ModelConfig, tokens, page_size: int,
     def storage_of(path, leaf):
         return flat_cache[jax.tree_util.keystr(path)]
 
-    return logits.astype(jnp.float32), _scatter_prefill_caches(
-        new, storage_of, page_ids, page_size)
+    with jax.named_scope("prefill"):
+        logits, new = M.prefill(params, cfg, {"tokens": tokens}, lens)
+        return logits.astype(jnp.float32), _scatter_prefill_caches(
+            new, storage_of, page_ids, page_size)
 
 
 @functools.partial(jax.jit, static_argnums=(1, 5, 6, 7, 8))
@@ -354,9 +364,6 @@ def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
     prompts, scatter their page blocks into the pool storage (masked-out
     rows scatter to trash), reset the masked rows, then run the segment."""
     COMPILE_COUNTS["paged_refill_scan_decode"] += 1
-    last_new, new = M.prefill(params, cfg, {"tokens": refill_prompts},
-                              refill_lens)
-
     flat_cache = {}
 
     def name_leaf(path, leaf):
@@ -364,15 +371,17 @@ def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
         return leaf
 
     jax.tree_util.tree_map_with_path(name_leaf, caches)
-    caches = _scatter_prefill_caches(
-        new, lambda path, leaf: flat_cache[jax.tree_util.keystr(path)],
-        refill_page_ids, spec.page_size)
-
-    last_logits = jnp.where(refill_mask[:, None],
-                            last_new.astype(jnp.float32), last_logits)
-    positions = jnp.where(refill_mask, refill_lens.astype(jnp.int32),
-                          positions)
-    done = jnp.where(refill_mask, False, done)
+    with jax.named_scope("prefill"):
+        last_new, new = M.prefill(params, cfg, {"tokens": refill_prompts},
+                                  refill_lens)
+        caches = _scatter_prefill_caches(
+            new, lambda path, leaf: flat_cache[jax.tree_util.keystr(path)],
+            refill_page_ids, spec.page_size)
+        last_logits = jnp.where(refill_mask[:, None],
+                                last_new.astype(jnp.float32), last_logits)
+        positions = jnp.where(refill_mask, refill_lens.astype(jnp.int32),
+                              positions)
+        done = jnp.where(refill_mask, False, done)
     out = _run_scan(params, cfg, last_logits, caches, key, steps,
                     temperature, stop_at_eos, positions, done,
                     paged=(spec, table))

@@ -134,15 +134,17 @@ class SchedulerStats:
     # decode-slot accounting (continuous batching): how many slot-steps the
     # decode executables ran, how many of them decoded a live request's
     # tokens, and how much of that came from mid-batch refills.  The engine
-    # folds these in at parse/retire time for both the whole-retire and the
-    # segment-chunked refill paths, so the bench occupancy comparison reads
-    # one counter pair instead of recomputing.
+    # folds these in at parse time on the whole-retire path and at every
+    # segment boundary on the refill path, so a snapshot between two
+    # requests is exact and the occupancy comparison reads one counter pair.
     slots_refilled: int = 0         # requests popped into an open slot
     refill_steps_saved: int = 0     # active decode steps served by refilled
     #                                 rows — whole-retire would have idled
     #                                 those slot-steps at PAD
     slot_steps_total: int = 0       # batch x decode-steps actually run
     slot_steps_active: int = 0      # of those, steps holding a live request
+    prefill_rows: int = 0           # rows the prefill-bearing launches
+    #                                 computed (their whole batch each)
     # paged-KV accounting (segment granularity, folded in by
     # SlotRun.account / SlotRuntime._admit).  pages_in_use / kv_live_tokens
     # are gauges (last retire's snapshot); the peaks are monotonic maxima.
@@ -266,6 +268,7 @@ class SchedulerStats:
                 "refill_steps_saved": self.refill_steps_saved,
                 "slot_steps": {"total": self.slot_steps_total,
                                "active": self.slot_steps_active},
+                "prefill_rows": self.prefill_rows,
                 "slot_occupancy": round(self.slot_occupancy, 4),
                 "kv_pages": {"page_size": self.kv_page_size,
                              "in_use": self.pages_in_use,
