@@ -189,13 +189,13 @@ def _register_defaults() -> None:
     def _fused_refill():
         s = _abstract_serve_state()
         cfg, B, L, T = s["cfg"], s["B"], s["L"], s["T"]
-        mask = jax.ShapeDtypeStruct((B,), jnp.bool_)
+        rows = jax.ShapeDtypeStruct((B,), jnp.int32)
         rlens = jax.ShapeDtypeStruct((B,), jnp.int32)
-        fn = lambda p, lg, c, k, pos, dn, m, rp, rl: \
+        fn = lambda p, lg, c, k, pos, dn, r, rp, rl: \
             sampler._refill_scan_decode(p, cfg, lg, c, k, T, 0.0, True,
-                                        pos, dn, m, rp, rl)
+                                        pos, dn, r, rp, rl)
         return jax.make_jaxpr(fn)(s["params"], s["last"], s["padded"],
-                                  s["key"], s["pos"], s["done"], mask,
+                                  s["key"], s["pos"], s["done"], rows,
                                   s["tokens"], rlens)
 
     def _paged_state(kernel):
@@ -236,15 +236,15 @@ def _register_defaults() -> None:
         from repro.kernels.decode_attention import KernelType
         s, pcaches, spec, table, ids = _paged_state(KernelType.XLA)
         cfg, B, T = s["cfg"], s["B"], s["T"]
-        mask = jax.ShapeDtypeStruct((B,), jnp.bool_)
+        rows = jax.ShapeDtypeStruct((B,), jnp.int32)
         rlens = jax.ShapeDtypeStruct((B,), jnp.int32)
-        fn = lambda p, lg, c, k, tbl, pos, dn, m, rp, rl, ri: \
+        fn = lambda p, lg, c, k, tbl, pos, dn, r, rp, rl, ri: \
             sampler._paged_refill_scan_decode(
                 p, cfg, lg, c, k, T, 0.0, True, spec, tbl, pos, dn,
-                m, rp, rl, ri)
+                r, rp, rl, ri)
         return jax.make_jaxpr(fn)(s["params"], s["last"], pcaches,
                                   s["key"], table, s["pos"], s["done"],
-                                  mask, s["tokens"], rlens, ids)
+                                  rows, s["tokens"], rlens, ids)
 
     @register("tier0_forward")
     def _tier0_forward():

@@ -902,6 +902,7 @@ class ScopeEngine:
             fill(mb.tags, batch)
             if budget:
                 sched.stats.prefill_rows += mb.tokens.shape[0]
+                sched.stats.prefill_launches_by_rows[mb.tokens.shape[0]] += 1
                 sched.stats.slot_steps_total += mb.tokens.shape[0] * budget
                 sched.stats.slot_steps_active += int(
                     batch.pred_tokens[: mb.n_real].sum())

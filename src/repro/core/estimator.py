@@ -13,6 +13,7 @@ parity tests pin the batched parse against.
 from __future__ import annotations
 
 import dataclasses
+from collections import Counter
 from typing import List, Optional, Sequence
 
 import jax
@@ -273,8 +274,11 @@ class SlotRun:
     drained at EOS (or exhausted the per-request ``max_new_tokens`` budget)
     are parsed from their own window of the accumulated decode buffer and
     their slot freed; ``admit`` prefills freshly popped prompts into the
-    free slots — one batched prefill per boundary, padded to the warmed
-    (b, L) executable shape, however many slots drain together.  The slot
+    free slots — one batched prefill per boundary, fused into the next
+    segment's launch and computed on the smallest row bucket (b/4, b/2 or
+    b rows) that holds the admitted prompts, however many slots drain
+    together.  A paged state opens with nothing prefilled: its opening
+    rows are admitted the same way, by its first launch.  The slot
     cache is allocated ``horizon`` decode steps deep (default 4x the
     budget, rounded up to whole segments) so a slot serves several requests
     back-to-back before the state retires; ``can_admit`` turns False once
@@ -328,20 +332,21 @@ class SlotRun:
         # per-row true lengths only when genuinely ragged: exact-fit
         # buckets stay on the unmasked path (SSM backbones included)
         pl = lens if lens is not None and (lens != L).any() else None
+        placed = estimator._place_batch(tokens)
         if kv_pool is None:
             self.state = sampler.prefill_state(
-                estimator.params, estimator.cfg,
-                estimator._place_batch(tokens),
+                estimator.params, estimator.cfg, placed,
                 max_new_tokens=self.horizon, prompt_lens=pl, rng=rng)
         else:
             from repro.kernels.decode_attention import KernelType
-            self.state = sampler.prefill_state(
-                estimator.params, estimator.cfg,
-                estimator._place_batch(tokens),
-                max_new_tokens=self.budget_steps, prompt_lens=pl, rng=rng,
-                kv_pool=kv_pool,
-                kv_kernel=kv_kernel or KernelType.XLA,
-                kv_active=np.arange(b) < len(tags))
+            self.state = sampler.open_state(
+                estimator.params, estimator.cfg, placed,
+                max_new_tokens=self.budget_steps, kv_pool=kv_pool,
+                kv_kernel=kv_kernel or KernelType.XLA, rng=rng)
+            # refills prefill row buckets: run each once per shape, before
+            # any request waits on one
+            sampler.warm_row_buckets(estimator.params, estimator.cfg,
+                                     self.state, L, self.segment_len)
         # rows past the real tags are free slots from the start (a
         # partially-filled opening bucket refills instead of padding)
         true_lens = lens if lens is not None else np.full(b, L, int)
@@ -356,18 +361,34 @@ class SlotRun:
         self._gen = np.full((b, buf), -1, np.int32)
         self._dec = np.zeros((b, buf, 2), np.float32)
         # slot-aligned refills admitted since the last launch; fused into
-        # the next ``decode_segment(refill=...)`` executable
+        # the next ``decode_segment(refill=...)`` executable.  A paged
+        # state opens with nothing prefilled: its opening rows ride the
+        # first launch's refill, in the row bucket that holds them.
         self._pending: Optional[tuple] = None
+        if self.paged:
+            for i in range(len(tags)):
+                self._stage(i, tokens[i, : int(true_lens[i])],
+                            int(true_lens[i]))
         self._inflight: Optional[tuple] = None      # (gen, dec) futures
         # decode-slot accounting (token granularity; folded into
         # SchedulerStats by ``fold`` at each boundary and ``account``)
         self.slot_steps_total = 0
         self.slot_steps_active = 0
         self.refill_steps = 0               # active steps on refilled rows
-        # rows the prefills computed: the opening batch, then the whole
-        # batch again for every launch that carries a refill
-        self.prefill_rows = b
+        # rows the prefills computed (the opening prefill's, then those of
+        # every launch that carries a refill), and launches per row count
+        self.prefill_rows = 0
+        self.prefill_launches_by_rows: Counter = Counter()
+        if self.state.prefill_rows:
+            self._count_prefill()
         self._folded = (0, 0, 0, 0)
+        self._folded_launches: Counter = Counter()
+
+    def _count_prefill(self) -> None:
+        """Count the prefill of the launch that made the current state."""
+        rows = self.state.prefill_rows
+        self.prefill_rows += rows
+        self.prefill_launches_by_rows[rows] += 1
 
     # -- slot bookkeeping ----------------------------------------------
     @property
@@ -423,12 +444,6 @@ class SlotRun:
         if len(items) > len(free):
             raise ValueError(
                 f"{len(items)} refills for {len(free)} free slots")
-        if self._pending is None:
-            self._pending = (np.zeros(self.batch, bool),
-                             np.full((self.batch, self.width), tok.PAD,
-                                     np.int32),
-                             np.ones(self.batch, np.int64))
-        mask, mat, lens = self._pending
         for (tag, prompt, length), row in zip(items, free, strict=False):
             if not self.can_admit():
                 raise ValueError(
@@ -440,16 +455,26 @@ class SlotRun:
                 raise ValueError(
                     f"refill prompt of {len(p)} tokens does not fit the "
                     f"slot width {self.width}")
-            mask[row] = True
-            mat[row] = tok.PAD
-            mat[row, : len(p)] = p
-            lens[row] = int(length) if length else len(p)
-            if self.paged:
-                # reserve the row's pages NOW so the next can_admit()
-                # check sees the pool as the coming launch will leave it
-                self.state.paged.pre_admit(row, int(lens[row]))
+            self._stage(row, p, int(length) if length else len(p))
             self.slots[row] = _Slot(tag, self.steps_run, True,
                                     prompt=p.tolist())
+
+    def _stage(self, row: int, prompt: np.ndarray, length: int) -> None:
+        """Put ``prompt`` into the pending refill for slot ``row``."""
+        if self._pending is None:
+            self._pending = (np.zeros(self.batch, bool),
+                             np.full((self.batch, self.width), tok.PAD,
+                                     np.int32),
+                             np.ones(self.batch, np.int64))
+        mask, mat, lens = self._pending
+        mask[row] = True
+        mat[row] = tok.PAD
+        mat[row, : len(prompt)] = prompt
+        lens[row] = length
+        if self.paged:
+            # reserve the row's pages NOW so the next can_admit() check
+            # sees the pool as the coming launch will leave it
+            self.state.paged.pre_admit(row, length)
 
     # -- failure surface (serve-runtime fault tolerance) ---------------
     @property
@@ -515,7 +540,7 @@ class SlotRun:
             self.est.params, self.est.cfg, self.state, self.segment_len,
             refill=self._pending)
         if self._pending is not None:
-            self.prefill_rows += self.batch
+            self._count_prefill()
         self._pending = None
         self._inflight = (g, d)
         self.steps_run += self.segment_len
@@ -597,7 +622,10 @@ class SlotRun:
         stats.slot_steps_active += active
         stats.refill_steps_saved += refill
         stats.prefill_rows += rows
+        stats.prefill_launches_by_rows.update(
+            self.prefill_launches_by_rows - self._folded_launches)
         self._folded = now
+        self._folded_launches = self.prefill_launches_by_rows.copy()
 
     def account(self, stats) -> None:
         """Fold what is left of this run's counters into

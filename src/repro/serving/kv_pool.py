@@ -359,13 +359,16 @@ class PagedKV:
         import jax.numpy as jnp
         return jnp.asarray(self.table)
 
-    def prompt_page_ids(self, mask: np.ndarray, n_pages_row: int
+    def prompt_page_ids(self, rows: np.ndarray, n_pages_row: int
                         ) -> np.ndarray:
-        """(b, n_pages_row) scatter destinations for refill prompt page
-        blocks: admitted rows' freshly allocated prompt pages where the
-        mask is set, the trash page elsewhere (so non-refilled rows' live
-        pages are never touched by the fused scatter)."""
-        ids = np.where(np.asarray(mask, bool)[:, None],
-                       self.table[:, :n_pages_row],
-                       np.int32(self.pool.trash_page))
-        return ids.astype(np.int32)
+        """(len(rows), n_pages_row) scatter destinations for the prompt
+        page blocks of slot rows ``rows``: each admitted row's freshly
+        allocated prompt pages, the trash page for a filler entry (a row
+        >= the batch), so no live row's pages are touched by the
+        scatter."""
+        rows = np.asarray(rows, np.int64).reshape(-1)
+        real = rows < self.batch
+        ids = np.full((len(rows), n_pages_row), self.pool.trash_page,
+                      np.int32)
+        ids[real] = self.table[rows[real], :n_pages_row]
+        return ids

@@ -11,10 +11,14 @@ idling the slot until the batch finishes:
   state = refill_slot(params, cfg, state, row=2, prompt=new_prompt)
   state, gen2, dec2 = decode_segment(params, cfg, state, 4)
 
-``refill_slots`` is the batched form the serve runtime uses: every slot
-drained at one segment boundary refills with a single prefill call,
-padded to the warmed (b, L) executable shape with true per-prompt
-lengths.
+``refill_slots`` is the batched form: every slot drained at one segment
+boundary refills with a single prefill call, padded to a warmed (p, L)
+executable shape with true per-prompt lengths.  The serve runtime fuses
+that prefill into the next segment (``decode_segment(refill=...)``),
+which prefills the admitted rows only, compacted into the smallest row
+bucket (b/4, b/2 or b rows) that holds them; a paged state opens with
+nothing prefilled (``open_state``), so its opening rows ride its first
+launch the same way.
 
 Positions are **per row**: rows at different sequence offsets (ragged
 prompt lengths under a bucket grid, refilled slots mid-decode) share one
@@ -206,43 +210,82 @@ def _check_refill_lens(cfg: ModelConfig, state: "DecodeState", width: int,
             f"leaves no decode room in a {state.max_len}-slot cache")
 
 
+# ---------------------------------------------------------------------------
+# Row buckets: a refill prefills the admitted rows only
+# ---------------------------------------------------------------------------
+def row_buckets(b: int, multiple: int = 1) -> Tuple[int, ...]:
+    """Row counts the paged prefills of a ``b``-slot batch compute: b/4,
+    b/2 and b, keeping those that are a whole multiple of ``multiple``
+    (the batch-sharding degree, so a sharded batch still partitions)."""
+    return tuple(sorted({r for r in (b // 4, b // 2, b)
+                         if r == b or (r >= 1 and r % multiple == 0)}))
+
+
+def bucket_rows(rows, b: int, multiple: int = 1) -> np.ndarray:
+    """Slot rows ``rows`` padded to the smallest row bucket that holds
+    them; filler entries equal ``b``, and the executables drop their
+    updates and send their page blocks to trash."""
+    rows = np.asarray(rows, np.int32).reshape(-1)
+    size = next(r for r in row_buckets(b, multiple) if r >= len(rows))
+    out = np.full((size,), b, np.int32)
+    out[: len(rows)] = rows
+    return out
+
+
+def _row_shards(x) -> int:
+    """How many ways ``x``'s leading (batch) axis is split over devices."""
+    sharding = getattr(x, "sharding", None)
+    if sharding is None:
+        return 1
+    return x.shape[0] // sharding.shard_shape(x.shape)[0]
+
+
+def _admit_rows(params, cfg: ModelConfig, rows, prompts, lens,
+                last_logits, positions, done):
+    """Traced refill prefill shared by the fused executables: prefill the
+    bucketed prompts (R, W) at their true lengths, and reset the slots
+    ``rows`` (R,) to them — last logits, position, not done; filler
+    entries (== b) are dropped.  Returns the prompts' prompt-sized caches
+    (R rows) for the caller to merge into its own layout."""
+    last_new, new = M.prefill(params, cfg, {"tokens": prompts}, lens)
+    last_logits = last_logits.at[rows].set(last_new.astype(jnp.float32),
+                                           mode="drop")
+    positions = positions.at[rows].set(lens.astype(jnp.int32), mode="drop")
+    done = done.at[rows].set(False, mode="drop")
+    return new, last_logits, positions, done
+
+
 @functools.partial(jax.jit, static_argnums=(1, 5, 6, 7))
 def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
                         steps: int, temperature: float, stop_at_eos: bool,
-                        positions, done, refill_mask, refill_prompts,
+                        positions, done, refill_rows, refill_prompts,
                         refill_lens):
     """``_scan_decode`` with slot refill fused into the same executable.
 
-    ``refill_prompts`` is a **slot-aligned** (b, W) token matrix: row i
-    replaces slot i's request iff ``refill_mask[i]``; ``refill_lens`` (b,)
-    gives each refill prompt's true length (ignored where the mask is
-    False).  The prompts are prefilled, their caches grown to decode
-    capacity and merged under the mask, and the masked rows' position /
-    done / last-logits reset — then the segment scan runs.  One executable
-    launch admits every slot drained at a boundary *and* decodes the next
-    segment; the per-row math is identical to a separate
-    ``refill_slots`` + ``_scan_decode`` pair (asserted bit-exactly in the
-    tests), the fusion only removes per-boundary launch overhead.
+    ``refill_prompts`` (R, W) are the admitted prompts compacted into a
+    row bucket and ``refill_rows`` (R,) their slot rows (``bucket_rows``;
+    filler entries equal b); ``refill_lens`` (R,) gives each prompt's
+    true length.  The prompts are prefilled, their caches grown to decode
+    capacity and written to their slots, and those slots' position /
+    done / last-logits reset — then the segment scan runs over all b
+    slots.  One executable launch admits every slot drained at a boundary
+    *and* decodes the next segment; the per-row math is identical to a
+    separate ``refill_slots`` + ``_scan_decode`` pair (asserted
+    bit-exactly in the tests), the fusion only removes per-boundary
+    launch overhead.
     """
     COMPILE_COUNTS["refill_scan_decode"] += 1   # traced once per compile
 
     def merge(old, new):
-        shape = [1] * old.ndim
-        shape[CACHE_BATCH_AXIS] = old.shape[CACHE_BATCH_AXIS]
-        return jnp.where(refill_mask.reshape(shape), new.astype(old.dtype),
-                         old)
+        return old.at[:, refill_rows].set(new.astype(old.dtype), mode="drop")
 
     with jax.named_scope("prefill"):
-        last_new, new_caches = M.prefill(
-            params, cfg, {"tokens": refill_prompts}, refill_lens)
+        new_caches, last_logits, positions, done = _admit_rows(
+            params, cfg, refill_rows, refill_prompts, refill_lens,
+            last_logits, positions, done)
         new_caches = jax.tree_util.tree_map_with_path(_grow_to, new_caches,
                                                       caches)
         caches = jax.tree.map(merge, caches, new_caches)
-        last_logits = jnp.where(refill_mask[:, None],
-                                last_new.astype(jnp.float32), last_logits)
-        positions = jnp.where(refill_mask, refill_lens.astype(jnp.int32),
-                              positions)
-        done = jnp.where(refill_mask, False, done)
     out = _run_scan(params, cfg, last_logits, caches, key, steps,
                     temperature, stop_at_eos, positions, done)
     return out + (positions,)
@@ -313,6 +356,27 @@ def _paged_prefill(params, cfg: ModelConfig, tokens, n_pages_total: int,
             caches, storage_of, page_ids, page_size)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _paged_open(params, cfg: ModelConfig, batch: int, n_pages_total: int,
+                page_size: int):
+    """Zeroed paged storage for ``cfg``'s decode caches, laid out as
+    ``_paged_prefill`` leaves it, and zero (batch, V) last logits: a state
+    opened with nothing prefilled."""
+    COMPILE_COUNTS["paged_open"] += 1       # traced once per compilation
+    logits, caches = jax.eval_shape(
+        lambda p: M.prefill(p, cfg, {"tokens": jnp.zeros((1, page_size),
+                                                         jnp.int32)}),
+        params)
+
+    def storage(leaf):
+        count, _, hkv, _, hd = leaf.shape
+        return jnp.zeros((count, n_pages_total, hkv, page_size, hd),
+                         leaf.dtype)
+
+    return (jnp.zeros((batch, logits.shape[-1]), jnp.float32),
+            jax.tree.map(storage, caches))
+
+
 @functools.partial(jax.jit, static_argnums=(1, 3))
 def _paged_refill_prefill(params, cfg: ModelConfig, tokens, page_size: int,
                           page_ids, caches, lens):
@@ -358,11 +422,15 @@ def _paged_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
 def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
                               key, steps: int, temperature: float,
                               stop_at_eos: bool, spec, table, positions,
-                              done, refill_mask, refill_prompts,
+                              done, refill_rows, refill_prompts,
                               refill_lens, refill_page_ids):
-    """``_refill_scan_decode`` over the paged layout: prefill the refill
-    prompts, scatter their page blocks into the pool storage (masked-out
-    rows scatter to trash), reset the masked rows, then run the segment."""
+    """``_refill_scan_decode`` over the paged layout, prefilling only the
+    admitted rows: ``refill_prompts`` (R, W) are the admitted prompts
+    compacted into a row bucket, ``refill_rows`` (R,) their slot rows
+    (``bucket_rows``; filler entries equal b).  Prefill the R rows,
+    scatter their page blocks into the pool storage (filler rows scatter
+    to trash), reset the admitted slots, then run the segment over all b
+    slots."""
     COMPILE_COUNTS["paged_refill_scan_decode"] += 1
     flat_cache = {}
 
@@ -372,16 +440,12 @@ def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
 
     jax.tree_util.tree_map_with_path(name_leaf, caches)
     with jax.named_scope("prefill"):
-        last_new, new = M.prefill(params, cfg, {"tokens": refill_prompts},
-                                  refill_lens)
+        new, last_logits, positions, done = _admit_rows(
+            params, cfg, refill_rows, refill_prompts, refill_lens,
+            last_logits, positions, done)
         caches = _scatter_prefill_caches(
             new, lambda path, leaf: flat_cache[jax.tree_util.keystr(path)],
             refill_page_ids, spec.page_size)
-        last_logits = jnp.where(refill_mask[:, None],
-                                last_new.astype(jnp.float32), last_logits)
-        positions = jnp.where(refill_mask, refill_lens.astype(jnp.int32),
-                              positions)
-        done = jnp.where(refill_mask, False, done)
     out = _run_scan(params, cfg, last_logits, caches, key, steps,
                     temperature, stop_at_eos, positions, done,
                     paged=(spec, table))
@@ -415,6 +479,10 @@ class DecodeState:
     max_len: int                    # per-row cache capacity (slots)
     used: int                       # host upper bound of max(positions)
     paged: Optional[PagedKV] = None
+    # rows the prefill of the launch that made this state computed (0 for
+    # a plain segment), and the batch-sharding degree its row buckets keep
+    prefill_rows: int = 0
+    row_shards: int = 1
 
     @property
     def batch(self) -> int:
@@ -425,8 +493,7 @@ def prefill_state(params, cfg: ModelConfig, prompts, *,
                   max_new_tokens: int, prompt_lens=None,
                   rng: Optional[jax.Array] = None,
                   kv_pool: Optional[KVPool] = None,
-                  kv_kernel: KernelType = KernelType.XLA,
-                  kv_active=None) -> DecodeState:
+                  kv_kernel: KernelType = KernelType.XLA) -> DecodeState:
     """Batch prefill into a ``DecodeState`` sized for ``max_new_tokens``.
 
     ``prompts``: (b, L) int32, right-padded.  ``prompt_lens`` (b,) gives
@@ -438,10 +505,8 @@ def prefill_state(params, cfg: ModelConfig, prompts, *,
     ``kv_pool`` backs the state with the block-paged KV layout instead of
     a dense O(b x max_len) allocation: each admitted row reserves its own
     worst case (``len + max_new_tokens`` tokens, page-rounded) and pages
-    materialize only as positions advance.  ``kv_active`` (b,) bool marks
-    the rows to admit (None = all); inactive rows own no pages — their
-    tables point at the trash page and their decoded tokens are garbage
-    to discard, exactly like a dense free slot.  ``kv_kernel`` selects the
+    materialize only as positions advance (``open_state`` opens one with
+    no row admitted, for the refill serve path).  ``kv_kernel`` selects the
     paged attention implementation (``KernelType.XLA`` is bit-identical
     to dense; PALLAS is the TPU kernel, interpreted on CPU).
     """
@@ -471,34 +536,117 @@ def prefill_state(params, cfg: ModelConfig, prompts, *,
     paged = None
     if kv_pool is not None:
         check_paged_support(cfg)
-        if kv_pool.page_size > max_len:
-            raise ValueError(
-                f"kv_page_size {kv_pool.page_size} exceeds the row "
-                f"capacity {max_len} — a page would never fill")
-        paged = kv_pool.attach(b, kv_cap=max_len,
-                               budget_steps=int(max_new_tokens),
-                               kernel=kv_kernel)
-        active = (np.ones((b,), bool) if kv_active is None
-                  else np.asarray(kv_active, bool).reshape(-1))
-        if active.shape != (b,):
-            raise ValueError(f"kv_active shape {active.shape} != ({b},)")
+        paged = _attach(kv_pool, b, max_len, max_new_tokens, kv_kernel)
         row_lens = np.full((b,), lp, np.int64) if lens is None else lens
-        for i in np.flatnonzero(active):
-            paged.admit_row(int(i), int(row_lens[i]))
+        for i in range(b):
+            paged.admit_row(i, int(row_lens[i]))
     positions = (jnp.full((b,), lp, jnp.int32) if lens is None
                  else jnp.asarray(lens, jnp.int32))
     if paged is not None:
         npg = _ceil_div(lp, paged.page_size)
-        ids = jnp.asarray(paged.prompt_page_ids(active, npg).reshape(-1))
+        ids = paged.prompt_page_ids(np.arange(b), npg).reshape(-1)
         last, caches = _paged_prefill(params, cfg, prompts,
                                       kv_pool.n_pages + 1,
-                                      paged.page_size, ids, positions)
+                                      paged.page_size, jnp.asarray(ids),
+                                      positions)
     else:
         last, caches = _prefill(params, cfg, prompts, positions)
         caches = _pad_caches(caches, max_len, lp)
     return DecodeState(caches, last, positions,
                        done=jnp.zeros((b,), bool), key=rng,
-                       max_len=max_len, used=lp, paged=paged)
+                       max_len=max_len, used=lp, paged=paged,
+                       prefill_rows=b, row_shards=_row_shards(prompts))
+
+
+def _attach(kv_pool: KVPool, b: int, max_len: int, max_new_tokens: int,
+            kv_kernel: KernelType) -> PagedKV:
+    """Page tables for a b-row paged state of ``max_len`` slots a row."""
+    if kv_pool.page_size > max_len:
+        raise ValueError(
+            f"kv_page_size {kv_pool.page_size} exceeds the row "
+            f"capacity {max_len} — a page would never fill")
+    return kv_pool.attach(b, kv_cap=max_len,
+                          budget_steps=int(max_new_tokens), kernel=kv_kernel)
+
+
+def open_state(params, cfg: ModelConfig, prompts, *, max_new_tokens: int,
+               kv_pool: KVPool, kv_kernel: KernelType = KernelType.XLA,
+               rng: Optional[jax.Array] = None) -> DecodeState:
+    """A paged ``DecodeState`` over a slot batch shaped (b, L) like
+    ``prompts``, with no row admitted and nothing prefilled.
+
+    Every slot starts free — zero logits, its table on the trash page —
+    and is admitted by a refill: the opening prompts ride the first
+    ``decode_segment(refill=...)`` launch, prefilled in the fused
+    executable's row bucket with its first segment.  ``prompts`` gives
+    the batch shape and its placement (a sharded batch keeps row buckets
+    that partition); its tokens are not read.  Sizes and guards are
+    ``prefill_state``'s.
+    """
+    check_paged_support(cfg)
+    b, lp = np.shape(prompts)
+    max_len = lp + int(max_new_tokens)
+    paged = _attach(kv_pool, b, max_len, max_new_tokens, kv_kernel)
+    last, caches = _paged_open(params, cfg, b, kv_pool.n_pages + 1,
+                               kv_pool.page_size)
+    return DecodeState(caches, last, jnp.full((b,), lp, jnp.int32),
+                       done=jnp.zeros((b,), bool), key=rng, max_len=max_len,
+                       used=lp, paged=paged, row_shards=_row_shards(prompts))
+
+
+def _refill_operands(rows: np.ndarray, prompts: np.ndarray,
+                     lens: np.ndarray) -> tuple:
+    """The bucketed slot rows ``rows`` (``bucket_rows``) with their
+    prompts and true lengths compacted from the slot-aligned (b, W)
+    ``prompts`` and (b,) ``lens`` — filler entries take the last slot's —
+    as the fused refill executables take them."""
+    take = functools.partial(np.take, indices=rows, axis=0, mode="clip")
+    return (jnp.asarray(rows), jnp.asarray(take(prompts)),
+            jnp.asarray(take(lens), jnp.int32))
+
+
+# signatures (static arguments, input shapes, dtypes and placements) whose
+# row buckets ``warm_row_buckets`` has already run: process-wide, as jit's
+# own cache of executables is, which is what it stands for
+_WARMED: set = set()
+
+
+def _signature(*trees) -> tuple:
+    return tuple((tuple(x.shape), str(x.dtype), getattr(x, "sharding", None))
+                 for x in jax.tree.leaves(trees))
+
+
+def warm_row_buckets(params, cfg: ModelConfig, state: DecodeState,
+                     width: int, steps: int) -> None:
+    """Run every row bucket of the paged fused refill once, so that no
+    later refill of this state's shapes compiles.
+
+    ``state`` is a paged state as opened (``open_state``), ``width`` its
+    refill prompts' width and ``steps`` its segment length; the launches
+    sample as ``decode_segment`` does by default (greedy, stopping at
+    EOS).  Every row of them is filler — no update lands, every page
+    block goes to trash — and their outputs are dropped, so ``state`` is
+    untouched.  A signature already warmed in this process is skipped.
+    """
+    pg = state.paged
+    b = state.batch
+    # scopelint: allow[serve-time-nondeterminism] -- the key decode_segment passes a greedy state; these launches' outputs are dropped
+    key = state.key if state.key is not None else jax.random.PRNGKey(0)
+    sig = (cfg, int(width), int(steps), pg.spec, pg.pool.n_pages,
+           _signature(params, state.caches, state.last_logits, key))
+    if sig in _WARMED:
+        return
+    _WARMED.add(sig)
+    pad = np.full((b, width), PAD, np.int32)
+    for size in row_buckets(b, state.row_shards):
+        rows = np.full((size,), b, np.int32)
+        # one at a time: each holds a copy of the pool until it ends
+        jax.block_until_ready(_paged_refill_scan_decode(
+            params, cfg, state.last_logits, state.caches, key, int(steps),
+            0.0, True, pg.spec, pg.device_table(), state.positions,
+            state.done, *_refill_operands(rows, pad, np.ones((b,), np.int64)),
+            jnp.asarray(pg.prompt_page_ids(
+                rows, _ceil_div(width, pg.page_size)).reshape(-1))))
 
 
 def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
@@ -515,12 +663,13 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
 
     ``refill`` = (mask (b,), prompts (b, W), prompt_lens (b,)) admits new
     requests into the masked slots **in the same executable launch**: the
-    slot-aligned prompts are prefilled (right-padded to width W, true
-    lengths in ``prompt_lens``) and the masked rows reset to decode from
-    their own prompt before the segment runs — bit-identical to
-    ``refill_slots`` followed by a plain segment, minus the per-boundary
-    launch overhead.  The same attention-backbone restriction applies to
-    padded refill prompts.
+    masked rows' prompts are compacted into the smallest row bucket that
+    holds them (``bucket_rows``) and prefilled (right-padded to width W,
+    true lengths in ``prompt_lens``), and the masked rows reset to decode
+    from their own prompt before the segment runs — bit-identical to
+    ``refill_slots`` of the same rows followed by a plain segment, minus
+    the per-boundary launch overhead.  The same attention-backbone
+    restriction applies to padded refill prompts.
     """
     steps = int(steps)
     pg = state.paged
@@ -577,7 +726,11 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
                              "refill=None for a plain segment")
         _check_refill_lens(cfg, state, width, lens[mask])
         mlens = lens[mask]
-        lens = np.where(mask, lens, 1)      # unmasked rows: any valid index
+        lens = np.where(mask, lens, 1)      # filler rows: any valid length
+        # prefill the admitted rows only, in the smallest bucket that holds
+        # them
+        rows = bucket_rows(np.flatnonzero(mask), b, state.row_shards)
+        operands = _refill_operands(rows, prompts, lens)
         if pg is not None:
             # host-side admission before the launch: release whatever the
             # refilled slots still hold (no-op if the serve loop retired
@@ -590,26 +743,26 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
                     pg.admit_row(int(i), int(lens[i]))
             pg.check_steps(steps)
             npg = _ceil_div(width, pg.page_size)
-            ids = jnp.asarray(pg.prompt_page_ids(mask, npg).reshape(-1))
+            ids = jnp.asarray(pg.prompt_page_ids(rows, npg).reshape(-1))
             pg.ensure(steps)
             (gen, dec, last, caches, done, key,
              positions) = _paged_refill_scan_decode(
                 params, cfg, state.last_logits, state.caches, key, steps,
                 float(temperature), bool(stop_at_eos), pg.spec,
-                pg.device_table(), state.positions, state.done,
-                jnp.asarray(mask), jnp.asarray(prompts),
-                jnp.asarray(lens, jnp.int32), ids)
+                pg.device_table(), state.positions, state.done, *operands,
+                ids)
         else:
             gen, dec, last, caches, done, key, positions = \
                 _refill_scan_decode(
                     params, cfg, state.last_logits, state.caches, key, steps,
                     float(temperature), bool(stop_at_eos), state.positions,
-                    state.done, jnp.asarray(mask), jnp.asarray(prompts),
-                    jnp.asarray(lens, jnp.int32))
+                    state.done, *operands)
         used = max(state.used, int(mlens.max()))
     new = DecodeState(caches, last, positions + steps, done,
                       key if state.key is not None else None,
-                      state.max_len, used + steps, paged=pg)
+                      state.max_len, used + steps, paged=pg,
+                      prefill_rows=0 if refill is None else len(rows),
+                      row_shards=state.row_shards)
     return new, gen, dec
 
 
@@ -668,9 +821,9 @@ def refill_slots(params, cfg: ModelConfig, state: DecodeState,
         npg = _ceil_div(width, pg.page_size)
         # prompt-row j's page blocks land in slot rows[j]'s fresh pages;
         # filler prompt rows (j >= r) scatter to trash
-        ids = np.full((p, npg), pg.pool.trash_page, np.int32)
-        for j, row in enumerate(rows):
-            ids[j] = pg.table[row, :npg]
+        ids = pg.prompt_page_ids(
+            np.concatenate([rows, np.full(p - r, state.batch, np.int32)]),
+            npg)
         last, merged = _paged_refill_prefill(
             params, cfg, jnp.asarray(arr), pg.page_size,
             jnp.asarray(ids.reshape(-1)), state.caches, plens)

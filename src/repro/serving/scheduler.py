@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from collections import OrderedDict, deque
+from collections import Counter, OrderedDict, deque
 from typing import (
     Any, Callable, Deque, Dict, Iterator, List, Optional, Sequence, Tuple)
 
@@ -68,6 +68,7 @@ def decode_compile_counts() -> Dict[str, int]:
             "refill_scan_decode":
                 int(sampler.COMPILE_COUNTS["refill_scan_decode"]),
             "paged_prefill": int(sampler.COMPILE_COUNTS["paged_prefill"]),
+            "paged_open": int(sampler.COMPILE_COUNTS["paged_open"]),
             "paged_scan_decode":
                 int(sampler.COMPILE_COUNTS["paged_scan_decode"]),
             "paged_refill_prefill":
@@ -144,7 +145,9 @@ class SchedulerStats:
     slot_steps_total: int = 0       # batch x decode-steps actually run
     slot_steps_active: int = 0      # of those, steps holding a live request
     prefill_rows: int = 0           # rows the prefill-bearing launches
-    #                                 computed (their whole batch each)
+    #                                 computed (each its row bucket)
+    prefill_launches_by_rows: Counter = dataclasses.field(
+        default_factory=Counter)    # rows computed -> launch count
     # paged-KV accounting (segment granularity, folded in by
     # SlotRun.account / SlotRuntime._admit).  pages_in_use / kv_live_tokens
     # are gauges (last retire's snapshot); the peaks are monotonic maxima.
@@ -269,6 +272,8 @@ class SchedulerStats:
                 "slot_steps": {"total": self.slot_steps_total,
                                "active": self.slot_steps_active},
                 "prefill_rows": self.prefill_rows,
+                "prefill_launches_by_rows":
+                    dict(sorted(self.prefill_launches_by_rows.items())),
                 "slot_occupancy": round(self.slot_occupancy, 4),
                 "kv_pages": {"page_size": self.kv_page_size,
                              "in_use": self.pages_in_use,

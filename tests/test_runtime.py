@@ -1,6 +1,8 @@
 """Continuous-batching serve runtime: per-row ragged decode, DecodeState
 segments + slot refill, the double-buffered ServeRuntime, and the engine's
 overlapped predict_stream (incl. ragged-length grid parity)."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -202,7 +204,9 @@ def test_refill_slots_batched_matches_sequential(tiny_trained):
 def test_decode_segment_fused_refill_matches_unfused(tiny_trained):
     """decode_segment(refill=(mask, prompts, lens)) — prefill + merge +
     scan in one executable — is bit-identical to refill_slots followed by
-    a plain segment (tokens AND decision logits: same math, one launch)."""
+    a plain segment (tokens AND decision logits: same math, one launch).
+    The fused launch prefills the two admitted rows as a 2-row bucket, so
+    the unfused prefill gets the same two rows."""
     cfg, params, _ = tiny_trained
     rng = np.random.default_rng(13)
     prompts = rng.integers(3, 100, size=(4, 18)).astype(np.int32)
@@ -215,8 +219,7 @@ def test_decode_segment_fused_refill_matches_unfused(tiny_trained):
     mat[1, :12] = fresh[0]
     mat[3, :12] = fresh[1]
     s_ref = sampler.refill_slots(params, cfg, state, [1, 3],
-                                 np.concatenate([mat[1:2], mat[3:4],
-                                                 mat[:2] * 0]),
+                                 np.concatenate([mat[1:2], mat[3:4]]),
                                  prompt_lens=[12, 12])
     s_ref, g_ref, d_ref = sampler.decode_segment(params, cfg, s_ref, 4)
 
@@ -945,6 +948,150 @@ def test_slot_run_paged_admission_gates_on_pages(tiny_trained):
     while not run.finished:
         run.step()
     assert pool.pages_in_use == 0 and pool.reserved == 0
+
+
+# ---------------------------------------------------------------------------
+# Row buckets: the paged prefills compute the admitted rows only
+# ---------------------------------------------------------------------------
+def test_row_bucket_rule():
+    """The smallest of b/4, b/2 and b that holds the admitted rows; a full
+    admission takes the whole batch; a sharded batch keeps buckets that
+    are a multiple of its sharding degree; filler entries equal b."""
+    assert sampler.row_buckets(64) == (16, 32, 64)
+    for n, want in [(1, 16), (16, 16), (17, 32), (32, 32), (33, 64),
+                    (64, 64)]:
+        rows = sampler.bucket_rows(np.arange(n), 64)
+        assert len(rows) == want, n
+        assert rows[:n].tolist() == list(range(n))
+        assert (rows[n:] == 64).all()
+    assert sampler.row_buckets(8) == (2, 4, 8)
+    assert sampler.row_buckets(8, 4) == (4, 8)
+    assert sampler.row_buckets(4, 4) == (4,)
+    assert sampler.row_buckets(3) == (1, 3)
+    assert sampler.bucket_rows([6, 1, 3], 8).tolist() == [6, 1, 3, 8]
+
+
+def _pool(b, width, page_size=8, budget=12):
+    from repro.serving.kv_pool import KVPool
+    return KVPool(n_pages=b * -(-(width + budget) // page_size),
+                  page_size=page_size)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 5, 8])     # 1, b/4, b/4+1, b/2+1, b
+def test_row_bucket_prefills_match_slot_aligned(tiny_trained, n):
+    """Opening a state whose first launch admits n of b = 8 slots, and a
+    fused refill launch admitting n slots of a full state, serve the same
+    tokens as today's slot-aligned computation, which prefills all b rows.
+    Decision logits are bit-identical where the bucket is the whole batch,
+    and within 1e-6 (absolute and relative) where it is smaller: the CPU
+    backend blocks a matrix product by its row count, so a smaller batch
+    rounds its sums in another order."""
+    cfg, params, _ = tiny_trained
+    b, width = 8, 16
+    rng = np.random.default_rng(40 + n)
+    prompts = rng.integers(3, 100, size=(b, width)).astype(np.int32)
+    lens = rng.integers(width // 2, width + 1, size=b)
+    admitted = np.zeros(b, bool)
+    admitted[rng.choice(b, n, replace=False)] = True
+    bucket = min(r for r in (2, 4, 8) if r >= n)
+    if bucket == b:
+        close = np.testing.assert_array_equal
+    else:
+        close = functools.partial(np.testing.assert_allclose, atol=1e-6,
+                                  rtol=1e-6)
+
+    # opening: nothing prefilled, the admitted rows ride the first launch;
+    # against a prefill of all b rows and a plain segment
+    got = sampler.open_state(params, cfg, prompts, max_new_tokens=12,
+                             kv_pool=_pool(b, width))
+    assert got.prefill_rows == 0
+    got, g1, d1 = sampler.decode_segment(params, cfg, got, 4,
+                                         refill=(admitted, prompts, lens))
+    want = sampler.prefill_state(params, cfg, prompts, max_new_tokens=12,
+                                 prompt_lens=lens, kv_pool=_pool(b, width))
+    assert (got.prefill_rows, want.prefill_rows) == (bucket, b)
+    want, g2, d2 = sampler.decode_segment(params, cfg, want, 4)
+    np.testing.assert_array_equal(np.asarray(g1)[admitted],
+                                  np.asarray(g2)[admitted])
+    close(np.asarray(d1)[admitted], np.asarray(d2)[admitted])
+    np.testing.assert_array_equal(np.asarray(got.positions)[admitted],
+                                  np.asarray(want.positions)[admitted])
+
+    # fused refill of the same slots on a full state, against the unfused
+    # refill of a slot-aligned (b, W) matrix followed by a plain segment
+    fresh = rng.integers(3, 100, size=(b, width)).astype(np.int32)
+    flens = rng.integers(width // 2, width + 1, size=b)
+    rows = np.flatnonzero(admitted)
+    full = np.zeros((b, width), np.int32)
+    full[: len(rows)] = fresh[rows]
+    states = []
+    for _ in range(2):
+        st = sampler.prefill_state(params, cfg, prompts, max_new_tokens=12,
+                                   prompt_lens=lens, kv_pool=_pool(b, width))
+        st, _, _ = sampler.decode_segment(params, cfg, st, 4)
+        states.append(st)
+    fused, g1, d1 = sampler.decode_segment(
+        params, cfg, states[0], 4, refill=(admitted, fresh, flens))
+    ref = sampler.refill_slots(params, cfg, states[1], rows, full,
+                               prompt_lens=flens[rows])
+    ref, g2, d2 = sampler.decode_segment(params, cfg, ref, 4)
+    assert fused.prefill_rows == bucket
+    np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
+    close(np.asarray(d1), np.asarray(d2))
+    np.testing.assert_array_equal(np.asarray(fused.positions),
+                                  np.asarray(ref.positions))
+
+
+def test_row_buckets_warm_at_first_open(tiny_trained):
+    """The first paged open runs every row bucket of the fused refill;
+    after it, opens and refills that hit every bucket compile nothing,
+    and the counters record each launch's bucket."""
+    from repro.serving.scheduler import SchedulerStats
+    cfg, params, _ = tiny_trained
+    b, width, budget = 8, 22, 8          # a width no other test compiles
+    est = ReasoningEstimator(cfg, params, max_new_tokens=budget)
+    pool = _pool(b, width, budget=budget)
+    rng = np.random.default_rng(50)
+    prompts = rng.integers(3, 100, size=(b, width)).astype(np.int32)
+    before = dict(sampler.COMPILE_COUNTS)
+    run = est.open_slots(prompts, tags=["o"], kv_pool=pool, segment_len=4)
+    warm = {k: v - before.get(k, 0) for k, v in sampler.COMPILE_COUNTS.items()
+            if v != before.get(k, 0)}
+    # one refill executable per bucket (the storage one may be shared)
+    assert warm["paged_refill_scan_decode"] == 3
+    assert set(warm) <= {"paged_open", "paged_refill_scan_decode"}
+    after_open = dict(sampler.COMPILE_COUNTS)
+
+    def prompt():
+        return list(rng.integers(3, 100, size=int(rng.integers(8, width + 1))))
+
+    served = len(run.step()[0])          # the opening row: bucket 2
+    for k in (2, 3, 6, 1):               # buckets 2, 4, 8, 2
+        while len(run.free_rows()) < k:
+            served += len(run.step()[0])
+        run.admit([(f"{k}.{i}", p, len(p))
+                   for i, p in enumerate(prompt() for _ in range(k))])
+        served += len(run.step()[0])
+    while not run.finished:
+        served += len(run.step()[0])
+    assert served == 1 + 2 + 3 + 6 + 1
+    assert run.prefill_launches_by_rows == {2: 3, 4: 1, 8: 1}
+    assert run.prefill_rows == 2 * 3 + 4 + 8
+    stats = SchedulerStats()
+    run.account(stats)
+    assert stats.prefill_launches_by_rows == {2: 3, 4: 1, 8: 1}
+    assert stats.prefill_rows == run.prefill_rows
+    for n in (3, 5, 8):                  # opens at buckets 4, 8, 8
+        run = est.open_slots(prompts, tags=list(range(n)), kv_pool=pool,
+                             segment_len=4)
+        while not run.finished:
+            run.step()
+        assert run.prefill_launches_by_rows[min(r for r in (2, 4, 8)
+                                                if r >= n)] == 1
+    # (the plain segment compiles at its first use, as without buckets)
+    opened = ("paged_open", "paged_refill_scan_decode")
+    assert {k: sampler.COMPILE_COUNTS[k] for k in opened} == \
+        {k: after_open[k] for k in opened}
 
 
 def test_stream_paged_matches_dense_refill(real_engine):

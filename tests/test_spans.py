@@ -140,13 +140,13 @@ def _paged_args(params, b=4, width=16, steps=3):
     state = sampler.prefill_state(
         params, TINY, tokens, max_new_tokens=6,
         kv_pool=KVPool(n_pages=b * 4, page_size=8),
-        kv_kernel=KernelType.XLA, kv_active=np.ones(b, bool))
+        kv_kernel=KernelType.XLA)
     pg = state.paged
     head = (params, TINY, state.last_logits, state.caches,
             jax.random.PRNGKey(0), steps, 0.0, True, pg.spec,
             pg.device_table(), state.positions, state.done)
     npg = -(-width // pg.page_size)
-    refill = (jnp.ones((b,), bool), jnp.asarray(tokens),
+    refill = (jnp.arange(b, dtype=jnp.int32), jnp.asarray(tokens),
               jnp.full((b,), width, jnp.int32),
               jnp.zeros((b * npg,), jnp.int32))
     return {"_paged_scan_decode": head,
@@ -208,26 +208,28 @@ def test_named_scopes_change_metadata_only(tiny_params, monkeypatch):
     jax.tree.map(np.testing.assert_array_equal, scoped_out, plain_out)
 
 
-def _stream_counting(make, ticks, monkeypatch):
-    """Run the stream; count prefill-bearing launches and their rows, and
-    snapshot the stats against the live runs after every request."""
-    est, engine = make()
-    launches = {"n": 0, "rows": 0}
-    prefill_state, decode_segment = (sampler.prefill_state,
-                                     sampler.decode_segment)
+def _bucket(n: int, b: int = 8) -> int:
+    """The rows a prefill admitting n of b slots computes: the smallest of
+    b/4, b/2 and b that holds them."""
+    return min(r for r in (b // 4, b // 2, b) if r >= n)
 
-    def counted_prefill(params, cfg, prompts, **kw):
-        launches["n"] += 1
-        launches["rows"] += np.asarray(prompts).shape[0]
-        return prefill_state(params, cfg, prompts, **kw)
+
+def _stream_counting(make, ticks, monkeypatch):
+    """Run the stream; count prefill-bearing launches and the rows their
+    buckets compute, and snapshot the stats against the live runs after
+    every request."""
+    est, engine = make()
+    launches = {"n": 0, "rows": 0, "by_rows": {}}
+    decode_segment = sampler.decode_segment
 
     def counted_segment(params, cfg, state, steps, **kw):
         if kw.get("refill") is not None:
+            rows = _bucket(int(np.sum(kw["refill"][0])), state.batch)
             launches["n"] += 1
-            launches["rows"] += state.batch
+            launches["rows"] += rows
+            launches["by_rows"][rows] = launches["by_rows"].get(rows, 0) + 1
         return decode_segment(params, cfg, state, steps, **kw)
 
-    monkeypatch.setattr(sampler, "prefill_state", counted_prefill)
     monkeypatch.setattr(sampler, "decode_segment", counted_segment)
     sched = _scheduler()
     snapshots = []
@@ -246,11 +248,15 @@ def test_prefill_rows_count_every_prefill_bearing_launch(stream_setup,
                                                           monkeypatch):
     make, ticks = stream_setup
     est, sched, launches, _ = _stream_counting(make, ticks, monkeypatch)
-    refills = launches["n"] - len(est.runs)
-    assert len(est.runs) >= 1 and refills >= 1
+    # a paged state opens with nothing prefilled: its opening rows ride
+    # its first launch's refill, and later boundaries refill too
+    assert len(est.runs) >= 1 and launches["n"] > len(est.runs)
+    # each launch computed its row bucket, not the whole batch
     assert sched.stats.prefill_rows == launches["rows"]
-    assert sched.stats.prefill_rows == 8 * launches["n"]
     assert sched.stats.as_dict()["prefill_rows"] == launches["rows"]
+    by_rows = sched.stats.as_dict()["prefill_launches_by_rows"]
+    assert by_rows == launches["by_rows"]
+    assert sum(by_rows.values()) == launches["n"]
 
 
 def test_slot_steps_exact_mid_stream_and_unchanged_at_the_end(
