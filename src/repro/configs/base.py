@@ -49,6 +49,14 @@ class ModelConfig:
     # Attention options
     rope_theta: float = 10000.0
     rope_kind: str = "standard"         # standard | mrope | none
+    # YaRN rope scaling (DeepSeek-V2 ``rope_scaling`` type "yarn"); a
+    # factor of 0 leaves the rope and the softmax scale unscaled
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     mrope_sections: Tuple[int, ...] = (16, 24, 24)   # t/h/w head-dim split
     sliding_window: int = 4096          # used by attn_local blocks
     logit_softcap: float = 0.0          # gemma2: 50.0 on attention logits
@@ -69,6 +77,12 @@ class ModelConfig:
     moe_d_ff: int = 0                   # per-expert hidden (0 -> d_ff)
     first_dense_layers: int = 0         # DeepSeek-V2: layer 0 dense
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True         # renormalise the top-k gate weights
+    # the routed experts this device holds: experts [expert_offset,
+    # expert_offset + experts_held) of num_experts (0 = all of them); the
+    # router keeps all num_experts outputs (expert parallelism's share)
+    experts_held: int = 0
+    expert_offset: int = 0
     router_aux_coef: float = 0.01
 
     # SSM (Mamba2 / SSD)
@@ -105,6 +119,11 @@ class ModelConfig:
         for k in self.block_pattern:
             if k not in VALID_BLOCK_KINDS:
                 raise ValueError(f"unknown block kind {k!r}")
+        if self.expert_offset + self.resolved_experts_held > self.num_experts:
+            raise ValueError(
+                f"experts [{self.expert_offset}, {self.expert_offset} + "
+                f"{self.resolved_experts_held}) outside the "
+                f"{self.num_experts} routed experts")
 
     # ------------------------------------------------------------------
     @property
@@ -114,6 +133,10 @@ class ModelConfig:
     @property
     def resolved_moe_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def d_inner(self) -> int:
@@ -169,6 +192,8 @@ class ModelConfig:
                 num_shared_experts=min(self.num_shared_experts, 1),
                 moe_d_ff=d_model * 2,
                 first_dense_layers=min(self.first_dense_layers, 1),
+                experts_held=0,
+                expert_offset=0,
             )
         if self.has_ssm():
             changes.update(
@@ -216,5 +241,5 @@ def shape_applicable(cfg: ModelConfig, shape: InputShape) -> Tuple[bool, str]:
 
 def long_context_variant(cfg: ModelConfig) -> ModelConfig:
     """Sub-quadratic variant for long_500k: every attention layer becomes
-    sliding-window (SSM layers untouched).  Deviation recorded in DESIGN.md."""
+    sliding-window (SSM layers untouched)."""
     return dataclasses.replace(cfg, force_window=cfg.long_context_window)

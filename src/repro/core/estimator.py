@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import Counter
-from typing import List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
@@ -369,7 +369,8 @@ class SlotRun:
             for i in range(len(tags)):
                 self._stage(i, tokens[i, : int(true_lens[i])],
                             int(true_lens[i]))
-        self._inflight: Optional[tuple] = None      # (gen, dec) futures
+        # (gen, dec, launch counters) futures
+        self._inflight: Optional[tuple] = None
         # decode-slot accounting (token granularity; folded into
         # SchedulerStats by ``fold`` at each boundary and ``account``)
         self.slot_steps_total = 0
@@ -383,6 +384,11 @@ class SlotRun:
             self._count_prefill()
         self._folded = (0, 0, 0, 0)
         self._folded_launches: Counter = Counter()
+        # tokens each expert layer routed to each held expert, summed over
+        # the synced launches ({"expert_tokens_prefill"|"_decode":
+        # (expert layers, experts held)}; empty without expert layers)
+        self.expert_tokens: Dict[str, np.ndarray] = {}
+        self._folded_experts: Dict[str, np.ndarray] = {}
 
     def _count_prefill(self) -> None:
         """Count the prefill of the launch that made the current state."""
@@ -542,7 +548,7 @@ class SlotRun:
         if self._pending is not None:
             self._count_prefill()
         self._pending = None
-        self._inflight = (g, d)
+        self._inflight = (g, d, self.state.stats)
         self.steps_run += self.segment_len
         self.slot_steps_total += self.batch * self.segment_len
 
@@ -557,7 +563,7 @@ class SlotRun:
         """
         if self._inflight is None:
             self.launch()
-        g, d = self._inflight
+        g, d, launched = self._inflight
         self._inflight = None
         t0, t1 = self.steps_done, self.steps_done + self.segment_len
         if t1 > self._gen.shape[1]:
@@ -572,6 +578,12 @@ class SlotRun:
                  np.zeros((self.batch, grow, 2), np.float32)], 1)
         self._gen[:, t0:t1] = np.asarray(g)
         self._dec[:, t0:t1] = np.asarray(d)
+        for name, routed in launched.items():
+            # the launch has finished: its counters come back with gen
+            have = self.expert_tokens.get(name)
+            routed = np.asarray(routed, np.int64)
+            self.expert_tokens[name] = routed if have is None \
+                else have + routed
         self.steps_done = t1
         done = np.asarray(self.state.done)
         completed = []
@@ -626,6 +638,12 @@ class SlotRun:
             self.prefill_launches_by_rows - self._folded_launches)
         self._folded = now
         self._folded_launches = self.prefill_launches_by_rows.copy()
+        for name, routed in self.expert_tokens.items():
+            gained = routed - self._folded_experts.get(name, 0)
+            have = getattr(stats, name)
+            setattr(stats, name, gained if have is None else have + gained)
+        self._folded_experts = {k: v.copy()
+                                for k, v in self.expert_tokens.items()}
 
     def account(self, stats) -> None:
         """Fold what is left of this run's counters into

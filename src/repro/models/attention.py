@@ -95,7 +95,7 @@ def gqa_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
     run in **ring-buffer mode**: the new KV lands at ``pos % window`` and
     attention sees min(pos+1, window) valid slots — softmax is permutation
     invariant, so slot order is irrelevant.  This keeps long_500k decode
-    memory/traffic at O(window), not O(context) (EXPERIMENTS.md §Perf HC3).
+    memory/traffic at O(window), not O(context).
     """
     b = x.shape[0]
     hd = cfg.resolved_head_dim
@@ -202,6 +202,16 @@ def cross_attend(p, cfg: ModelConfig, x, kv: Dict) -> jax.Array:
 # ---------------------------------------------------------------------------
 # MLA (DeepSeek-V2 family)
 # ---------------------------------------------------------------------------
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(qk_nope + qk_rope)^-0.5``, times YaRN's ``mscale^2`` when the
+    rope is YaRN-scaled with an ``mscale_all_dim`` (DeepSeek-V2)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        m = rope_mod.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
 def init_mla(key, cfg: ModelConfig) -> Dict:
     d, h = cfg.d_model, cfg.num_heads
     nope, rdim, vdim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -253,12 +263,47 @@ def mla_full(p, cfg: ModelConfig, x, cos, sin, *, kind: str = "mla",
     q = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
     k = jnp.concatenate([k_nope, k_rope_h], -1).transpose(0, 2, 1, 3)
     vh = v.transpose(0, 2, 1, 3)
-    scale = (nope + rdim) ** -0.5
+    scale = mla_softmax_scale(cfg)
     out = ops.flash_attention(q, k, vh, causal=causal, scale=scale,
                               window=resolve_window(cfg, kind),
                               softcap=cfg.logit_softcap)
     y = out.transpose(0, 2, 1, 3).reshape(b, s, h * vdim)
     return y @ p["wo"], {"c_kv": c_kv, "k_rope": k_rope}
+
+
+def _mla_absorbed(p, cfg: ModelConfig, x, q_nope, q_rope, ckv, krope, mask):
+    """Absorbed-form attention of one new token per row over latent rows.
+
+    ``ckv`` (b, S, lora) and ``krope`` (b, S, rdim) are each row's latent
+    cache, ``mask`` (b, 1, S) the positions it may read.  The up
+    projections w_uk / w_uv are folded into the query and the output, so
+    the cache is read once and never re-expanded.  Products over the cache
+    take its own dtype and accumulate in float32.
+    """
+    b = x.shape[0]
+    h = cfg.num_heads
+    nope, vdim = cfg.qk_nope_head_dim, cfg.v_head_dim
+    lora = cfg.kv_lora_rank
+    dt = ckv.dtype
+    # absorb w_uk into q: q_lat[b,h,lora] = sum_n q_nope[b,h,n] w_uk[lora,h,n]
+    w_uk = p["w_uk"].reshape(lora, h, nope)
+    q_lat = jnp.einsum("bhn,lhn->bhl", q_nope[:, 0].astype(jnp.float32),
+                       w_uk.astype(jnp.float32))
+    scale = mla_softmax_scale(cfg)
+    s_lat = jnp.einsum("bhl,bsl->bhs", q_lat.astype(dt), ckv,
+                       preferred_element_type=jnp.float32)
+    s_rope = jnp.einsum("bhr,bsr->bhs", q_rope[:, 0].astype(dt), krope,
+                        preferred_element_type=jnp.float32)
+    s = (s_lat + s_rope) * scale
+    if cfg.logit_softcap > 0.0:
+        s = cfg.logit_softcap * jnp.tanh(s / cfg.logit_softcap)
+    s = jnp.where(mask, s, -1e30)
+    probs = jax.nn.softmax(s, axis=-1)
+    ctx_lat = jnp.einsum("bhs,bsl->bhl", probs.astype(dt), ckv,
+                         preferred_element_type=jnp.float32)
+    w_uv = p["w_uv"].reshape(lora, h, vdim)
+    v_ctx = jnp.einsum("bhl,lhv->bhv", ctx_lat, w_uv.astype(jnp.float32))
+    return v_ctx.reshape(b, 1, h * vdim).astype(x.dtype) @ p["wo"]
 
 
 def mla_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
@@ -272,9 +317,6 @@ def mla_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
     naive form would up-project all S cached entries per token).
     """
     b = x.shape[0]
-    h = cfg.num_heads
-    nope, rdim, vdim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    lora = cfg.kv_lora_rank
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     q_nope, q_rope = _mla_q(p, cfg, x, cos, sin)            # (b,1,h,·)
     c_kv_new, k_rope_new = _mla_compress(p, cfg, x, cos, sin)
@@ -285,28 +327,46 @@ def mla_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
     krope = cache["k_rope"].at[rows, pos].set(
         k_rope_new[:, 0].astype(cache["k_rope"].dtype), unique_indices=True)
 
-    # absorb w_uk into q: q_lat[b,h,lora] = sum_n q_nope[b,h,n] w_uk[lora,h,n]
-    w_uk = p["w_uk"].reshape(lora, h, nope)
-    q_lat = jnp.einsum("bhn,lhn->bhl", q_nope[:, 0].astype(jnp.float32),
-                       w_uk.astype(jnp.float32))
-    scale = (nope + rdim) ** -0.5
-    s_lat = jnp.einsum("bhl,bsl->bhs", q_lat,
-                       ckv.astype(jnp.float32)) * scale
-    s_rope = jnp.einsum("bhr,bsr->bhs", q_rope[:, 0].astype(jnp.float32),
-                        krope.astype(jnp.float32)) * scale
-    s = s_lat + s_rope
-    if cfg.logit_softcap > 0.0:
-        s = cfg.logit_softcap * jnp.tanh(s / cfg.logit_softcap)
     S = ckv.shape[1]
     kpos = jnp.arange(S)[None, None]                    # (1, 1, S)
     mask = kpos <= pos[:, None, None]                   # (b, 1, S)
     window = resolve_window(cfg, kind)
     if window > 0:
         mask = mask & (kpos > pos[:, None, None] - window)
-    s = jnp.where(mask, s, -1e30)
-    probs = jax.nn.softmax(s, axis=-1)
-    ctx_lat = jnp.einsum("bhs,bsl->bhl", probs, ckv.astype(jnp.float32))
-    w_uv = p["w_uv"].reshape(lora, h, vdim)
-    v_ctx = jnp.einsum("bhl,lhv->bhv", ctx_lat, w_uv.astype(jnp.float32))
-    y = v_ctx.reshape(b, 1, h * vdim).astype(x.dtype) @ p["wo"]
+    y = _mla_absorbed(p, cfg, x, q_nope, q_rope, ckv, krope, mask)
     return y, {"c_kv": ckv, "k_rope": krope}
+
+
+def mla_decode_paged(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
+                     table, spec) -> Tuple[jax.Array, Dict]:
+    """Absorbed-form MLA decode against block-paged latent pages.
+
+    cache["c_kv"]: (n_pages, page_size, lora) and cache["k_rope"]:
+    (n_pages, page_size, rdim), physical pages shared by the batch, as
+    ``gqa_decode_paged`` keeps K and V; ``table`` (b, W) and ``spec`` are
+    the same.  The new token's latent lands in slot ``pos % page_size`` of
+    page ``table[row, pos // page_size]`` (clamped, so retired rows write
+    to the trash page and the scatter must not claim unique indices); each
+    row then reads its pages up to ``kv_cap`` (the ``latent`` scope) and
+    attends to positions <= ``pos``.
+    """
+    b = x.shape[0]
+    ps = spec.page_size
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
+    q_nope, q_rope = _mla_q(p, cfg, x, cos, sin)            # (b,1,h,·)
+    c_kv_new, k_rope_new = _mla_compress(p, cfg, x, cos, sin)
+    lp = jnp.minimum(pos // ps, table.shape[1] - 1)
+    pid = table[jnp.arange(b), lp]                       # (b,)
+    slot = pos % ps
+    ckv_pages = cache["c_kv"].at[pid, slot].set(
+        c_kv_new[:, 0].astype(cache["c_kv"].dtype))
+    krope_pages = cache["k_rope"].at[pid, slot].set(
+        k_rope_new[:, 0].astype(cache["k_rope"].dtype))
+    with jax.named_scope("latent"):
+        width = table.shape[1] * ps
+        ckv = ckv_pages[table].reshape(b, width, -1)[:, :spec.kv_cap]
+        krope = krope_pages[table].reshape(b, width, -1)[:, :spec.kv_cap]
+    kpos = jnp.arange(ckv.shape[1])[None, None]          # (1, 1, S)
+    mask = kpos <= pos[:, None, None]                    # (b, 1, S)
+    y = _mla_absorbed(p, cfg, x, q_nope, q_rope, ckv, krope, mask)
+    return y, {"c_kv": ckv_pages, "k_rope": krope_pages}

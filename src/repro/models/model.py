@@ -7,6 +7,11 @@
   decode_step(params, cfg, token, caches, pos) -> (logits, caches)
   init_cache(cfg, batch_size, max_len)       -> zeroed cache pytree
 
+``prefill`` and ``decode_step`` are the serve path: their expert layers
+are drop-free, and ``with_stats=True`` adds a third result, ``stats``:
+``{"expert_tokens": (expert layers, experts held) int32}``, the tokens
+each expert layer routed to each held expert (empty without experts).
+
 Batch dict keys: "tokens" (b, s) int32; optional "labels" (b, s) int32
 (-100 = ignore), "enc_features" (b, enc_seq, d) for audio stubs,
 "image_embeds" (b, P, d) for VLM stubs, "positions_3d" (3, b, s) for M-RoPE.
@@ -88,7 +93,7 @@ def _cos_sin_full(cfg: ModelConfig, batch: Dict, b: int, s: int):
         return rope_mod.mrope_cos_sin(pos3, rope_dim, cfg.rope_theta,
                                       cfg.mrope_sections)
     pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    return rope_mod.rope_cos_sin(pos, rope_dim, cfg.rope_theta)
+    return _rope(cfg, pos, rope_dim)
 
 
 def _cos_sin_decode(cfg: ModelConfig, b: int, pos):
@@ -103,7 +108,25 @@ def _cos_sin_decode(cfg: ModelConfig, b: int, pos):
         return rope_mod.mrope_cos_sin(rope_mod.text_positions_3d(positions),
                                       rope_dim, cfg.rope_theta,
                                       cfg.mrope_sections)
-    return rope_mod.rope_cos_sin(positions, rope_dim, cfg.rope_theta)
+    return _rope(cfg, positions, rope_dim)
+
+
+def _rope(cfg: ModelConfig, positions, rope_dim: int):
+    """cos/sin of standard RoPE, or YaRN's when ``cfg.yarn_factor`` is set
+    (its cos/sin factor is 1 when ``mscale`` equals ``mscale_all_dim``)."""
+    if not cfg.yarn_factor:
+        return rope_mod.rope_cos_sin(positions, rope_dim, cfg.rope_theta)
+    freqs = rope_mod.yarn_frequencies(
+        rope_dim, cfg.rope_theta, cfg.yarn_factor,
+        cfg.yarn_original_max_position, cfg.yarn_beta_fast,
+        cfg.yarn_beta_slow)
+    cos, sin = rope_mod.rope_cos_sin(positions, rope_dim, cfg.rope_theta,
+                                     freqs)
+    m = (rope_mod.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+         / rope_mod.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
+    return cos, sin
 
 
 def _sinusoid_at(pos, d: int):
@@ -158,18 +181,34 @@ def _encode(params, cfg: ModelConfig, enc_features):
     for seg, (unit, count) in zip(enc["segments"],
                                   [(("attn",), cfg.num_encoder_layers)],
                                   strict=False):
-        h, _, _ = tf.segment_full(seg, None, cfg, unit, count, h, None, None,
-                                  causal=False)
+        h, _, _, _ = tf.segment_full(seg, None, cfg, unit, count, h, None,
+                                     None, causal=False)
     return rmsnorm(enc["final_norm"], h, cfg.rmsnorm_eps)
 
 
 # ---------------------------------------------------------------------------
 # Full-sequence forward (train / prefill)
 # ---------------------------------------------------------------------------
+def expert_layers(cfg: ModelConfig) -> int:
+    """How many layers of the decoder stack are expert layers."""
+    return sum(count for unit, count in tf.build_plan(cfg)
+               for kind in unit if tf._is_moe(kind))
+
+
+def _stats(per_segment) -> Dict:
+    """Per-segment scan stats -> ``{"expert_tokens": (layers, held)}``."""
+    got = [st[j]["expert_tokens"] for st in per_segment
+           for j in sorted(st, key=int)]
+    return {"expert_tokens": jnp.concatenate(got)} if got else {}
+
+
 def _forward_full(params, cfg: ModelConfig, batch: Dict, *,
-                  want_cache: bool = False, head_at=None):
+                  want_cache: bool = False, head_at=None,
+                  dropless: bool = False, routed=None):
     """``head_at`` (b,) int32 applies the LM head at one position per row
-    only — (b, 1, V) logits instead of (b, s, V)."""
+    only — (b, 1, V) logits instead of (b, s, V).  ``dropless`` runs expert
+    layers drop-free (the serve path), routing the tokens ``routed`` (b, s)
+    marks (None: all).  Returns (logits, aux, caches, stats)."""
     tokens = batch["tokens"]
     b, s = tokens.shape
     h = _embed(params, cfg, tokens, batch)
@@ -183,21 +222,23 @@ def _forward_full(params, cfg: ModelConfig, batch: Dict, *,
     plan = tf.build_plan(cfg)
     shared = params.get("shared_attn")
     aux_total = jnp.zeros((), jnp.float32)
-    caches = []
+    caches, stats = [], []
     for seg, (unit, count) in zip(params["segments"], plan, strict=True):
-        h, aux, cache = tf.segment_full(seg, shared, cfg, unit, count, h,
-                                        cos, sin, enc_out=enc_out,
-                                        want_cache=want_cache)
+        h, aux, cache, st = tf.segment_full(seg, shared, cfg, unit, count, h,
+                                            cos, sin, enc_out=enc_out,
+                                            want_cache=want_cache,
+                                            dropless=dropless, routed=routed)
         aux_total = aux_total + aux
         caches.append(cache)
+        stats.append(st)
     if head_at is not None:
         idx = jnp.asarray(head_at, jnp.int32).reshape(b, 1, 1)
         h = jnp.take_along_axis(h, idx, axis=1)
-    return _logits(params, cfg, h), aux_total, tuple(caches)
+    return _logits(params, cfg, h), aux_total, tuple(caches), _stats(stats)
 
 
 def forward_train(params, cfg: ModelConfig, batch: Dict):
-    logits, aux, _ = _forward_full(params, cfg, batch)
+    logits, aux, _, _ = _forward_full(params, cfg, batch)
     return logits, aux
 
 
@@ -219,27 +260,40 @@ def loss_fn(params, cfg: ModelConfig, batch: Dict):
     return loss, {"ce": ce, "aux": aux, "acc": acc}
 
 
-def prefill(params, cfg: ModelConfig, batch: Dict, lengths=None):
+def prefill(params, cfg: ModelConfig, batch: Dict, lengths=None, *,
+            live=None, with_stats: bool = False):
     """Full forward returning the logits at each row's last valid prompt
     position, (b, V), and per-layer caches sized to the prompt.
 
     ``lengths`` (b,) gives the true length of each right-padded row (None
     = every row fills the width).  Only the sampled position goes through
     the LM head: the (b, L, V) logits of a whole prompt batch would cost
-    more HBM than the KV cache at a 152k vocabulary.
+    more HBM than the KV cache at a 152k vocabulary.  ``live`` (b,) bool
+    marks the rows that carry a prompt (None: all); expert layers route
+    only live rows' tokens before their lengths, so padding and filler
+    rows add no expert work.
     """
     b, s = batch["tokens"].shape
     last = (jnp.full((b,), s - 1, jnp.int32) if lengths is None
             else jnp.asarray(lengths, jnp.int32) - 1)
-    logits, _, caches = _forward_full(params, cfg, batch, want_cache=True,
-                                      head_at=last)
+    routed = None
+    if expert_layers(cfg) and (lengths is not None or live is not None):
+        routed = jnp.arange(s)[None] <= last[:, None]
+        if live is not None:
+            routed = routed & jnp.asarray(live, bool)[:, None]
+    logits, _, caches, stats = _forward_full(params, cfg, batch,
+                                             want_cache=True, head_at=last,
+                                             dropless=True, routed=routed)
+    if with_stats:
+        return logits[:, 0], caches, stats
     return logits[:, 0], caches
 
 
 # ---------------------------------------------------------------------------
 # Decode
 # ---------------------------------------------------------------------------
-def decode_step(params, cfg: ModelConfig, token, caches, pos, *, paged=None):
+def decode_step(params, cfg: ModelConfig, token, caches, pos, *, paged=None,
+                with_stats: bool = False):
     """token: (b, 1) int32; pos: scalar OR (b,) int32 — per-row count of
     tokens already cached (row ``i``'s new token lands at absolute position
     ``pos[i]``).  A scalar broadcasts to every row, so rows at different
@@ -250,7 +304,7 @@ def decode_step(params, cfg: ModelConfig, token, caches, pos, *, paged=None):
     rotation, embedding and head math are untouched — positions stay
     absolute, only the KV storage addressing changes.
 
-    Returns (logits (b, 1, V), new caches)."""
+    Returns (logits (b, 1, V), new caches[, stats])."""
     b = token.shape[0]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     with jax.named_scope("embed"):
@@ -263,12 +317,15 @@ def decode_step(params, cfg: ModelConfig, token, caches, pos, *, paged=None):
 
     plan = tf.build_plan(cfg)
     shared = params.get("shared_attn")
-    new_caches = []
+    new_caches, stats = [], []
     for seg, cache, (unit, count) in zip(params["segments"], caches, plan,
                                       strict=True):
-        h, nc = tf.segment_decode(seg, shared, cfg, unit, count, h, cos, sin,
-                                  cache, pos, paged=paged)
+        h, nc, st = tf.segment_decode(seg, shared, cfg, unit, count, h, cos,
+                                      sin, cache, pos, paged=paged)
         new_caches.append(nc)
+        stats.append(st)
+    if with_stats:
+        return _logits(params, cfg, h), tuple(new_caches), _stats(stats)
     return _logits(params, cfg, h), tuple(new_caches)
 
 

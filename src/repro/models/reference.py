@@ -1,21 +1,37 @@
-"""Plain float32 reference forward of the SCOPE estimator backbone.
+"""Plain float32 reference forward of the estimator backbones.
 
-The estimator is a Qwen3-shaped dense decoder (arXiv:2505.09388): token
-embedding, then per layer RMSNorm -> grouped-query attention with per-head
-RMSNorm on q and k (qk-norm) and rotary positions (rotate-half form) ->
-residual -> RMSNorm -> SwiGLU MLP -> residual, a final RMSNorm and the LM
-head (the transposed embedding when tied).  This module writes those
-equations out in straightforward ``jax.numpy`` at float32 and
-``highest`` matmul precision: no kernels, no KV cache, no batching tricks,
-no sharding.  It reads the same params pytree as ``models.model`` but
-shares none of its code, so it is the oracle the serve path (prefill, then
-decode through the dense or paged cache) is compared against.
+Two families are written out:
 
-Only the dense family the estimator uses is covered; any other block kind
-or option raises rather than being silently approximated.
+* the SCOPE estimator, a Qwen3-shaped dense decoder (arXiv:2505.09388):
+  token embedding, then per layer RMSNorm -> grouped-query attention with
+  per-head RMSNorm on q and k (qk-norm) and rotary positions (rotate-half
+  form) -> residual -> RMSNorm -> SwiGLU MLP -> residual, a final RMSNorm
+  and the LM head (the transposed embedding when tied);
+* DeepSeek-V2 (arXiv:2405.04434): multi-head latent attention in its
+  naive form (queries from ``wq``; keys and values up-projected from the
+  RMS-normed latent ``c_kv``, plus one shared rotary key; YaRN rope and
+  softmax scale), the first ``first_dense_layers`` with a SwiGLU MLP and
+  the rest with a softmax router, greedy top-k and the experts held here
+  (``experts_held`` from ``expert_offset``; a choice of another expert
+  adds nothing) plus the shared experts.
+
+This module writes those equations out in straightforward ``jax.numpy`` at
+float32 and ``highest`` matmul precision: no kernels, no KV cache, no
+batching tricks, no sharding, and every expert computed on every token and
+weighted by its gate (zero where not chosen).  It reads the same params
+pytree as ``models.model`` but shares none of its code, so it is the
+oracle the serve path (prefill, then decode through the dense or paged
+cache) is compared against.
+
+Rope is the rotate-half form on the program's layout; DeepSeek-V2
+publishes an interleaved rope, which equals this one under a fixed
+permutation of the rope columns of ``wq`` and ``w_dkv`` — with random
+weights a relabelling.  Any other block kind or option raises rather than
+being silently approximated.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict
 
 import jax
@@ -25,9 +41,19 @@ import numpy as np
 from repro.configs.base import ModelConfig
 
 
+def _kinds(cfg: ModelConfig):
+    """Each layer's kind, the first dense layers of an expert stack made
+    dense."""
+    dense = {"mla_moe": "mla", "moe": "attn"}
+    return [dense.get(k, k) if i < cfg.first_dense_layers else k
+            for i, k in enumerate(cfg.layer_kinds())]
+
+
 def check_supported(cfg: ModelConfig) -> None:
+    kinds = set(_kinds(cfg))
     unsupported = {
-        "block kinds other than 'attn'": set(cfg.layer_kinds()) != {"attn"},
+        "block kinds other than 'attn', or 'mla' and 'mla_moe'":
+            not (kinds == {"attn"} or kinds <= {"mla", "mla_moe"}),
         "rope_kind other than 'standard'": cfg.rope_kind != "standard",
         "attention or final logit softcaps":
             cfg.logit_softcap > 0.0 or cfg.final_logit_softcap > 0.0,
@@ -37,12 +63,13 @@ def check_supported(cfg: ModelConfig) -> None:
         "encoder-decoder or stub frontends":
             cfg.is_encoder_decoder or cfg.num_stub_patches > 0,
         "non-SwiGLU MLPs": cfg.mlp_kind == "gelu",
+        "YaRN outside MLA": cfg.yarn_factor > 0 and kinds == {"attn"},
     }
     bad = [name for name, hit in unsupported.items() if hit]
     if bad:
         raise NotImplementedError(
-            f"reference forward covers the dense estimator family only; "
-            f"{cfg.name!r} has {', '.join(bad)}")
+            f"reference forward covers the dense estimator and DeepSeek-V2 "
+            f"families only; {cfg.name!r} has {', '.join(bad)}")
 
 
 def _rmsnorm(x, scale, eps):
@@ -60,6 +87,103 @@ def _rope(x, positions, theta):
     sin = jnp.sin(jnp.concatenate([ang, ang], -1))[None, :, None]
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def _yarn_inv_freq(cfg: ModelConfig, d: int) -> np.ndarray:
+    """YaRN (DeepSeek-V2 ``rope_scaling``) inverse frequencies: the pair
+    index ``i`` whose wavelength turns ``r`` times in the original length
+    is d ln(L / (2 pi r)) / (2 ln theta); pairs below that of
+    ``beta_fast`` keep theta^(-2i/d), pairs above that of ``beta_slow``
+    take it over ``factor``, a linear ramp between."""
+    theta, L = cfg.rope_theta, cfg.yarn_original_max_position
+    inv = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def index(r):
+        return d * math.log(L / (r * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo = max(math.floor(index(cfg.yarn_beta_fast)), 0)
+    hi = min(math.ceil(index(cfg.yarn_beta_slow)), d - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    ramp = np.clip((np.arange(d // 2) - lo) / (hi - lo), 0.0, 1.0)
+    return inv * (1.0 - ramp) + inv / cfg.yarn_factor * ramp
+
+
+def _yarn_m(factor, mscale):
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def _rope_mla(x, positions, cfg: ModelConfig):
+    """Rotate-half rope of the MLA rope dims, YaRN-scaled when set (cos
+    and sin times m(mscale) / m(mscale_all_dim))."""
+    d = x.shape[-1]
+    if not cfg.yarn_factor:
+        return _rope(x, positions, cfg.rope_theta)
+    inv = _yarn_inv_freq(cfg, d)
+    f = (_yarn_m(cfg.yarn_factor, cfg.yarn_mscale)
+         / _yarn_m(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    ang = positions[:, None].astype(jnp.float32) * jnp.asarray(
+        inv, jnp.float32)[None]
+    cos = f * jnp.cos(jnp.concatenate([ang, ang], -1))[None, :, None]
+    sin = f * jnp.sin(jnp.concatenate([ang, ang], -1))[None, :, None]
+    x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+def _mla(a: Dict, cfg: ModelConfig, x):
+    """Naive multi-head latent attention of normed ``x`` (b, s, d)."""
+    b, s, _ = x.shape
+    h, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    rdim, vdim, r = cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    pos = jnp.arange(s)
+    q = (x @ a["wq"]).reshape(b, s, h, nope + rdim)
+    q_nope, q_rope = q[..., :nope], _rope_mla(q[..., nope:], pos, cfg)
+    kv = x @ a["w_dkv"]
+    c = _rmsnorm(kv[..., :r], a["kv_norm"]["scale"], cfg.rmsnorm_eps)
+    k_rope = _rope_mla(kv[..., r:][:, :, None], pos, cfg)    # one head
+    k_nope = (c @ a["w_uk"]).reshape(b, s, h, nope)
+    v = (c @ a["w_uv"]).reshape(b, s, h, vdim)
+    scale = (nope + rdim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= _yarn_m(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope)
+              + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope[:, :, 0])) * scale
+    causal = pos[:, None] >= pos[None, :]
+    scores = jnp.where(causal[None, None], scores, -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(b, s, h * vdim)
+    return o @ a["wo"]
+
+
+def _swiglu(m: Dict, x):
+    return (jax.nn.silu(x @ m["wi_gate"]) * (x @ m["wi_up"])) @ m["wo"]
+
+
+def _moe(m: Dict, cfg: ModelConfig, x):
+    """Softmax router over all experts, greedy top-k (renormalised only
+    when ``norm_topk_prob``), the held experts' gated outputs and the
+    shared experts."""
+    probs = jax.nn.softmax(x @ m["router"], axis=-1)        # (b, s, E)
+    top_p, top_i = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    if cfg.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
+    held = cfg.experts_held or cfg.num_experts
+    y = jnp.zeros_like(x)
+    for j in range(held):
+        e = cfg.expert_offset + j
+        gate = jnp.sum(jnp.where(top_i == e, top_p, 0.0), -1)   # (b, s)
+        w = {k: m[k][j] for k in ("wi_gate", "wi_up", "wo")}
+        y = y + gate[..., None] * _swiglu(w, x)
+    if cfg.num_shared_experts:
+        y = y + _swiglu(m["shared"], x)
+    return y
+
+
+def _mla_layer(p: Dict, cfg: ModelConfig, kind: str, h):
+    eps = cfg.rmsnorm_eps
+    h = h + _mla(p["attn"], cfg, _rmsnorm(h, p["attn_norm"]["scale"], eps))
+    x = _rmsnorm(h, p["mlp_norm"]["scale"], eps)
+    return h + (_moe(p["moe"], cfg, x) if kind == "mla_moe"
+                else _swiglu(p["mlp"], x))
 
 
 def _layer(p: Dict, cfg: ModelConfig, h):
@@ -97,11 +221,23 @@ def hidden(params: Dict, cfg: ModelConfig, tokens) -> jax.Array:
     """Final-normed hidden states (b, s, d) of ``tokens`` (b, s)."""
     check_supported(cfg)
     p32 = jax.tree.map(lambda a: jnp.asarray(a, jnp.float32), params)
+    kinds = _kinds(cfg)
+    # the program stacks consecutive layers of one kind: segment i holds
+    # the next run of them under "0"
+    runs, i = [], 0
+    while i < len(kinds):
+        j = i
+        while j < len(kinds) and kinds[j] == kinds[i]:
+            j += 1
+        runs.append((kinds[i], j - i))
+        i = j
     with jax.default_matmul_precision("highest"):
         h = p32["embed"][tokens]
-        layers = p32["segments"][0]["0"]
-        for i in range(cfg.num_layers):
-            h = _layer(jax.tree.map(lambda a, i=i: a[i], layers), cfg, h)
+        for seg, (kind, count) in zip(p32["segments"], runs, strict=True):
+            for i in range(count):
+                p = jax.tree.map(lambda a, i=i: a[i], seg["0"])
+                h = (_layer(p, cfg, h) if kind == "attn"
+                     else _mla_layer(p, cfg, kind, h))
         return _rmsnorm(h, p32["final_norm"]["scale"], cfg.rmsnorm_eps)
 
 

@@ -13,8 +13,16 @@ Unit patterns handle heterogeneous stacks:
 but keep per-occurrence KV caches.
 
 An attention block's two halves run under ``jax.named_scope`` ``attn``
-(norm through the residual add) and ``mlp``, so a profile's ops name the
-half they belong to.
+(norm through the residual add) and ``mlp`` — ``moe`` for an expert layer,
+with ``route``, ``experts`` and ``shared`` inside — so a profile's ops name
+the half they belong to.
+
+The serve path (``prefill``, ``decode_step``: ``dropless=True``) runs expert
+layers drop-free (``moe.moe_dropless``) and returns, per segment, the
+tokens each layer routed to each held expert (``stats["expert_tokens"]``,
+(count, experts_held)); training keeps the capacity dispatch.  A segment
+with no expert layer returns an empty ``stats``, which adds nothing to the
+traced program.
 """
 from __future__ import annotations
 
@@ -113,16 +121,43 @@ def init_block(key, cfg: ModelConfig, kind: str, *, cross: bool = False) -> Dict
 # ---------------------------------------------------------------------------
 # Block apply — full sequence
 # ---------------------------------------------------------------------------
+def _ffn(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
+         dropless: bool, full: bool, routed=None
+         ) -> Tuple[jax.Array, jax.Array, Dict]:
+    """The block's second half, residual add included: (h, moe_aux or
+    None, stats).  ``full``: a whole sequence, whose residual stream is
+    sharding-constrained; ``routed``: ``moe_dropless``'s token mask."""
+    aux = None
+    stats: Dict = {}
+    with jax.named_scope("moe" if _is_moe(kind) else "mlp"):
+        x2 = rmsnorm(p["mlp_norm"], h, cfg.rmsnorm_eps)
+        if _is_moe(kind) and dropless:
+            y2, stats["expert_tokens"] = moe_mod.moe_dropless(
+                p["moe"], cfg, x2, routed)
+        elif _is_moe(kind):
+            y2, aux = moe_mod.moe_forward(p["moe"], cfg, x2)
+        else:
+            y2 = mlp_mod.mlp_forward(p["mlp"], cfg, x2)
+        if cfg.sandwich_norm:
+            y2 = rmsnorm(p["post_mlp_norm"], y2, cfg.rmsnorm_eps)
+        if full:
+            return shard_activation(h + y2, "batch", None, "residual"), aux, \
+                stats
+        return h + y2, aux, stats
+
+
 def block_full(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
-               cos, sin, *, enc_out=None, causal: bool = True
-               ) -> Tuple[jax.Array, Dict, jax.Array]:
-    """Returns (h, cache, moe_aux)."""
+               cos, sin, *, enc_out=None, causal: bool = True,
+               dropless: bool = False, routed=None
+               ) -> Tuple[jax.Array, Dict, jax.Array, Dict]:
+    """Returns (h, cache, moe_aux, stats)."""
     aux = jnp.zeros((), jnp.float32)
     cache: Dict = {}
     if kind == "mamba":
         y, cache = ssm_mod.mamba_full(p["mamba"], cfg,
                                       rmsnorm(p["norm"], h, cfg.rmsnorm_eps))
-        return shard_activation(h + y, "batch", None, "residual"), cache, aux
+        return (shard_activation(h + y, "batch", None, "residual"), cache,
+                aux, {})
 
     with jax.named_scope("attn"):
         x = rmsnorm(p["attn_norm"], h, cfg.rmsnorm_eps)
@@ -143,16 +178,8 @@ def block_full(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
         h = h + attn_mod.cross_attend(p["cross"], cfg, xc, ckv)
         cache.update(ckv)
 
-    with jax.named_scope("mlp"):
-        x2 = rmsnorm(p["mlp_norm"], h, cfg.rmsnorm_eps)
-        if _is_moe(kind):
-            y2, aux = moe_mod.moe_forward(p["moe"], cfg, x2)
-        else:
-            y2 = mlp_mod.mlp_forward(p["mlp"], cfg, x2)
-        if cfg.sandwich_norm:
-            y2 = rmsnorm(p["post_mlp_norm"], y2, cfg.rmsnorm_eps)
-        out = shard_activation(h + y2, "batch", None, "residual")
-    return out, cache, aux
+    out, a, stats = _ffn(p, cfg, kind, h, dropless, True, routed)
+    return out, cache, aux if a is None else a, stats
 
 
 # ---------------------------------------------------------------------------
@@ -160,22 +187,26 @@ def block_full(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
 # ---------------------------------------------------------------------------
 def block_decode(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
                  cos, sin, cache: Dict, pos, *, paged=None
-                 ) -> Tuple[jax.Array, Dict]:
-    """``paged`` = (PagedSpec, page table (b, W)) routes attention layers
-    through the block-paged cache layout; ``kv_pool.check_paged_support``
-    guarantees only plain GQA kinds reach here when it is set."""
+                 ) -> Tuple[jax.Array, Dict, Dict]:
+    """Returns (h, cache, stats).  ``paged`` = (PagedSpec, page table
+    (b, W)) routes attention layers through the block-paged cache layout
+    (GQA K/V pages or MLA latent pages); ``kv_pool.check_paged_support``
+    guarantees only full-window GQA and MLA kinds reach here when it is
+    set."""
     if kind == "mamba":
         y, new = ssm_mod.mamba_decode(p["mamba"], cfg,
                                       rmsnorm(p["norm"], h, cfg.rmsnorm_eps),
                                       cache)
-        return h + y, new
+        return h + y, new, {}
 
     new_cache: Dict = {}
     with jax.named_scope("attn"):
         x = rmsnorm(p["attn_norm"], h, cfg.rmsnorm_eps)
-        if paged is not None:
-            if _is_mla(kind):
-                raise ValueError("paged decode does not support MLA layers")
+        if paged is not None and _is_mla(kind):
+            spec, table = paged
+            y, kv = attn_mod.mla_decode_paged(p["attn"], cfg, x, cos, sin,
+                                              cache, pos, table, spec)
+        elif paged is not None:
             spec, table = paged
             y, kv = attn_mod.gqa_decode_paged(p["attn"], cfg, x, cos, sin,
                                               cache, pos, table, spec,
@@ -197,16 +228,8 @@ def block_decode(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
         h = h + attn_mod.cross_attend(p["cross"], cfg, xc, ckv)
         new_cache.update(ckv)
 
-    with jax.named_scope("mlp"):
-        x2 = rmsnorm(p["mlp_norm"], h, cfg.rmsnorm_eps)
-        if _is_moe(kind):
-            y2, _ = moe_mod.moe_forward(p["moe"], cfg, x2)
-        else:
-            y2 = mlp_mod.mlp_forward(p["mlp"], cfg, x2)
-        if cfg.sandwich_norm:
-            y2 = rmsnorm(p["post_mlp_norm"], y2, cfg.rmsnorm_eps)
-        h = h + y2
-    return h, new_cache
+    h, _, stats = _ffn(p, cfg, kind, h, True, False)
+    return h, new_cache, stats
 
 
 # ---------------------------------------------------------------------------
@@ -230,8 +253,11 @@ def init_segment(key, cfg: ModelConfig, unit: Tuple[str, ...], count: int,
 def segment_full(seg_params: Dict, shared_params, cfg: ModelConfig,
                  unit: Tuple[str, ...], count: int, h: jax.Array, cos, sin,
                  *, enc_out=None, causal: bool = True, remat: bool = True,
-                 want_cache: bool = True):
-    """Scan the unit body over ``count`` stacked layers.
+                 want_cache: bool = True, dropless: bool = False,
+                 routed=None):
+    """Scan the unit body over ``count`` stacked layers; returns (h, aux,
+    caches, stats).  ``routed`` (b, s) bool: the tokens a drop-free expert
+    layer routes (``moe.moe_dropless``; None: all).
 
     The body is rematerialized (activation checkpointing, MaxText-style):
     backward recomputes layer internals instead of storing the blocked
@@ -240,23 +266,26 @@ def segment_full(seg_params: Dict, shared_params, cfg: ModelConfig,
     """
     def body(carry, xs):
         hh, aux = carry
-        caches = {}
+        caches, stats = {}, {}
         for j, kind in enumerate(unit):
             p = shared_params if kind == "shared_attn" else xs[str(j)]
             kk = "attn" if kind == "shared_attn" else kind
-            hh, cache, a = block_full(p, cfg, kk, hh, cos, sin,
-                                      enc_out=enc_out, causal=causal)
+            hh, cache, a, st = block_full(p, cfg, kk, hh, cos, sin,
+                                          enc_out=enc_out, causal=causal,
+                                          dropless=dropless, routed=routed)
             if want_cache:
                 caches[str(j)] = cache
+            if st:
+                stats[str(j)] = st
             aux = aux + a
-        return (hh, aux), caches
+        return (hh, aux), (caches, stats)
 
     if remat:
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.nothing_saveable)
-    (h, aux), caches = jax.lax.scan(
+    (h, aux), (caches, stats) = jax.lax.scan(
         body, (h, jnp.zeros((), jnp.float32)), seg_params, length=count)
-    return h, aux, caches
+    return h, aux, caches, stats
 
 
 def segment_decode(seg_params: Dict, shared_params, cfg: ModelConfig,
@@ -267,16 +296,18 @@ def segment_decode(seg_params: Dict, shared_params, cfg: ModelConfig,
     # (n_pages, hkv, page, hd) slice of the stacked page storage
     def body(hh, xs):
         layer_caches = xs["__cache__"]
-        new_caches = {}
+        new_caches, stats = {}, {}
         for j, kind in enumerate(unit):
             p = shared_params if kind == "shared_attn" else xs[str(j)]
             kk = "attn" if kind == "shared_attn" else kind
-            hh, nc = block_decode(p, cfg, kk, hh, cos, sin,
-                                  layer_caches[str(j)], pos, paged=paged)
+            hh, nc, st = block_decode(p, cfg, kk, hh, cos, sin,
+                                      layer_caches[str(j)], pos, paged=paged)
             new_caches[str(j)] = nc
-        return hh, new_caches
+            if st:
+                stats[str(j)] = st
+        return hh, (new_caches, stats)
 
     xs = dict(seg_params)
     xs["__cache__"] = caches
-    h, new_caches = jax.lax.scan(body, h, xs, length=count)
-    return h, new_caches
+    h, (new_caches, stats) = jax.lax.scan(body, h, xs, length=count)
+    return h, new_caches, stats

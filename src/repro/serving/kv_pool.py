@@ -13,8 +13,14 @@ queued prompt — slot admission checks free pages, not remaining horizon.
 
 Layout per attention layer-stack cache leaf:
 
-  dense  k/v : (count, b, hkv, S, hd)                 S = max_len slots
-  paged  k/v : (count, n_pages + 1, hkv, page, hd)    physical pages
+  dense  k/v    : (count, b, hkv, S, hd)              S = max_len slots
+  paged  k/v    : (count, n_pages + 1, hkv, page, hd) physical pages
+  dense  latent : (count, b, S, r)                    MLA c_kv / k_rope
+  paged  latent : (count, n_pages + 1, page, r)       r = kv_lora_rank or
+                                                      qk_rope_head_dim
+
+GQA K/V pages and MLA latent pages live side by side, each layer stack in
+its own layout, under one page id space.
 
 A *page id* spans **all** layers: allocating page p grants the row
 ``page_size`` token slots in every layer's storage at physical index p.
@@ -50,22 +56,25 @@ def _ceil_div(a: int, b: int) -> int:
 
 
 def kv_bytes_per_token(cfg: ModelConfig) -> int:
-    """KV bytes one cached token costs across every attention layer."""
+    """Cache bytes one token costs across every attention layer: K and V
+    of a GQA layer, the latent (``kv_lora_rank + qk_rope_head_dim``) of an
+    MLA layer."""
     from repro.models import transformer as tf
     from repro.models.common import dtype_of
 
     itemsize = np.dtype(dtype_of(cfg.dtype)).itemsize
-    per_layer = 2 * cfg.num_kv_heads * cfg.resolved_head_dim * itemsize
-    layers = sum(1 for k in cfg.layer_kinds() if tf._is_attn(k))
-    return per_layer * layers
+    gqa = 2 * cfg.num_kv_heads * cfg.resolved_head_dim * itemsize
+    mla = (cfg.kv_lora_rank + cfg.qk_rope_head_dim) * itemsize
+    return sum(mla if tf._is_mla(k) else gqa
+               for k in cfg.layer_kinds() if tf._is_attn(k) or tf._is_mla(k))
 
 
 def check_paged_support(cfg: ModelConfig) -> None:
-    """Paged v1 covers plain-GQA attention stacks only.
+    """Paged KV covers full-window attention stacks: GQA (K/V pages) and
+    MLA (latent pages).
 
-    Every layer must be a full-window GQA attention block: MLA latents,
-    SSM/conv states and encoder cross caches have no paged layout yet,
-    and windowed ring buffers already cap their own memory at O(window).
+    SSM/conv states and encoder cross caches have no paged layout, and
+    windowed ring buffers already cap their own memory at O(window).
     Loud failure beats silently decoding from the wrong cache lines.
     """
     from repro.models import transformer as tf
@@ -77,11 +86,11 @@ def check_paged_support(cfg: ModelConfig) -> None:
             "encoder cross caches")
     for kind in cfg.layer_kinds():
         kk = "attn" if kind == "shared_attn" else kind
-        if not tf._is_attn(kk) or tf._is_mla(kk):
+        if not (tf._is_attn(kk) or tf._is_mla(kk)):
             raise ValueError(
-                "paged KV requires an attention-only GQA backbone: "
-                f"{cfg.name!r} has a {kind!r} layer (SSM/MLA states have "
-                "no paged layout)")
+                "paged KV requires an attention-only backbone: "
+                f"{cfg.name!r} has a {kind!r} layer (SSM states have no "
+                "paged layout)")
         if resolve_window(cfg, kk) > 0:
             raise ValueError(
                 "paged KV does not support sliding-window layers: "

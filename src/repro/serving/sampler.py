@@ -37,6 +37,13 @@ The executables name their halves with ``jax.named_scope`` — ``prefill``,
 and per scan step ``decode`` with ``sample`` inside — so the ops of a
 profile carry the layer they belong to in their ``op_name`` metadata.
 
+For a backbone with expert layers the executables also return ``stats``:
+``expert_tokens_decode`` (and, with a refill, ``expert_tokens_prefill``),
+(expert layers, experts held) int32, the tokens each layer routed to each
+held expert in the launch; ``DecodeState.stats`` carries them to the
+host's next sync.  Without expert layers ``stats`` is empty and the
+executables are what they were without it.
+
 ``COMPILE_COUNTS`` counts executable builds explicitly (incremented inside
 the traced bodies, once per compilation) — the serve path's "0 recompiles
 after warmup" gate reads it instead of sniffing jit internals.
@@ -131,11 +138,12 @@ def _run_scan(params, cfg: ModelConfig, last_logits, caches, key,
     and their paged twins: sample -> emit (token, YES/NO) -> step, for
     ``steps`` steps.  ``paged`` = (PagedSpec, page table) reroutes the KV
     writes/reads through the block-paged layout; the sampling math is
-    byte-for-byte the same code path."""
+    byte-for-byte the same code path.  Returns (gen, dec, last, caches,
+    done, key, stats)."""
     dec_ix = jnp.asarray(DECISION_TOKENS, jnp.int32)
 
     def step(carry, t):
-        logits, kv, dn, k = carry
+        logits, kv, dn, k, routed = carry
         with jax.named_scope("decode"):
             with jax.named_scope("sample"):
                 if temperature > 0.0:
@@ -148,16 +156,24 @@ def _run_scan(params, cfg: ModelConfig, last_logits, caches, key,
                 dec = logits[:, dec_ix]                  # (b, 2)
                 if stop_at_eos:
                     dn = dn | (nxt == EOS)
-            new_logits, kv = M.decode_step(params, cfg, nxt[:, None], kv,
-                                           positions + t, paged=paged)
+            new_logits, kv, st = M.decode_step(params, cfg, nxt[:, None], kv,
+                                               positions + t, paged=paged,
+                                               with_stats=True)
+            routed = jax.tree.map(jnp.add, routed, st)
             new_logits = new_logits[:, 0].astype(jnp.float32)
-        return (new_logits, kv, dn, k), (nxt, dec)
+        return (new_logits, kv, dn, k, routed), (nxt, dec)
 
-    init = (last_logits, caches, done, key)
-    (last, kv, done, key), (gen, dec) = jax.lax.scan(step, init,
-                                                     jnp.arange(steps))
+    # the step's stats summed over the segment (none without experts)
+    routed = {}
+    if cfg.has_moe():
+        routed["expert_tokens"] = jnp.zeros(
+            (M.expert_layers(cfg), cfg.resolved_experts_held), jnp.int32)
+    init = (last_logits, caches, done, key, routed)
+    (last, kv, done, key, routed), (gen, dec) = jax.lax.scan(
+        step, init, jnp.arange(steps))
+    stats = {f"{name}_decode": v for name, v in routed.items()}
     # (b, T), (b, T, 2), + carry for the next segment
-    return gen.T, dec.transpose(1, 0, 2), last, kv, done, key
+    return gen.T, dec.transpose(1, 0, 2), last, kv, done, key, stats
 
 
 # no donate_argnums on the caches: XLA reports the KV buffers as unusable
@@ -246,13 +262,20 @@ def _admit_rows(params, cfg: ModelConfig, rows, prompts, lens,
     bucketed prompts (R, W) at their true lengths, and reset the slots
     ``rows`` (R,) to them — last logits, position, not done; filler
     entries (== b) are dropped.  Returns the prompts' prompt-sized caches
-    (R rows) for the caller to merge into its own layout."""
-    last_new, new = M.prefill(params, cfg, {"tokens": prompts}, lens)
+    (R rows) for the caller to merge into its own layout, and the
+    prefill's ``stats`` (``expert_tokens_prefill``; empty without expert
+    layers)."""
+    # filler rows route no tokens to experts (checked only for an expert
+    # backbone, so a dense one traces exactly what it did before)
+    live = rows < last_logits.shape[0] if M.expert_layers(cfg) else None
+    last_new, new, st = M.prefill(params, cfg, {"tokens": prompts}, lens,
+                                  live=live, with_stats=True)
+    stats = {f"{name}_prefill": v for name, v in st.items()}
     last_logits = last_logits.at[rows].set(last_new.astype(jnp.float32),
                                            mode="drop")
     positions = positions.at[rows].set(lens.astype(jnp.int32), mode="drop")
     done = done.at[rows].set(False, mode="drop")
-    return new, last_logits, positions, done
+    return new, last_logits, positions, done, stats
 
 
 @functools.partial(jax.jit, static_argnums=(1, 5, 6, 7))
@@ -280,7 +303,7 @@ def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
         return old.at[:, refill_rows].set(new.astype(old.dtype), mode="drop")
 
     with jax.named_scope("prefill"):
-        new_caches, last_logits, positions, done = _admit_rows(
+        new_caches, last_logits, positions, done, admitted = _admit_rows(
             params, cfg, refill_rows, refill_prompts, refill_lens,
             last_logits, positions, done)
         new_caches = jax.tree_util.tree_map_with_path(_grow_to, new_caches,
@@ -288,12 +311,35 @@ def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
         caches = jax.tree.map(merge, caches, new_caches)
     out = _run_scan(params, cfg, last_logits, caches, key, steps,
                     temperature, stop_at_eos, positions, done)
-    return out + (positions,)
+    return out[:-1] + (positions, {**out[-1], **admitted})
 
 
 # ---------------------------------------------------------------------------
 # Paged twins: prefill-scatter + decode over the block-paged KV layout
 # ---------------------------------------------------------------------------
+def _paged_latent_scatter(leaf, storage, page_ids, page_size: int):
+    """``_paged_leaf_scatter`` for an MLA latent leaf: (count, b, L, r)
+    into latent pages (count, n_pages + 1, page_size, r)."""
+    count, b, L, r = leaf.shape
+    npg = page_ids.shape[0] // b
+    pad = npg * page_size - L
+    if pad:
+        leaf = jnp.pad(leaf, [(0, 0), (0, 0), (0, pad), (0, 0)])
+    blocks = leaf.reshape(count, b * npg, page_size, r)
+    return storage.at[:, page_ids].set(blocks.astype(storage.dtype))
+
+
+def _paged_storage(leaf, n_pages_total: int, page_size: int):
+    """Zeroed page storage for a prefill cache leaf: K/V (count, b, hkv,
+    L, hd) -> (count, n_pages_total, hkv, page_size, hd); an MLA latent
+    (count, b, L, r) -> (count, n_pages_total, page_size, r)."""
+    if leaf.ndim == 4:
+        count, _, _, r = leaf.shape
+        return jnp.zeros((count, n_pages_total, page_size, r), leaf.dtype)
+    count, _, hkv, _, hd = leaf.shape
+    return jnp.zeros((count, n_pages_total, hkv, page_size, hd), leaf.dtype)
+
+
 def _paged_leaf_scatter(leaf, storage, page_ids, page_size: int):
     """Scatter a dense prefill leaf (count, b, hkv, L, hd) into paged
     storage (count, n_pages + 1, hkv, page_size, hd) at the flattened
@@ -316,16 +362,20 @@ def _paged_leaf_scatter(leaf, storage, page_ids, page_size: int):
 
 
 def _scatter_prefill_caches(caches, storage_of, page_ids, page_size: int):
-    """Tree-map the page scatter over the k/v cache leaves.
+    """Tree-map the page scatter over the cache leaves: GQA k/v pairs and
+    MLA latents (c_kv, k_rope).
 
-    ``check_paged_support`` guarantees every decode-cache leaf is a GQA
-    k/v pair, so anything else here is a bug, not a user error.
+    ``check_paged_support`` guarantees every decode-cache leaf is one of
+    those, so anything else here is a bug, not a user error.
     """
     def scatter(path, leaf):
         name = _leaf_name(path)
+        if name in ("c_kv", "k_rope"):
+            return _paged_latent_scatter(leaf, storage_of(path, leaf),
+                                         page_ids, page_size)
         if name not in ("k", "v"):
             raise AssertionError(
-                f"paged scatter hit non-GQA cache leaf {name!r}")
+                f"paged scatter hit an unpaged cache leaf {name!r}")
         return _paged_leaf_scatter(leaf, storage_of(path, leaf), page_ids,
                                    page_size)
 
@@ -346,9 +396,7 @@ def _paged_prefill(params, cfg: ModelConfig, tokens, n_pages_total: int,
     COMPILE_COUNTS["paged_prefill"] += 1    # traced once per compilation
 
     def storage_of(path, leaf):
-        count, _, hkv, _, hd = leaf.shape
-        return jnp.zeros((count, n_pages_total, hkv, page_size, hd),
-                         leaf.dtype)
+        return _paged_storage(leaf, n_pages_total, page_size)
 
     with jax.named_scope("prefill"):
         logits, caches = M.prefill(params, cfg, {"tokens": tokens}, lens)
@@ -368,13 +416,9 @@ def _paged_open(params, cfg: ModelConfig, batch: int, n_pages_total: int,
                                                          jnp.int32)}),
         params)
 
-    def storage(leaf):
-        count, _, hkv, _, hd = leaf.shape
-        return jnp.zeros((count, n_pages_total, hkv, page_size, hd),
-                         leaf.dtype)
-
     return (jnp.zeros((batch, logits.shape[-1]), jnp.float32),
-            jax.tree.map(storage, caches))
+            jax.tree.map(lambda leaf: _paged_storage(leaf, n_pages_total,
+                                                     page_size), caches))
 
 
 @functools.partial(jax.jit, static_argnums=(1, 3))
@@ -440,7 +484,7 @@ def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
 
     jax.tree_util.tree_map_with_path(name_leaf, caches)
     with jax.named_scope("prefill"):
-        new, last_logits, positions, done = _admit_rows(
+        new, last_logits, positions, done, admitted = _admit_rows(
             params, cfg, refill_rows, refill_prompts, refill_lens,
             last_logits, positions, done)
         caches = _scatter_prefill_caches(
@@ -449,7 +493,7 @@ def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
     out = _run_scan(params, cfg, last_logits, caches, key, steps,
                     temperature, stop_at_eos, positions, done,
                     paged=(spec, table))
-    return out + (positions,)
+    return out[:-1] + (positions, {**out[-1], **admitted})
 
 
 # ---------------------------------------------------------------------------
@@ -483,6 +527,9 @@ class DecodeState:
     # a plain segment), and the batch-sharding degree its row buckets keep
     prefill_rows: int = 0
     row_shards: int = 1
+    # that launch's device-side counters (``expert_tokens_*``; empty
+    # without expert layers), read by the host at its next sync
+    stats: dict = dataclasses.field(default_factory=dict)
 
     @property
     def batch(self) -> int:
@@ -696,12 +743,12 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
     if refill is None:
         if pg is not None:
             pg.ensure(steps)
-            gen, dec, last, caches, done, key = _paged_scan_decode(
+            gen, dec, last, caches, done, key, stats = _paged_scan_decode(
                 params, cfg, state.last_logits, state.caches, key, steps,
                 float(temperature), bool(stop_at_eos), pg.spec,
                 pg.device_table(), state.positions, state.done)
         else:
-            gen, dec, last, caches, done, key = _scan_decode(
+            gen, dec, last, caches, done, key, stats = _scan_decode(
                 params, cfg, state.last_logits, state.caches, key, steps,
                 float(temperature), bool(stop_at_eos), state.positions,
                 state.done)
@@ -745,14 +792,14 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
             npg = _ceil_div(width, pg.page_size)
             ids = jnp.asarray(pg.prompt_page_ids(rows, npg).reshape(-1))
             pg.ensure(steps)
-            (gen, dec, last, caches, done, key,
-             positions) = _paged_refill_scan_decode(
+            (gen, dec, last, caches, done, key, positions,
+             stats) = _paged_refill_scan_decode(
                 params, cfg, state.last_logits, state.caches, key, steps,
                 float(temperature), bool(stop_at_eos), pg.spec,
                 pg.device_table(), state.positions, state.done, *operands,
                 ids)
         else:
-            gen, dec, last, caches, done, key, positions = \
+            gen, dec, last, caches, done, key, positions, stats = \
                 _refill_scan_decode(
                     params, cfg, state.last_logits, state.caches, key, steps,
                     float(temperature), bool(stop_at_eos), state.positions,
@@ -762,7 +809,7 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
                       key if state.key is not None else None,
                       state.max_len, used + steps, paged=pg,
                       prefill_rows=0 if refill is None else len(rows),
-                      row_shards=state.row_shards)
+                      row_shards=state.row_shards, stats=stats)
     return new, gen, dec
 
 

@@ -148,6 +148,12 @@ class SchedulerStats:
     #                                 computed (each its row bucket)
     prefill_launches_by_rows: Counter = dataclasses.field(
         default_factory=Counter)    # rows computed -> launch count
+    # expert layers (folded at every segment boundary, as the slot-step
+    # counters): tokens each expert layer routed to each held expert,
+    # (expert layers, experts held), in the refill prefills and in the
+    # decode steps (every slot, live or not); None without expert layers
+    expert_tokens_prefill: Optional[np.ndarray] = None
+    expert_tokens_decode: Optional[np.ndarray] = None
     # paged-KV accounting (segment granularity, folded in by
     # SlotRun.account / SlotRuntime._admit).  pages_in_use / kv_live_tokens
     # are gauges (last retire's snapshot); the peaks are monotonic maxima.
@@ -248,6 +254,22 @@ class SchedulerStats:
         gated = self.tier0_answered + self.escalated
         return self.escalated / gated if gated else 1.0
 
+    def expert_tokens_summary(self) -> Dict[str, Any]:
+        """Per phase: tokens routed to each held expert, summed over the
+        expert layers, and the largest layer-expert count over the mean
+        (1.0 = an even load)."""
+        out: Dict[str, Any] = {}
+        for phase in ("prefill", "decode"):
+            got = getattr(self, f"expert_tokens_{phase}")
+            if got is None:
+                continue
+            mean = float(got.mean())
+            out[phase] = {"per_expert": got.sum(axis=0).tolist(),
+                          "max_over_mean":
+                              round(float(got.max()) / mean, 4) if mean
+                              else 0.0}
+        return out
+
     def queue_age_percentiles(self) -> Dict[str, float]:
         """Seconds spent queued, per emitted prompt (p50/p95/max)."""
         if not self.queue_ages:
@@ -274,6 +296,7 @@ class SchedulerStats:
                 "prefill_rows": self.prefill_rows,
                 "prefill_launches_by_rows":
                     dict(sorted(self.prefill_launches_by_rows.items())),
+                "expert_tokens": self.expert_tokens_summary(),
                 "slot_occupancy": round(self.slot_occupancy, 4),
                 "kv_pages": {"page_size": self.kv_page_size,
                              "in_use": self.pages_in_use,

@@ -876,12 +876,14 @@ def test_paged_guards(tiny_trained):
                                   kv_pool=pool)
     with pytest.raises(ValueError, match="paged row"):
         sampler.decode_segment(params, cfg, state, 7)
-    # non-GQA backbones are rejected up front
+    # backbones with no paged layout (SSM states, sliding windows) are
+    # rejected up front; MLA latents have one
     from repro.configs import get_config
     with pytest.raises(ValueError, match="paged"):
         check_paged_support(get_config("mamba2-1.3b").reduced())
     with pytest.raises(ValueError, match="paged"):
-        check_paged_support(get_config("deepseek-v2-lite-16b").reduced())
+        check_paged_support(get_config("gemma2-2b").reduced())
+    check_paged_support(get_config("deepseek-v2-lite-16b").reduced())
 
 
 def test_slot_run_paged_matches_dense(tiny_trained):
