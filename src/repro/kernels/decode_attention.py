@@ -15,9 +15,12 @@ into SMEM like the paged kernel's table) and an optional sliding window
 physical pages and each row's logical block ``iw`` is resolved through a
 scalar-prefetched page table — ``PrefetchScalarGridSpec`` makes the table
 available to the BlockSpec index map, so the grid DMAs exactly the pages a
-row owns and never materializes a gathered dense cache.  Callers select
-the implementation via the ``KernelType`` enum (``KernelTypeMapping`` in
-``kernels/ops.py`` maps it to this kernel or the XLA gather path).
+row owns and never materializes a gathered dense cache.  Given a layer
+index it reads one layer of the whole stacked pool (L, n_pages, hkv,
+page_size, hd) the same way, so the decode layer scan never slices it.
+Callers select the implementation via the ``KernelType`` enum
+(``KernelTypeMapping`` in ``kernels/ops.py`` maps it to this kernel or the
+XLA gather path).
 
 Validated against ``ref.attention`` / ``ops.decode_attention`` in
 interpret mode; both kernels compile for TPU v5e
@@ -147,16 +150,17 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(b, hq, 1, dv)
 
 
-def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, scale: float,
-                         softcap: float, page_size: int, kv_cap: int,
-                         n_w: int):
+def _paged_decode_kernel(table_ref, len_ref, layer_ref, q_ref, k_ref,
+                         v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                         scale: float, softcap: float, page_size: int,
+                         kv_cap: int, n_w: int):
     """One grid step = one logical page of one (batch row, kv head).
 
-    ``table_ref``/``len_ref`` are scalar-prefetched: the flattened page
-    table already steered the BlockSpec index map, so ``k_ref``/``v_ref``
-    hold the *physical* page this row's logical block ``iw`` maps to
-    (the trash page for unallocated entries — fully masked below).
+    ``table_ref``/``len_ref``/``layer_ref`` are scalar-prefetched: the
+    flattened page table and the layer index already steered the
+    BlockSpec index map, so ``k_ref``/``v_ref`` hold the *physical* page
+    this row's logical block ``iw`` maps to (the trash page for
+    unallocated entries — fully masked below).
     """
     ib = pl.program_id(0)
     iw = pl.program_id(2)
@@ -205,23 +209,29 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            *, page_size: int, kv_cap: int,
                            softcap: float = 0.0,
                            scale: Optional[float] = None,
+                           layer=None,
                            interpret: Optional[bool] = None) -> jax.Array:
     """Block-paged decode attention.
 
     q: (b, hq, 1, d); k_pages/v_pages: (n_pages, hkv, page_size, d[v])
-    physical page storage; page_table: (b, W) int32 mapping each row's
+    physical page storage, or with ``layer`` (an int32 scalar) the stacked
+    storage of every layer (L, n_pages, hkv, page_size, d[v]), of which
+    layer ``layer`` is read; page_table: (b, W) int32 mapping each row's
     logical page to a physical one; cache_len: (b,) or scalar valid
     lengths; kv_cap: the per-row logical capacity (W * page_size rounded
     down to it).  Returns (b, hq, 1, dv).
     """
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     b, hq, _, d = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[2]
     dv = v_pages.shape[-1]
     g = hq // hkv
     n_w = page_table.shape[1]
     scale = scale if scale is not None else d ** -0.5
     cache_len = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
     table_flat = jnp.asarray(page_table, jnp.int32).reshape(-1)   # (b*W,)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     qg = q.reshape(b, hkv, g, d)
 
@@ -229,20 +239,21 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         _paged_decode_kernel, scale=scale, softcap=softcap,
         page_size=page_size, kv_cap=kv_cap, n_w=n_w)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, hkv, n_w),
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
-                         lambda b_, h, iw, tbl, lens: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, d),
-                         lambda b_, h, iw, tbl, lens:
-                         (tbl[b_ * n_w + iw], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, dv),
-                         lambda b_, h, iw, tbl, lens:
-                         (tbl[b_ * n_w + iw], h, 0, 0)),
+                         lambda b_, h, iw, tbl, lens, lyr: (b_, h, 0, 0)),
+            pl.BlockSpec((None, 1, 1, page_size, d),
+                         lambda b_, h, iw, tbl, lens, lyr:
+                         (lyr[0], tbl[b_ * n_w + iw], h, 0, 0)),
+            pl.BlockSpec((None, 1, 1, page_size, dv),
+                         lambda b_, h, iw, tbl, lens, lyr:
+                         (lyr[0], tbl[b_ * n_w + iw], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv),
-                               lambda b_, h, iw, tbl, lens: (b_, h, 0, 0)),
+                               lambda b_, h, iw, tbl, lens, lyr:
+                               (b_, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g, 1), jnp.float32),
             pltpu.VMEM((g, 1), jnp.float32),
@@ -254,5 +265,5 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
         interpret=resolve_interpret(interpret, "paged_decode_attention"),
-    )(table_flat, cache_len, qg, k_pages, v_pages)
+    )(table_flat, cache_len, layer, qg, k_pages, v_pages)
     return out.reshape(b, hq, 1, dv)

@@ -228,9 +228,12 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0,
 def _paged_decode_attention_xla(q, k_pages, v_pages, cache_len, page_table,
                                 *, page_size: int, kv_cap: int,
                                 softcap: float = 0.0,
-                                scale: Optional[float] = None) -> jax.Array:
+                                scale: Optional[float] = None,
+                                layer=None) -> jax.Array:
     """XLA paged path: gather each row's pages into a dense per-row view,
     slice to ``kv_cap``, then run the exact dense masked-softmax above.
+    With ``layer`` the gather indexes the stacked pages as
+    ``pages[layer, table]``, so no per-layer slice of them is made.
 
     Every valid cache position holds the same value as the dense layout
     (the scatter wrote it there) and every position past ``cache_len``
@@ -238,11 +241,13 @@ def _paged_decode_attention_xla(q, k_pages, v_pages, cache_len, page_table,
     **bit-identical** to the dense oracle whenever ``kv_cap`` equals the
     dense cache length — the parity the tests pin down.
     """
+    if layer is None:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
     b, hq, _, d = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[2]
     n_w = page_table.shape[1]
-    kd = k_pages[page_table]                    # (b, W, hkv, page, hd)
-    vd = v_pages[page_table]
+    kd = k_pages[layer, page_table]             # (b, W, hkv, page, hd)
+    vd = v_pages[layer, page_table]
     kd = kd.transpose(0, 2, 1, 3, 4).reshape(
         b, hkv, n_w * page_size, d)[:, :, :kv_cap]
     vd = vd.transpose(0, 2, 1, 3, 4).reshape(
@@ -254,11 +259,11 @@ def _paged_decode_attention_xla(q, k_pages, v_pages, cache_len, page_table,
 def _paged_decode_attention_pallas(q, k_pages, v_pages, cache_len,
                                    page_table, *, page_size: int,
                                    kv_cap: int, softcap: float = 0.0,
-                                   scale: Optional[float] = None
-                                   ) -> jax.Array:
+                                   scale: Optional[float] = None,
+                                   layer=None) -> jax.Array:
     return _da.paged_decode_attention(
         q, k_pages, v_pages, cache_len, page_table, page_size=page_size,
-        kv_cap=kv_cap, softcap=softcap, scale=scale)
+        kv_cap=kv_cap, softcap=softcap, scale=scale, layer=layer)
 
 
 # KernelType -> implementation, the dispatch idiom shared with the other
@@ -273,17 +278,20 @@ KernelTypeMapping = {
 def paged_decode_attention(q, k_pages, v_pages, cache_len, page_table, *,
                            page_size: int, kv_cap: int, softcap: float = 0.0,
                            scale: Optional[float] = None,
-                           kernel=_da.KernelType.XLA) -> jax.Array:
+                           kernel=_da.KernelType.XLA,
+                           layer=None) -> jax.Array:
     """Single-token attention against a block-paged cache.
 
     q: (b, hq, 1, d); k_pages/v_pages: (n_pages, hkv, page_size, d)
-    physical page storage (the last page is the trash page);
-    page_table: (b, W) int32; cache_len: scalar or (b,) valid lengths
-    INCLUDING the current token.
+    physical page storage (the last page is the trash page), or with
+    ``layer`` (an int32 scalar) every layer's storage stacked (L, n_pages,
+    hkv, page_size, d), read at that layer in place; page_table: (b, W)
+    int32; cache_len: scalar or (b,) valid lengths INCLUDING the current
+    token.
     """
     return KernelTypeMapping[kernel](
         q, k_pages, v_pages, cache_len, page_table, page_size=page_size,
-        kv_cap=kv_cap, softcap=softcap, scale=scale)
+        kv_cap=kv_cap, softcap=softcap, scale=scale, layer=layer)
 
 
 # ---------------------------------------------------------------------------

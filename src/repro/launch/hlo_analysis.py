@@ -91,6 +91,38 @@ def parse_hlo(text: str):
     return comps, entry
 
 
+def entry_parameters(text: str) -> Dict[int, Tuple[int, ...]]:
+    """Entry-computation parameter number -> its shape."""
+    comps, entry = parse_hlo(text)
+    out = {}
+    for ins in comps[entry]:
+        if ins.op == "parameter":
+            out[int(re.match(r"\s*(\d+)", ins.rest).group(1))] = tuple(
+                _shapes_in(ins.type_str)[0][1])
+    return out
+
+
+def aliased_parameters(text: str) -> List[int]:
+    """Entry parameter numbers that the module's ``input_output_alias``
+    hands to an output: donated arguments whose buffer the executable
+    updates in place and returns."""
+    header = text.split("\n", 1)[0]
+    return sorted(int(p) for p in re.findall(r"\{[\d,]*\}:\s*\((\d+),",
+                                             header))
+
+
+def ops_shaped_like(text: str, ops, shapes) -> List[str]:
+    """``computation/instruction`` of every instruction, fused
+    computations included, whose opcode is in ``ops`` and whose result has
+    one of ``shapes`` up to the order of its dimensions (so a transposed
+    copy counts)."""
+    want = {tuple(sorted(s)) for s in shapes}
+    comps, _ = parse_hlo(text)
+    return [f"{c}/{ins.name}" for c, instrs in comps.items() for ins in instrs
+            if ins.op in ops and any(tuple(sorted(d)) in want
+                                     for _, d in _shapes_in(ins.type_str))]
+
+
 def analyze(text: str) -> Dict:
     comps, entry = parse_hlo(text)
 

@@ -3,7 +3,10 @@ cross-attention (enc-dec), and Multi-head Latent Attention (DeepSeek-V2).
 
 Shapes: activations are (batch, seq, d_model); heads are split internally.
 Every block exposes a full-sequence path (train/prefill, returns the KV
-cache slice) and a single-token decode path (reads/writes a cache).
+cache slice) and a single-token decode path.  A decode path takes the
+cache of its whole layer stack (leading layer axis) and the layer index:
+it writes its new token there in place and reads that layer through the
+index, so the decode layer scan never slices or restacks a cache.
 """
 from __future__ import annotations
 
@@ -80,16 +83,25 @@ def gqa_full(p, cfg: ModelConfig, x, cos, sin, *, kind: str = "attn",
     return y, {"k": kh, "v": vh}
 
 
-def gqa_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
+def _heads(cfg: ModelConfig) -> jax.Array:
+    """(1, hkv) head indices for writing one token's K/V per row: indexing
+    every axis ahead of head_dim keeps the scatter's indexed axes leading
+    and contiguous, so XLA updates the cache in place rather than first
+    transposing it to bring them together."""
+    return jnp.arange(cfg.num_kv_heads)[None, :]
+
+
+def gqa_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos, layer,
                *, kind: str = "attn") -> Tuple[jax.Array, Dict]:
     """Single-token GQA decode.
 
-    x: (b, 1, d); cache["k"/"v"]: (b, hkv, S, hd); pos: scalar or (b,)
-    int — per-row number of tokens already cached (row ``i``'s new token
-    has absolute position ``pos[i]``).  Per-row positions let rows at
-    different sequence offsets (continuous batching, ragged prompt
-    lengths) share one decode executable: the new KV lands at each row's
-    own slot and the attention mask sees each row's own valid length.
+    x: (b, 1, d); cache["k"/"v"]: (L, b, hkv, S, hd), of which layer
+    ``layer`` is this one's; pos: scalar or (b,) int — per-row number of
+    tokens already cached (row ``i``'s new token has absolute position
+    ``pos[i]``).  Per-row positions let rows at different sequence
+    offsets (continuous batching, ragged prompt lengths) share one decode
+    executable: the new KV lands at each row's own slot and the attention
+    mask sees each row's own valid length.
 
     Windowed layers whose cache is allocated at exactly ``window`` entries
     run in **ring-buffer mode**: the new KV lands at ``pos % window`` and
@@ -101,7 +113,7 @@ def gqa_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
     hd = cfg.resolved_head_dim
     q, k, v = _gqa_qkv(p, cfg, x, cos, sin)
     window = resolve_window(cfg, kind)
-    S_cache = cache["k"].shape[2]
+    S_cache = cache["k"].shape[3]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     ring = window > 0 and S_cache == window
     if ring:
@@ -112,15 +124,13 @@ def gqa_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
         slot = pos
         valid = pos + 1
         attn_window = window
-    rows = jnp.arange(b)
-    kc = cache["k"].at[rows, :, slot].set(
-        k.transpose(0, 2, 1, 3)[:, :, 0].astype(cache["k"].dtype),
-        unique_indices=True)
-    vc = cache["v"].at[rows, :, slot].set(
-        v.transpose(0, 2, 1, 3)[:, :, 0].astype(cache["v"].dtype),
-        unique_indices=True)
+    at = (layer, jnp.arange(b)[:, None], _heads(cfg), slot[:, None])
+    kc = cache["k"].at[at].set(k[:, 0].astype(cache["k"].dtype),
+                               unique_indices=True)
+    vc = cache["v"].at[at].set(v[:, 0].astype(cache["v"].dtype),
+                               unique_indices=True)
     out = ops.decode_attention(
-        q.transpose(0, 2, 1, 3), kc, vc, valid,
+        q.transpose(0, 2, 1, 3), kc[layer], vc[layer], valid,
         window=attn_window,
         softcap=cfg.logit_softcap,
         scale=cfg.attn_scale or None)
@@ -129,39 +139,39 @@ def gqa_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
 
 
 def gqa_decode_paged(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
-                     table, spec, *, kind: str = "attn"
+                     layer, table, spec, *, kind: str = "attn"
                      ) -> Tuple[jax.Array, Dict]:
     """Single-token GQA decode against a block-paged cache.
 
-    cache["k"/"v"]: (n_pages, hkv, page_size, hd) physical pages shared by
-    the whole batch; ``table``: (b, W) int32 page table; ``spec``: a
+    cache["k"/"v"]: (L, n_pages, hkv, page_size, hd), every layer's
+    physical pages shared by the whole batch, of which layer ``layer`` is
+    this one's; ``table``: (b, W) int32 page table; ``spec``: a
     ``PagedSpec`` (static page_size / kv_cap / kernel).  The new KV
     scatters into slot ``pos % page_size`` of physical page
-    ``table[row, pos // page_size]``.  The logical page index is clamped
-    to the table width: live rows never pass ``kv_cap`` (the sampler
-    guards each segment), so the clamp only fires for retired/done rows
-    whose table points at the trash page — their PAD writes collide there
-    harmlessly, which is also why the scatter must NOT claim unique
-    indices.  No ring-buffer mode: paged layers are full-window only
-    (``kv_pool.check_paged_support``).
+    ``table[row, pos // page_size]`` of the layer, and attention reads the
+    layer's pages through the table with the layer index folded in.  The
+    logical page index is clamped to the table width: live rows never
+    pass ``kv_cap`` (the sampler guards each segment), so the clamp only
+    fires for retired/done rows whose table points at the trash page —
+    their PAD writes collide there harmlessly, which is also why the
+    scatter must NOT claim unique indices.  No ring-buffer mode: paged
+    layers are full-window only (``kv_pool.check_paged_support``).
     """
     b = x.shape[0]
     hd = cfg.resolved_head_dim
     q, k, v = _gqa_qkv(p, cfg, x, cos, sin)
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     lp = jnp.minimum(pos // spec.page_size, table.shape[1] - 1)
-    rows = jnp.arange(b)
-    pid = table[rows, lp]                                # (b,)
+    pid = table[jnp.arange(b), lp]                       # (b,)
     slot = pos % spec.page_size
-    kc = cache["k"].at[pid, :, slot].set(
-        k.transpose(0, 2, 1, 3)[:, :, 0].astype(cache["k"].dtype))
-    vc = cache["v"].at[pid, :, slot].set(
-        v.transpose(0, 2, 1, 3)[:, :, 0].astype(cache["v"].dtype))
+    at = (layer, pid[:, None], _heads(cfg), slot[:, None])
+    kc = cache["k"].at[at].set(k[:, 0].astype(cache["k"].dtype))
+    vc = cache["v"].at[at].set(v[:, 0].astype(cache["v"].dtype))
     out = ops.paged_decode_attention(
         q.transpose(0, 2, 1, 3), kc, vc, pos + 1, table,
         page_size=spec.page_size, kv_cap=spec.kv_cap,
         softcap=cfg.logit_softcap, scale=cfg.attn_scale or None,
-        kernel=spec.kernel)
+        kernel=spec.kernel, layer=layer)
     y = out.transpose(0, 2, 1, 3).reshape(b, 1, cfg.num_heads * hd)
     return y @ p["wo"], {"k": kc, "v": vc}
 
@@ -306,12 +316,13 @@ def _mla_absorbed(p, cfg: ModelConfig, x, q_nope, q_rope, ckv, krope, mask):
     return v_ctx.reshape(b, 1, h * vdim).astype(x.dtype) @ p["wo"]
 
 
-def mla_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
+def mla_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos, layer,
                *, kind: str = "mla") -> Tuple[jax.Array, Dict]:
     """Absorbed-form MLA decode: attention runs in the latent space.
 
-    cache: {"c_kv": (b, S, lora), "k_rope": (b, S, rdim)}.  ``pos`` is a
-    scalar or per-row (b,) position, as in ``gqa_decode``.  The up
+    cache: {"c_kv": (L, b, S, lora), "k_rope": (L, b, S, rdim)}, of which
+    layer ``layer`` is this one's.  ``pos`` is a scalar or per-row (b,)
+    position, as in ``gqa_decode``.  The up
     projections w_uk/w_uv are folded into the query / output instead of
     re-expanding the cache each step (the TPU-friendly serving form — the
     naive form would up-project all S cached entries per token).
@@ -322,33 +333,35 @@ def mla_decode(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
     c_kv_new, k_rope_new = _mla_compress(p, cfg, x, cos, sin)
 
     rows = jnp.arange(b)
-    ckv = cache["c_kv"].at[rows, pos].set(
+    ckv = cache["c_kv"].at[layer, rows, pos].set(
         c_kv_new[:, 0].astype(cache["c_kv"].dtype), unique_indices=True)
-    krope = cache["k_rope"].at[rows, pos].set(
+    krope = cache["k_rope"].at[layer, rows, pos].set(
         k_rope_new[:, 0].astype(cache["k_rope"].dtype), unique_indices=True)
 
-    S = ckv.shape[1]
+    S = ckv.shape[2]
     kpos = jnp.arange(S)[None, None]                    # (1, 1, S)
     mask = kpos <= pos[:, None, None]                   # (b, 1, S)
     window = resolve_window(cfg, kind)
     if window > 0:
         mask = mask & (kpos > pos[:, None, None] - window)
-    y = _mla_absorbed(p, cfg, x, q_nope, q_rope, ckv, krope, mask)
+    y = _mla_absorbed(p, cfg, x, q_nope, q_rope, ckv[layer], krope[layer],
+                      mask)
     return y, {"c_kv": ckv, "k_rope": krope}
 
 
 def mla_decode_paged(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
-                     table, spec) -> Tuple[jax.Array, Dict]:
+                     layer, table, spec) -> Tuple[jax.Array, Dict]:
     """Absorbed-form MLA decode against block-paged latent pages.
 
-    cache["c_kv"]: (n_pages, page_size, lora) and cache["k_rope"]:
-    (n_pages, page_size, rdim), physical pages shared by the batch, as
-    ``gqa_decode_paged`` keeps K and V; ``table`` (b, W) and ``spec`` are
-    the same.  The new token's latent lands in slot ``pos % page_size`` of
-    page ``table[row, pos // page_size]`` (clamped, so retired rows write
-    to the trash page and the scatter must not claim unique indices); each
-    row then reads its pages up to ``kv_cap`` (the ``latent`` scope) and
-    attends to positions <= ``pos``.
+    cache["c_kv"]: (L, n_pages, page_size, lora) and cache["k_rope"]:
+    (L, n_pages, page_size, rdim), every layer's physical pages shared by
+    the batch, as ``gqa_decode_paged`` keeps K and V; ``layer``, ``table``
+    (b, W) and ``spec`` are the same.  The new token's latent lands in
+    slot ``pos % page_size`` of the layer's page ``table[row, pos //
+    page_size]`` (clamped, so retired rows write to the trash page and the
+    scatter must not claim unique indices); each row then reads its pages
+    of the layer up to ``kv_cap`` (the ``latent`` scope) and attends to
+    positions <= ``pos``.
     """
     b = x.shape[0]
     ps = spec.page_size
@@ -358,14 +371,15 @@ def mla_decode_paged(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
     lp = jnp.minimum(pos // ps, table.shape[1] - 1)
     pid = table[jnp.arange(b), lp]                       # (b,)
     slot = pos % ps
-    ckv_pages = cache["c_kv"].at[pid, slot].set(
+    ckv_pages = cache["c_kv"].at[layer, pid, slot].set(
         c_kv_new[:, 0].astype(cache["c_kv"].dtype))
-    krope_pages = cache["k_rope"].at[pid, slot].set(
+    krope_pages = cache["k_rope"].at[layer, pid, slot].set(
         k_rope_new[:, 0].astype(cache["k_rope"].dtype))
     with jax.named_scope("latent"):
         width = table.shape[1] * ps
-        ckv = ckv_pages[table].reshape(b, width, -1)[:, :spec.kv_cap]
-        krope = krope_pages[table].reshape(b, width, -1)[:, :spec.kv_cap]
+        ckv = ckv_pages[layer, table].reshape(b, width, -1)[:, :spec.kv_cap]
+        krope = krope_pages[layer, table].reshape(b, width,
+                                                  -1)[:, :spec.kv_cap]
     kpos = jnp.arange(ckv.shape[1])[None, None]          # (1, 1, S)
     mask = kpos <= pos[:, None, None]                    # (b, 1, S)
     y = _mla_absorbed(p, cfg, x, q_nope, q_rope, ckv, krope, mask)

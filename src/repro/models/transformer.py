@@ -186,47 +186,51 @@ def block_full(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
 # Block apply — single-token decode
 # ---------------------------------------------------------------------------
 def block_decode(p: Dict, cfg: ModelConfig, kind: str, h: jax.Array,
-                 cos, sin, cache: Dict, pos, *, paged=None
+                 cos, sin, cache: Dict, pos, layer, *, paged=None
                  ) -> Tuple[jax.Array, Dict, Dict]:
-    """Returns (h, cache, stats).  ``paged`` = (PagedSpec, page table
-    (b, W)) routes attention layers through the block-paged cache layout
-    (GQA K/V pages or MLA latent pages); ``kv_pool.check_paged_support``
-    guarantees only full-window GQA and MLA kinds reach here when it is
-    set."""
+    """Returns (h, cache, stats).  ``cache`` holds the block's leaves for
+    its whole layer stack (leading layer axis) and ``layer`` is this
+    block's index in it: attention writes its new token there in place
+    and reads through the index; a recurrent (SSM) state and the cross
+    caches, which a step reads whole, are read as the layer's slice.
+    ``paged`` = (PagedSpec, page table (b, W)) routes attention layers
+    through the block-paged cache layout (GQA K/V pages or MLA latent
+    pages); ``kv_pool.check_paged_support`` guarantees only full-window
+    GQA and MLA kinds reach here when it is set."""
     if kind == "mamba":
-        y, new = ssm_mod.mamba_decode(p["mamba"], cfg,
-                                      rmsnorm(p["norm"], h, cfg.rmsnorm_eps),
-                                      cache)
-        return h + y, new, {}
+        y, new = ssm_mod.mamba_decode(
+            p["mamba"], cfg, rmsnorm(p["norm"], h, cfg.rmsnorm_eps),
+            {name: leaf[layer] for name, leaf in cache.items()})
+        return h + y, {name: leaf.at[layer].set(new[name].astype(leaf.dtype))
+                       for name, leaf in cache.items()}, {}
 
-    new_cache: Dict = {}
+    new_cache = dict(cache)
     with jax.named_scope("attn"):
         x = rmsnorm(p["attn_norm"], h, cfg.rmsnorm_eps)
         if paged is not None and _is_mla(kind):
             spec, table = paged
             y, kv = attn_mod.mla_decode_paged(p["attn"], cfg, x, cos, sin,
-                                              cache, pos, table, spec)
+                                              cache, pos, layer, table, spec)
         elif paged is not None:
             spec, table = paged
             y, kv = attn_mod.gqa_decode_paged(p["attn"], cfg, x, cos, sin,
-                                              cache, pos, table, spec,
+                                              cache, pos, layer, table, spec,
                                               kind=kind)
         elif _is_mla(kind):
             y, kv = attn_mod.mla_decode(p["attn"], cfg, x, cos, sin, cache,
-                                        pos, kind=kind)
+                                        pos, layer, kind=kind)
         else:
             y, kv = attn_mod.gqa_decode(p["attn"], cfg, x, cos, sin, cache,
-                                        pos, kind=kind)
+                                        pos, layer, kind=kind)
         if cfg.sandwich_norm:
             y = rmsnorm(p["post_attn_norm"], y, cfg.rmsnorm_eps)
         h = h + y
     new_cache.update(kv)
 
     if "cross" in p:
-        ckv = {"ck": cache["ck"], "cv": cache["cv"]}
+        ckv = {"ck": cache["ck"][layer], "cv": cache["cv"][layer]}
         xc = rmsnorm(p["cross_norm"], h, cfg.rmsnorm_eps)
         h = h + attn_mod.cross_attend(p["cross"], cfg, xc, ckv)
-        new_cache.update(ckv)
 
     h, _, stats = _ffn(p, cfg, kind, h, True, False)
     return h, new_cache, stats
@@ -291,23 +295,26 @@ def segment_full(seg_params: Dict, shared_params, cfg: ModelConfig,
 def segment_decode(seg_params: Dict, shared_params, cfg: ModelConfig,
                    unit: Tuple[str, ...], count: int, h: jax.Array, cos, sin,
                    caches: Dict, pos, *, paged=None):
-    # the page table (in ``paged``) is closed over, not scanned: every
-    # layer shares one table while each scanned layer consumes its own
-    # (n_pages, hkv, page, hd) slice of the stacked page storage
-    def body(hh, xs):
-        layer_caches = xs["__cache__"]
-        new_caches, stats = {}, {}
+    # the stacked caches ride the scan carry, not its xs: each layer
+    # updates its own index of them in place (``block_decode``), so no
+    # layer's slice is cut out and no new stack is built per step; only the
+    # weights are scanned.  The page table (in ``paged``) is closed over:
+    # every layer shares one table.
+    def body(carry, xs):
+        hh, cache = carry
+        layer, layer_params = xs
+        cache, stats = dict(cache), {}
         for j, kind in enumerate(unit):
-            p = shared_params if kind == "shared_attn" else xs[str(j)]
+            p = (shared_params if kind == "shared_attn"
+                 else layer_params[str(j)])
             kk = "attn" if kind == "shared_attn" else kind
-            hh, nc, st = block_decode(p, cfg, kk, hh, cos, sin,
-                                      layer_caches[str(j)], pos, paged=paged)
-            new_caches[str(j)] = nc
+            hh, cache[str(j)], st = block_decode(
+                p, cfg, kk, hh, cos, sin, cache[str(j)], pos, layer,
+                paged=paged)
             if st:
                 stats[str(j)] = st
-        return hh, (new_caches, stats)
+        return (hh, cache), stats
 
-    xs = dict(seg_params)
-    xs["__cache__"] = caches
-    h, (new_caches, stats) = jax.lax.scan(body, h, xs, length=count)
-    return h, new_caches, stats
+    (h, caches), stats = jax.lax.scan(
+        body, (h, caches), (jnp.arange(count), seg_params), length=count)
+    return h, caches, stats

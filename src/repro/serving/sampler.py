@@ -176,9 +176,9 @@ def _run_scan(params, cfg: ModelConfig, last_logits, caches, key,
     return gen.T, dec.transpose(1, 0, 2), last, kv, done, key, stats
 
 
-# no donate_argnums on the caches: XLA reports the KV buffers as unusable
-# donations for a scan carry (they are not jit outputs), so donating would
-# only emit a warning per call without saving the copy
+# the dense executables keep their caches undonated: a dense state may be
+# decoded more than once (the tests branch segments from one state), and no
+# serve path runs them.  The paged twins below donate theirs.
 @functools.partial(jax.jit, static_argnums=(1, 5, 6, 7))
 def _scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
                  steps: int, temperature: float, stop_at_eos: bool,
@@ -317,6 +317,14 @@ def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
 # ---------------------------------------------------------------------------
 # Paged twins: prefill-scatter + decode over the block-paged KV layout
 # ---------------------------------------------------------------------------
+def _layers(count: int) -> jax.Array:
+    """(count, 1) layer indices for a page scatter into stacked storage:
+    indexing the layer axis with the page axis keeps the scatter's indexed
+    axes leading, so XLA writes the pages in place rather than first
+    transposing the storage to bring the page axis forward."""
+    return jnp.arange(count)[:, None]
+
+
 def _paged_latent_scatter(leaf, storage, page_ids, page_size: int):
     """``_paged_leaf_scatter`` for an MLA latent leaf: (count, b, L, r)
     into latent pages (count, n_pages + 1, page_size, r)."""
@@ -326,7 +334,8 @@ def _paged_latent_scatter(leaf, storage, page_ids, page_size: int):
     if pad:
         leaf = jnp.pad(leaf, [(0, 0), (0, 0), (0, pad), (0, 0)])
     blocks = leaf.reshape(count, b * npg, page_size, r)
-    return storage.at[:, page_ids].set(blocks.astype(storage.dtype))
+    return storage.at[_layers(count), page_ids].set(
+        blocks.astype(storage.dtype))
 
 
 def _paged_storage(leaf, n_pages_total: int, page_size: int):
@@ -358,7 +367,8 @@ def _paged_leaf_scatter(leaf, storage, page_ids, page_size: int):
     blocks = leaf.reshape(count, b, hkv, npg, page_size, hd)
     blocks = blocks.transpose(0, 1, 3, 2, 4, 5).reshape(
         count, b * npg, hkv, page_size, hd)
-    return storage.at[:, page_ids].set(blocks.astype(storage.dtype))
+    return storage.at[_layers(count), page_ids].set(
+        blocks.astype(storage.dtype))
 
 
 def _scatter_prefill_caches(caches, storage_of, page_ids, page_size: int):
@@ -448,7 +458,13 @@ def _paged_refill_prefill(params, cfg: ModelConfig, tokens, page_size: int,
             new, storage_of, page_ids, page_size)
 
 
-@functools.partial(jax.jit, static_argnums=(1, 5, 6, 7, 8))
+# The paged executables donate the page pool (``caches``, argument 3): the
+# layer scan carries it and writes each new token into it in place, so the
+# pool returned aliases the pool passed and a launch copies none of it.
+# The caller's pool is dead after the call; ``decode_segment`` hands the
+# returned one on in the new state.
+@functools.partial(jax.jit, static_argnums=(1, 5, 6, 7, 8),
+                   donate_argnums=(3,))
 def _paged_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
                        steps: int, temperature: float, stop_at_eos: bool,
                        spec, table, positions, done):
@@ -462,7 +478,8 @@ def _paged_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
                      paged=(spec, table))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 5, 6, 7, 8))
+@functools.partial(jax.jit, static_argnums=(1, 5, 6, 7, 8),
+                   donate_argnums=(3,))
 def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
                               key, steps: int, temperature: float,
                               stop_at_eos: bool, spec, table, positions,
@@ -672,8 +689,11 @@ def warm_row_buckets(params, cfg: ModelConfig, state: DecodeState,
     refill prompts' width and ``steps`` its segment length; the launches
     sample as ``decode_segment`` does by default (greedy, stopping at
     EOS).  Every row of them is filler — no update lands, every page
-    block goes to trash — and their outputs are dropped, so ``state`` is
-    untouched.  A signature already warmed in this process is skipped.
+    block and decode write goes to trash — so the pool each launch
+    returns (it donates the one it takes) differs from the one it took in
+    the trash page alone, which no read sees; it becomes ``state``'s pool,
+    and the launches' other outputs are dropped.  A signature already
+    warmed in this process is skipped.
     """
     pg = state.paged
     b = state.batch
@@ -687,13 +707,13 @@ def warm_row_buckets(params, cfg: ModelConfig, state: DecodeState,
     pad = np.full((b, width), PAD, np.int32)
     for size in row_buckets(b, state.row_shards):
         rows = np.full((size,), b, np.int32)
-        # one at a time: each holds a copy of the pool until it ends
-        jax.block_until_ready(_paged_refill_scan_decode(
+        out = _paged_refill_scan_decode(
             params, cfg, state.last_logits, state.caches, key, int(steps),
             0.0, True, pg.spec, pg.device_table(), state.positions,
             state.done, *_refill_operands(rows, pad, np.ones((b,), np.int64)),
             jnp.asarray(pg.prompt_page_ids(
-                rows, _ceil_div(width, pg.page_size)).reshape(-1))))
+                rows, _ceil_div(width, pg.page_size)).reshape(-1)))
+        state.caches = jax.block_until_ready(out[3])
 
 
 def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
@@ -706,7 +726,9 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
     (``np.asarray``), which is what lets the serve runtime overlap host
     assembly with device decode.  Chaining segments is bit-identical to one
     segment of the summed length (the scan body is unchanged and the
-    sampling key is carried).
+    sampling key is carried).  A paged ``state`` is consumed: its page
+    pool is donated to the launch and updated in place, so decode on from
+    the returned state only.
 
     ``refill`` = (mask (b,), prompts (b, W), prompt_lens (b,)) admits new
     requests into the masked slots **in the same executable launch**: the

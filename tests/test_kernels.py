@@ -268,6 +268,29 @@ def test_paged_decode_pallas_vs_xla(case):
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("kernel", ["XLA", "PALLAS"])
+def test_paged_decode_reads_one_layer_of_stacked_pages(kernel):
+    """Given a layer index, each paged path reads that layer of the stacked
+    storage (L, n_pages + 1, hkv, page, d) in place, exactly as it reads
+    that layer's storage alone."""
+    from repro.kernels.decode_attention import KernelType
+    b, hq, hkv, S, d, page, lens, cap = PAGED_CASES[0]
+    q = jax.random.normal(jax.random.PRNGKey(17), (b, hq, 1, d)) * 0.5
+    stores = [_paged_layout(jax.random.PRNGKey(18 + i), b, hkv, S, d, page,
+                            n_pages=64) for i in range(3)]
+    k_all = jnp.stack([st[2] for st in stores])
+    v_all = jnp.stack([st[3] for st in stores])
+    table, clen = stores[0][4], jnp.asarray(lens, jnp.int32)
+    kw = dict(page_size=page, kv_cap=S, softcap=cap,
+              kernel=KernelType[kernel])
+    for layer in range(3):
+        want = ops.paged_decode_attention(q, k_all[layer], v_all[layer],
+                                          clen, table, **kw)
+        got = ops.paged_decode_attention(q, k_all, v_all, clen, table,
+                                         layer=jnp.int32(layer), **kw)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_paged_decode_trash_page_is_inert():
     """Garbage in the trash page (or any unreferenced page) cannot leak
     into the output: only table-referenced, length-valid positions

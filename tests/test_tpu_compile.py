@@ -73,6 +73,18 @@ def _paged():
              ((B, N_W), jnp.int32)])
 
 
+def _paged_stacked():
+    # one layer of the stacked pool the decode layer scan carries
+    n_pages = B * N_W + 1
+    return (lambda q, k, v, n, t, layer: da.paged_decode_attention(
+                q, k, v, n, t, page_size=PAGE, kv_cap=KV_CAP, layer=layer,
+                interpret=False),
+            [((B, HQ, 1, D), jnp.bfloat16),
+             ((CONFIG.num_layers, n_pages, HKV, PAGE, D), jnp.bfloat16),
+             ((CONFIG.num_layers, n_pages, HKV, PAGE, D), jnp.bfloat16),
+             ((B,), jnp.int32), ((B, N_W), jnp.int32), ((), jnp.int32)])
+
+
 def _ssd():
     # Mamba2 heads: head dim 64, state 128, two 128-token chunks
     return (lambda x, dt, a, b, c: ss.ssd_scan(x, dt, a, b, c, chunk=128,
@@ -90,7 +102,8 @@ def _topk():
 
 
 KERNELS = {"flash_attention": _flash, "decode_attention": _decode,
-           "paged_decode_attention": _paged, "ssd_scan": _ssd,
+           "paged_decode_attention": _paged,
+           "paged_decode_attention_stacked": _paged_stacked, "ssd_scan": _ssd,
            "topk_retrieval": _topk}
 
 
