@@ -396,49 +396,49 @@ def bench_refill(engine, queries, *, bucket_sizes, segment_len: int = 4,
     ]
 
 
-def bench_paged(dense_engine, paged_engine, queries, *, bucket_sizes,
+def bench_paged(engine, paged_engine, queries, *, bucket_sizes,
                 segment_len: int = 4, repeats: int = 3, max_tick: int = 3,
                 smoke: bool = False) -> List[Dict]:
-    """Block-paged KV cache vs the dense per-slot horizon, same workload.
+    """Paged slots at a small page size vs whole-retire, same workload.
 
     Both engines carry the same trained parameters and stream the same
-    ragged refill workload; the only difference is the decode-cache
-    layout.  The paged engine's XLA gather path reconstructs exactly the
-    contiguous cache the dense kernel reads, so every token-derived field
-    must be bit-equal and the final routing decisions identical — the
-    page pool buys peak-KV headroom (``kv_peak_tokens`` scales with live
-    tokens rather than slots x horizon), not different outputs.
+    ragged workload: ``engine`` retires whole microbatches
+    (``refill=False``, one-shot dense caches), ``paged_engine`` refills
+    paged slots (``refill=True``) at its own ``kv_page_size``.  The paged
+    XLA gather path reconstructs exactly the contiguous cache the dense
+    kernel reads, so every token-derived field must be bit-equal and the
+    final routing decisions identical; the row reports what the page pool
+    held (``pages_peak``, ``kv_peak_tokens``).
     """
     from repro.api import FixedAlphaPolicy, RouteRequest
     from repro.serving.scheduler import BucketConfig, MicrobatchScheduler
     from repro.serving.scheduler import decode_compile_counts
 
-    seg = max(1, min(segment_len,
-                     int(dense_engine.estimator.max_new_tokens)))
+    seg = max(1, min(segment_len, int(engine.estimator.max_new_tokens)))
     ticks = _as_ticks(queries, _tick_sizes(len(queries), max_tick=max_tick))
     cfg = BucketConfig(batch_sizes=bucket_sizes)
 
-    def stream(engine):
+    def stream(eng, refill):
         sched = MicrobatchScheduler(cfg)
         t0 = time.perf_counter()
-        pools = list(engine.predict_stream(
+        pools = list(eng.predict_stream(
             (RouteRequest(t) for t in ticks), scheduler=sched,
-            use_cache=False, refill=True, segment_len=seg))
+            use_cache=False, refill=refill, segment_len=seg))
         return pools, time.perf_counter() - t0, sched
 
-    stream(dense_engine)            # warm both cache layouts' executables
-    stream(paged_engine)
+    stream(engine, False)           # warm both paths' executables
+    stream(paged_engine, True)
     warmed = decode_compile_counts()
 
-    t_dense = t_paged = None
-    dense_pools = paged_pools = s_dense = s_paged = None
+    t_whole = t_paged = None
+    whole_pools = paged_pools = s_paged = None
     for _ in range(repeats):
-        dense_pools, dt, s_dense = stream(dense_engine)
-        t_dense = dt if t_dense is None else min(t_dense, dt)
-        paged_pools, dt, s_paged = stream(paged_engine)
+        whole_pools, dt, _ = stream(engine, False)
+        t_whole = dt if t_whole is None else min(t_whole, dt)
+        paged_pools, dt, s_paged = stream(paged_engine, True)
         t_paged = dt if t_paged is None else min(t_paged, dt)
     recompiles = _compile_delta(warmed, decode_compile_counts())
-    qps_dense = len(queries) / t_dense
+    qps_whole = len(queries) / t_whole
     qps_paged = len(queries) / t_paged
 
     def cat(pools, field):
@@ -446,53 +446,46 @@ def bench_paged(dense_engine, paged_engine, queries, *, bucket_sizes,
                                for p in pools])
 
     token_identical = all(
-        np.array_equal(cat(paged_pools, f), cat(dense_pools, f))
+        np.array_equal(cat(paged_pools, f), cat(whole_pools, f))
         for f in ("y_hat", "len_hat", "well_formed", "cost_hat",
                   "pred_overhead"))
     conf_close = bool(np.allclose(cat(paged_pools, "p_hat"),
-                                  cat(dense_pools, "p_hat"),
+                                  cat(whole_pools, "p_hat"),
                                   atol=1e-6, rtol=1e-6))
     policy = FixedAlphaPolicy(0.6)
     choices_paged = np.concatenate(
-        [np.asarray(policy.decide(p, dense_engine).choices)
-         for p in paged_pools])
-    choices_dense = np.concatenate(
-        [np.asarray(policy.decide(p, dense_engine).choices)
-         for p in dense_pools])
-    identical_decisions = bool(np.array_equal(choices_paged, choices_dense))
+        [np.asarray(policy.decide(p, engine).choices) for p in paged_pools])
+    choices_whole = np.concatenate(
+        [np.asarray(policy.decide(p, engine).choices) for p in whole_pools])
+    identical_decisions = bool(np.array_equal(choices_paged, choices_whole))
 
-    st_p, st_d = s_paged.stats, s_dense.stats
+    st_p = s_paged.stats
     if smoke:
         assert recompiles == 0, (
             f"paged stream recompiled {recompiles} executables after "
             f"warmup — page tables are traced, so steady-state segments "
             f"must reuse the warmed bucket shapes")
         assert token_identical, (
-            "paged vs dense streams disagree on token-derived prediction "
-            "fields — the gather path lost bit parity")
-        assert conf_close, "paged vs dense confidences diverge"
+            "paged vs whole-retire streams disagree on token-derived "
+            "prediction fields — the gather path lost bit parity")
+        assert conf_close, "paged vs whole-retire confidences diverge"
         assert identical_decisions, (
-            "paged vs dense streams routed differently")
+            "paged vs whole-retire streams routed differently")
         assert st_p.pages_peak > 0 and st_p.kv_page_size > 0, (
             "the paged stream never touched the page pool")
-        assert st_p.kv_peak_tokens < st_d.kv_peak_tokens, (
-            f"paged peak KV {st_p.kv_peak_tokens} tokens does not beat "
-            f"the dense horizon's {st_d.kv_peak_tokens} — paging must "
-            f"cap KV at live tokens, not slots x horizon")
     return [
         {"name": "serve_throughput/engine_paged", "qps": qps_paged,
          "detail": {"queries": len(queries), "segment_len": seg,
                     "kv_page_size": st_p.kv_page_size,
                     "pages_peak": st_p.pages_peak,
                     "kv_peak_tokens": st_p.kv_peak_tokens,
-                    "kv_peak_tokens_dense": st_d.kv_peak_tokens,
                     "page_fragmentation":
                         round(st_p.page_fragmentation, 4),
                     "deferred_on_pages":
                         st_p.admissions_deferred_on_pages,
                     "recompiles_after_warmup": recompiles,
-                    "qps_vs_dense": round(qps_paged / max(qps_dense, 1e-9),
-                                          3),
+                    "qps_vs_whole_retire":
+                        round(qps_paged / max(qps_whole, 1e-9), 3),
                     "identical_decisions": identical_decisions}},
     ]
 
@@ -1033,11 +1026,9 @@ def run(bundle) -> List[Tuple[str, float, str]]:
     rows += bench_refill(bundle.engine(bundle.seen), queries,
                          bucket_sizes=BUCKETS)
     rows += bench_paged(bundle.engine(bundle.seen),
-                        bundle.engine(bundle.seen, kv_paged=True,
-                                      kv_page_size=8),
+                        bundle.engine(bundle.seen, kv_page_size=8),
                         queries, bucket_sizes=BUCKETS)
-    rows += bench_chaos(bundle.engine(bundle.seen, kv_paged=True,
-                                      kv_page_size=8),
+    rows += bench_chaos(bundle.engine(bundle.seen, kv_page_size=8),
                         queries, bucket_sizes=BUCKETS)
     rows += bench_tier0(bundle.engine(bundle.seen), queries,
                         bucket_sizes=BUCKETS, data=bundle.data,
@@ -1123,9 +1114,9 @@ def _smoke_trained_setup():
                              max_new_tokens=16, **ekw)
 
     engine = mk()
-    # paged twin: same params and pool, block-paged decode KV — streams
-    # must be bit-identical to the dense engine's refill streams
-    paged = mk(kv_paged=True, kv_page_size=8)
+    # same params and pool at a small KV page size — its refill streams
+    # must be bit-identical to the whole-retire streams
+    paged = mk(kv_page_size=8)
     queries = [data.queries[int(q)] for q in data.test_qids[:16]]
     return engine, paged, queries, data, mk
 
@@ -1171,8 +1162,8 @@ def main(argv=None) -> int:
               "overlap+sync streams bit-identical to batch predict, "
               "deadline flush ships partial buckets, refill stream beats "
               "whole-retire q/s at higher slot occupancy with identical "
-              "routing decisions, paged KV bit-identical to dense at "
-              "lower peak KV tokens, chaos stream delivers every pair "
+              "routing decisions, paged KV at page size 8 bit-identical "
+              "to whole-retire, chaos stream delivers every pair "
               "exactly once with a consistent fault ledger and the "
               "zero-fault plan bit-identical to no plan, tier-0 gating "
               "answers high-confidence pairs at >= 3x full-reasoning q/s "

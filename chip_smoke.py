@@ -228,7 +228,7 @@ def phase_stream(est, retriever, lib, world, data, warm_qids, qids, out):
         engine = ScopeEngine.build(EngineConfig(
             estimator=est, retriever=retriever, library=lib,
             models_meta=meta, refill=True, segment_len=SEGMENT,
-            kv_paged=True, kv_page_size=PAGE, kv_kernel=kernel))
+            kv_page_size=PAGE, kv_kernel=kernel))
         warm = MicrobatchScheduler(bucket)
         t0 = time.perf_counter()
         list(engine.serve_stream(data, ticks(warm_qids), policy,

@@ -16,10 +16,10 @@ walked recursively (scan bodies, pjit sub-jaxprs, pallas kernels) for:
   (``device_put`` to a host memory kind).
 
 Registry: ``register("name")(builder)`` where ``builder() -> ClosedJaxpr``.
-The default registry covers the serve path's six jitted executables —
-fused decode (``_scan_decode``), fused refill (``_refill_scan_decode``),
-the paged segment scan (``_paged_scan_decode``, XLA and Pallas kernels),
-the paged fused refill, and the tier-0 pre-router forward
+The default registry covers the serve path's five jitted executables —
+the dense one-shot decode (``_scan_decode``), the paged segment scan
+(``_paged_scan_decode``, XLA and Pallas kernels), the paged fused refill
+(``_paged_refill_scan_decode``), and the tier-0 pre-router forward
 (``tier0_forward``) — built over the TINY estimator config.  A
 builder that *fails to trace* is itself a finding: the hot path no longer
 compiles, which is worse than any primitive it might contain.
@@ -184,19 +184,6 @@ def _register_defaults() -> None:
             p, cfg, lg, c, k, T, 0.0, True, pos, dn)
         return jax.make_jaxpr(fn)(s["params"], s["last"], s["padded"],
                                   s["key"], s["pos"], s["done"])
-
-    @register("fused_refill")
-    def _fused_refill():
-        s = _abstract_serve_state()
-        cfg, B, L, T = s["cfg"], s["B"], s["L"], s["T"]
-        rows = jax.ShapeDtypeStruct((B,), jnp.int32)
-        rlens = jax.ShapeDtypeStruct((B,), jnp.int32)
-        fn = lambda p, lg, c, k, pos, dn, r, rp, rl: \
-            sampler._refill_scan_decode(p, cfg, lg, c, k, T, 0.0, True,
-                                        pos, dn, r, rp, rl)
-        return jax.make_jaxpr(fn)(s["params"], s["last"], s["padded"],
-                                  s["key"], s["pos"], s["done"], rows,
-                                  s["tokens"], rlens)
 
     def _paged_state(kernel):
         from repro.serving.kv_pool import PagedSpec, _ceil_div

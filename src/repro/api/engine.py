@@ -850,17 +850,17 @@ class ScopeEngine:
         refill-on and refill-off streams make identical routing decisions
         (token-derived fields bit-equal, confidences to f32 ulp).
 
-        Refill-mode latency caveat: while a slot state is live, queued
-        prompts are admitted at segment cadence via ``pop_one`` — usually
-        *sooner* than a deadline flush — but the scheduler's
-        ``max_queue_age``/``min_fill`` knobs and full-bucket emission are
-        only consulted between states, so a prompt that cannot ride the
-        live state (wider than its slots, or all slots busy) waits up to
-        the remaining refill horizon before a new bucket opens.  With
-        ``EngineConfig.kv_paged`` the horizon ceiling does not exist: the
-        slot cache is block-paged (``serving.kv_pool``), admission gates
-        on free pool pages, and a state serves requests indefinitely —
-        the wait collapses to "until a slot drains and pages free up".
+        The slot cache is block-paged (``serving.kv_pool``): admission
+        gates on free pool pages, and a state serves requests until its
+        slots drain and the queue holds nothing it can take.  While a slot
+        state is live, queued prompts are admitted at segment cadence via
+        ``pop_one`` — usually *sooner* than a deadline flush — but the
+        scheduler's ``max_queue_age``/``min_fill`` knobs and full-bucket
+        emission are only consulted between states, so a prompt wider
+        than the live state's slots waits for that state to retire.  The
+        whole-retire path (``refill=False``) serves what has no paged
+        layout — SSM, sliding-window and encoder-decoder backbones — and
+        estimators without ``open_slots``.
         """
         from repro.serving.runtime import ServeRuntime
         from repro.serving.scheduler import MicrobatchScheduler
@@ -869,11 +869,6 @@ class ScopeEngine:
             use_cache = cfg.enable_cache
         if refill is None:
             refill = cfg.refill
-        if cfg.kv_paged and not refill:
-            raise ValueError(
-                "kv_paged requires the refill serve path (the whole-retire "
-                "runtime keeps dense per-microbatch caches) — set "
-                "EngineConfig.refill=True or pass refill=True")
         sched = scheduler if scheduler is not None else MicrobatchScheduler()
         if refill:
             yield from self._predict_stream_refill(
@@ -959,60 +954,41 @@ class ScopeEngine:
         retires.  A queued prompt wider than the live state's slots waits
         for that state to retire and then opens its own.
         """
+        from repro.kernels.decode_attention import KernelType
+        from repro.serving.kv_pool import KVPool, worst_case_pool
         from repro.serving.runtime import SlotRuntime
         est = self.estimator
-        open_slots = getattr(est, "open_slots", None)
-        if open_slots is None:
+        if getattr(est, "open_slots", None) is None:
             raise TypeError(
                 "refill streaming requires an estimator with open_slots() "
                 f"(ReasoningEstimator); {type(est).__name__} lacks it — "
                 "stream with refill=False instead")
         cfg = self.config
+        kernel = {"xla": KernelType.XLA,
+                  "pallas": KernelType.PALLAS}.get(cfg.kv_kernel.lower())
+        if kernel is None:
+            raise ValueError(f"unknown kv_kernel {cfg.kv_kernel!r} "
+                             "(expected 'xla' or 'pallas')")
+        page = int(cfg.kv_page_size)
+        shared = (None if cfg.kv_pool_pages is None
+                  else KVPool(n_pages=int(cfg.kv_pool_pages), page_size=page))
 
-        def open_base(tokens, **kw):
+        def open_fn(tokens, **kw):
             # resolved per state-open, not per stream: a hot_swap between
-            # segments binds the *new* estimator's params to the next
-            # opened state, while the live state's slots finish on the old
-            # params they closed over — the swap lands at a segment
-            # boundary with exactly-once and FIFO untouched
-            return self.estimator.open_slots(tokens, **kw)
-
-        open_fn = open_base
-        if cfg.kv_paged:
-            if cfg.refill_horizon is not None:
-                raise ValueError(
-                    "kv_paged and refill_horizon are mutually exclusive: "
-                    "paged admission is gated on free pool pages, not a "
-                    "slot horizon")
-            from repro.kernels.decode_attention import KernelType
-            from repro.serving.kv_pool import KVPool
-            kernel = {"xla": KernelType.XLA,
-                      "pallas": KernelType.PALLAS}.get(cfg.kv_kernel.lower())
-            if kernel is None:
-                raise ValueError(f"unknown kv_kernel {cfg.kv_kernel!r} "
-                                 "(expected 'xla' or 'pallas')")
-            page = int(cfg.kv_page_size)
-            shared = (None if cfg.kv_pool_pages is None
-                      else KVPool(n_pages=int(cfg.kv_pool_pages),
-                                  page_size=page))
-
-            def open_fn(tokens, **kw):
-                if shared is not None:
-                    pool = shared
-                else:
-                    # auto-size: the opening bucket's dense worst case —
-                    # paged still wins whenever rows finish early or the
-                    # run outlives one horizon.  Budget read per open so a
-                    # hot-swapped estimator sizes its own pools.
-                    budget = int(getattr(self.estimator, "max_new_tokens",
-                                         0) or 0)
-                    budget_steps = -(-budget // segment_len) * segment_len
-                    b, width = np.asarray(tokens).shape
-                    pool = KVPool(
-                        n_pages=b * (-(-(width + budget_steps) // page)),
-                        page_size=page)
-                return open_base(tokens, kv_pool=pool, kv_kernel=kernel,
-                                 **kw)
+            # segments binds the *new* estimator's params (and its budget,
+            # which sizes its own pools) to the next opened state, while
+            # the live state's slots finish on the old params they closed
+            # over — the swap lands at a segment boundary with
+            # exactly-once and FIFO untouched
+            pool = shared
+            if pool is None:
+                budget = int(getattr(self.estimator, "max_new_tokens",
+                                     0) or 0)
+                b, width = np.asarray(tokens).shape
+                pool = worst_case_pool(
+                    b, width, -(-budget // segment_len) * segment_len, page)
+            return self.estimator.open_slots(tokens, kv_pool=pool,
+                                             kv_kernel=kernel, **kw)
 
         pending: Deque[_StreamEntry] = deque()
         inflight: Dict[Tuple, List[Tuple[_StreamEntry, int]]] = {}
@@ -1023,8 +999,7 @@ class ScopeEngine:
             fill(tags, control.corrupt(batch))
 
         runtime = SlotRuntime(open_fn, sched, segment_len=segment_len,
-                              on_parsed=on_parsed,
-                              horizon=cfg.refill_horizon, rng=rng,
+                              on_parsed=on_parsed, rng=rng,
                               injector=control.injector,
                               on_failed=control.on_failed)
         serial = 0

@@ -50,17 +50,14 @@ class EngineConfig:
     enable_cache: bool = True
     cache_capacity: Optional[int] = None
     # streaming serve runtime (predict_stream / serve_stream)
-    refill: bool = False            # segment-chunked mid-batch slot refill
+    refill: bool = False            # paged continuous batching: slots
+    #                                 refilled mid-batch between segments
     segment_len: int = 4            # decode steps per scan segment (refill)
-    refill_horizon: Optional[int] = None    # decode-slot capacity in steps
-    #                                         (None = 4x max_new_tokens)
     max_pending: Optional[int] = None       # in-flight microbatches in the
     #                                         ServeRuntime pipeline (None =
     #                                         1 if overlap else 0)
-    # paged KV cache (refill path only): block-paged decode-cache pool
-    # instead of the dense per-slot horizon — KV memory scales with live
-    # tokens, admission gates on free pages
-    kv_paged: bool = False
+    # paged KV cache of the refill path: a block-paged decode-cache pool
+    # — KV memory scales with live tokens, admission gates on free pages
     kv_page_size: int = 16          # token positions per KV page
     kv_pool_pages: Optional[int] = None     # pool size in pages (None =
     #                                         auto-size to the opening

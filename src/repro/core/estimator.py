@@ -22,7 +22,9 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.status import STATUS_DEGRADED, STATUS_FAILED, STATUS_OK
 from repro.data import tokenizer as tok
+from repro.kernels.decode_attention import KernelType
 from repro.serving import sampler
+from repro.serving.kv_pool import worst_case_pool
 
 
 @dataclasses.dataclass
@@ -269,22 +271,26 @@ class _Slot:
 class SlotRun:
     """One live continuous-batching decode state (the refill serve path).
 
-    Wraps a ``sampler.DecodeState`` over a fixed (b, L) bucket and drives
-    it in ``segment_len``-step scan segments: after each segment, rows that
-    drained at EOS (or exhausted the per-request ``max_new_tokens`` budget)
-    are parsed from their own window of the accumulated decode buffer and
-    their slot freed; ``admit`` prefills freshly popped prompts into the
-    free slots — one batched prefill per boundary, fused into the next
-    segment's launch and computed on the smallest row bucket (b/4, b/2 or
-    b rows) that holds the admitted prompts, however many slots drain
-    together.  A paged state opens with nothing prefilled: its opening
-    rows are admitted the same way, by its first launch.  The slot
-    cache is allocated ``horizon`` decode steps deep (default 4x the
-    budget, rounded up to whole segments) so a slot serves several requests
-    back-to-back before the state retires; ``can_admit`` turns False once
-    the remaining horizon cannot fit a full budget — a request is never
-    admitted into a window it could not finish, so every admitted request
-    decodes exactly the window a standalone run would.
+    Wraps a paged ``sampler.DecodeState`` over a fixed (b, L) bucket and
+    drives it in ``segment_len``-step scan segments: after each segment,
+    rows that drained at EOS (or exhausted the per-request
+    ``max_new_tokens`` budget) are parsed from their own window of the
+    accumulated decode buffer, and their slot and pages freed; ``admit``
+    prefills freshly popped prompts into the free slots — one batched
+    prefill per boundary, fused into the next segment's launch and
+    computed on the smallest row bucket (b/4, b/2 or b rows) that holds
+    the admitted prompts, however many slots drain together.  The state
+    opens with nothing prefilled: its opening rows are admitted the same
+    way, by its first launch.
+
+    The KV cache is block-paged (``serving.kv_pool``): ``kv_pool`` is the
+    page pool (None = ``worst_case_pool`` for this batch, at the default
+    page size) and ``kv_kernel`` the paged attention kernel (None = XLA).
+    Admission is gated on free pages only — each admitted row reserves its
+    worst case, so a request is never admitted into a window it could not
+    finish and every admitted request decodes exactly the window a
+    standalone run would.  ``horizon`` must be None: a slot state has no
+    dense horizon.
     """
 
     def __init__(self, estimator: "ReasoningEstimator", tokens, *,
@@ -292,6 +298,10 @@ class SlotRun:
                  horizon: Optional[int] = None,
                  rng: Optional[jax.Array] = None,
                  kv_pool=None, kv_kernel=None):
+        if horizon is not None:
+            raise ValueError(
+                f"horizon={horizon!r}: a slot state is paged and admission "
+                "is gated on free pages; pass horizon=None")
         tokens = np.asarray(tokens, np.int32)
         if tokens.ndim != 2:
             raise ValueError(f"tokens must be (b, L), got {tokens.shape}")
@@ -311,45 +321,24 @@ class SlotRun:
         # the host decode buffers grow by
         self.budget_steps = -(-self.budget // self.segment_len) \
             * self.segment_len
-        self.kv_pool = kv_pool
         if kv_pool is None:
-            horizon = int(horizon) if horizon else 4 * self.budget
-            horizon = max(horizon, self.budget)
-            # whole segments only: a window admitted while can_admit()
-            # holds always completes by the horizon boundary
-            self.horizon = -(-horizon // self.segment_len) \
-                * self.segment_len
-            buf = self.horizon
-        else:
-            # paged mode has no shared horizon: admission is gated on free
-            # pages and the host buffers grow per segment instead
-            self.horizon = None
-            buf = self.budget_steps
+            kv_pool = worst_case_pool(b, L, self.budget_steps)
+        self.kv_pool = kv_pool
         tags = list(tags) if tags is not None else list(range(b))
         if len(tags) > b:
             raise ValueError(f"{len(tags)} tags for {b} slots")
-        lens = None if lengths is None else np.asarray(lengths, int)
-        # per-row true lengths only when genuinely ragged: exact-fit
-        # buckets stay on the unmasked path (SSM backbones included)
-        pl = lens if lens is not None and (lens != L).any() else None
-        placed = estimator._place_batch(tokens)
-        if kv_pool is None:
-            self.state = sampler.prefill_state(
-                estimator.params, estimator.cfg, placed,
-                max_new_tokens=self.horizon, prompt_lens=pl, rng=rng)
-        else:
-            from repro.kernels.decode_attention import KernelType
-            self.state = sampler.open_state(
-                estimator.params, estimator.cfg, placed,
-                max_new_tokens=self.budget_steps, kv_pool=kv_pool,
-                kv_kernel=kv_kernel or KernelType.XLA, rng=rng)
-            # refills prefill row buckets: run each once per shape, before
-            # any request waits on one
-            sampler.warm_row_buckets(estimator.params, estimator.cfg,
-                                     self.state, L, self.segment_len)
+        self.state = sampler.open_state(
+            estimator.params, estimator.cfg, estimator._place_batch(tokens),
+            max_new_tokens=self.budget_steps, kv_pool=kv_pool,
+            kv_kernel=kv_kernel or KernelType.XLA, rng=rng)
+        # refills prefill row buckets: run each once per shape, before any
+        # request waits on one
+        sampler.warm_row_buckets(estimator.params, estimator.cfg,
+                                 self.state, L, self.segment_len)
         # rows past the real tags are free slots from the start (a
         # partially-filled opening bucket refills instead of padding)
-        true_lens = lens if lens is not None else np.full(b, L, int)
+        true_lens = (np.asarray(lengths, int) if lengths is not None
+                     else np.full(b, L, int))
         self.slots: List[Optional[_Slot]] = [
             _Slot(tags[i], 0, False,
                   prompt=tokens[i, : int(true_lens[i])].tolist())
@@ -358,17 +347,16 @@ class SlotRun:
         self.steps_run = 0                  # decode steps *launched*
         self.steps_done = 0                 # decode steps synced to host
         # host copy of the decode buffer, written once per segment
-        self._gen = np.full((b, buf), -1, np.int32)
-        self._dec = np.zeros((b, buf, 2), np.float32)
+        self._gen = np.full((b, self.budget_steps), -1, np.int32)
+        self._dec = np.zeros((b, self.budget_steps, 2), np.float32)
         # slot-aligned refills admitted since the last launch; fused into
-        # the next ``decode_segment(refill=...)`` executable.  A paged
-        # state opens with nothing prefilled: its opening rows ride the
-        # first launch's refill, in the row bucket that holds them.
+        # the next ``decode_segment(refill=...)`` executable.  The opening
+        # rows ride the first launch's refill, in the row bucket that
+        # holds them.
         self._pending: Optional[tuple] = None
-        if self.paged:
-            for i in range(len(tags)):
-                self._stage(i, tokens[i, : int(true_lens[i])],
-                            int(true_lens[i]))
+        for i in range(len(tags)):
+            self._stage(i, tokens[i, : int(true_lens[i])],
+                        int(true_lens[i]))
         # (gen, dec, launch counters) futures
         self._inflight: Optional[tuple] = None
         # decode-slot accounting (token granularity; folded into
@@ -376,12 +364,10 @@ class SlotRun:
         self.slot_steps_total = 0
         self.slot_steps_active = 0
         self.refill_steps = 0               # active steps on refilled rows
-        # rows the prefills computed (the opening prefill's, then those of
-        # every launch that carries a refill), and launches per row count
+        # rows the prefills of the refill-bearing launches computed, and
+        # launches per row count
         self.prefill_rows = 0
         self.prefill_launches_by_rows: Counter = Counter()
-        if self.state.prefill_rows:
-            self._count_prefill()
         self._folded = (0, 0, 0, 0)
         self._folded_launches: Counter = Counter()
         # tokens each expert layer routed to each held expert, summed over
@@ -408,28 +394,12 @@ class SlotRun:
     def free_rows(self) -> List[int]:
         return [i for i, s in enumerate(self.slots) if s is None]
 
-    @property
-    def paged(self) -> bool:
-        return self.kv_pool is not None
-
     def can_admit(self) -> bool:
-        """Whether one more request may be admitted into a free slot.
-
-        Dense mode gates on the remaining horizon fitting a full budget;
-        paged mode gates on the pool having a worst-case row's pages free
-        — the ``refill_horizon`` ceiling does not exist there, so a
-        queued prompt drains as soon as pages free up, however long the
-        run has already decoded.
+        """Whether one more request may be admitted into a free slot: the
+        pool has a worst-case row's pages free.  A queued prompt drains as
+        soon as pages free up, however long the run has already decoded.
         """
-        if self.paged:
-            return self.state.paged.can_admit(self.width)
-        return self.steps_run + self.budget <= self.horizon
-
-    @property
-    def deferral_reason(self) -> str:
-        """Which resource a ``can_admit() == False`` boundary waits on
-        (the stats counter the serve runtime bumps)."""
-        return "pages" if self.paged else "horizon"
+        return self.state.paged.can_admit(self.width)
 
     def admit(self, items: Sequence[tuple]) -> None:
         """Refill free slots with ``items`` = [(tag, prompt, length)].
@@ -454,8 +424,7 @@ class SlotRun:
             if not self.can_admit():
                 raise ValueError(
                     "cannot admit: the kv pool has no room for a "
-                    "worst-case row" if self.paged else
-                    "remaining horizon cannot fit a full decode budget")
+                    "worst-case row")
             p = np.asarray(prompt, np.int32).reshape(-1)
             if not 1 <= len(p) <= self.width:
                 raise ValueError(
@@ -477,10 +446,9 @@ class SlotRun:
         mat[row] = tok.PAD
         mat[row, : len(prompt)] = prompt
         lens[row] = length
-        if self.paged:
-            # reserve the row's pages NOW so the next can_admit() check
-            # sees the pool as the coming launch will leave it
-            self.state.paged.pre_admit(row, length)
+        # reserve the row's pages NOW so the next can_admit() check sees
+        # the pool as the coming launch will leave it
+        self.state.paged.pre_admit(row, length)
 
     # -- failure surface (serve-runtime fault tolerance) ---------------
     @property
@@ -499,9 +467,7 @@ class SlotRun:
 
     def starved_rows(self) -> List[int]:
         """Live rows the next segment's page allocation would starve
-        (paged mode; always empty within reserved budgets)."""
-        if not self.paged:
-            return []
+        (always empty within reserved budgets)."""
         return self.state.paged.starved_rows(self.segment_len)
 
     def fail_row(self, row: int) -> Optional[tuple]:
@@ -513,8 +479,7 @@ class SlotRun:
         if slot is None:
             return None
         self.slots[row] = None
-        if self.paged:
-            self.state.paged.retire_row(row)
+        self.state.paged.retire_row(row)
         return (slot.tag, slot.prompt)
 
     def abort(self) -> List[tuple]:
@@ -538,10 +503,6 @@ class SlotRun:
         host work with device decode."""
         if self._inflight is not None:
             raise RuntimeError("a segment is already in flight")
-        if not self.paged and \
-                self.steps_run + self.segment_len > self.horizon:
-            raise RuntimeError(
-                f"segment overruns the {self.horizon}-step slot horizon")
         self.state, g, d = sampler.decode_segment(
             self.est.params, self.est.cfg, self.state, self.segment_len,
             refill=self._pending)
@@ -567,8 +528,8 @@ class SlotRun:
         self._inflight = None
         t0, t1 = self.steps_done, self.steps_done + self.segment_len
         if t1 > self._gen.shape[1]:
-            # paged runs have no horizon, so the host buffers grow in
-            # budget-sized chunks as the run outlives its initial window
+            # the host buffers grow in budget-sized chunks as the run
+            # outlives its initial window
             grow = -(-(t1 - self._gen.shape[1]) // self.budget_steps) \
                 * self.budget_steps
             self._gen = np.concatenate(
@@ -593,11 +554,10 @@ class SlotRun:
             if bool(done[row]) or t1 - slot.start >= self.budget:
                 completed.append((row, slot))
                 self.slots[row] = None
-                if self.paged:
-                    # hand the row's pages back the moment it drains —
-                    # its table entries fall back to the trash page, so
-                    # the still-running PAD decode scatters harmlessly
-                    self.state.paged.retire_row(row)
+                # hand the row's pages back the moment it drains — its
+                # table entries fall back to the trash page, so the
+                # still-running PAD decode scatters harmlessly
+                self.state.paged.retire_row(row)
         return completed
 
     def parse_completed(self, completed: List[tuple]):
@@ -649,19 +609,12 @@ class SlotRun:
         """Fold what is left of this run's counters into
         ``SchedulerStats`` when it retires, and its KV footprint."""
         self.fold(stats)
-        if self.paged:
-            pool = self.kv_pool
-            stats.kv_page_size = pool.page_size
-            stats.pages_in_use = pool.pages_in_use
-            stats.pages_peak = max(stats.pages_peak, pool.pages_peak)
-            stats.kv_live_tokens = pool.live_tokens
-            stats.kv_peak_tokens = max(stats.kv_peak_tokens,
-                                       pool.tokens_peak)
-        else:
-            # dense KV is committed wholesale at prefill: every slot holds
-            # max_len token positions for the whole run
-            stats.kv_peak_tokens = max(
-                stats.kv_peak_tokens, self.batch * self.state.max_len)
+        pool = self.kv_pool
+        stats.kv_page_size = pool.page_size
+        stats.pages_in_use = pool.pages_in_use
+        stats.pages_peak = max(stats.pages_peak, pool.pages_peak)
+        stats.kv_live_tokens = pool.live_tokens
+        stats.kv_peak_tokens = max(stats.kv_peak_tokens, pool.tokens_peak)
 
 
 class ReasoningEstimator:
@@ -738,8 +691,7 @@ class ReasoningEstimator:
         return DecodeHandle(chunks)
 
     def open_slots(self, tokens, *, lengths=None, tags=None,
-                   segment_len: int = 4, horizon: Optional[int] = None,
-                   rng: Optional[jax.Array] = None,
+                   segment_len: int = 4, rng: Optional[jax.Array] = None,
                    kv_pool=None, kv_kernel=None) -> SlotRun:
         """Open a continuous-batching decode state over one microbatch.
 
@@ -747,16 +699,13 @@ class ReasoningEstimator:
         ``SlotRun``: ``step`` decode segments, ``admit`` fresh prompts into
         drained slots between them.  ``tokens``/``lengths``/``tags`` are a
         scheduler ``Microbatch``'s fields; rows beyond the real tags are
-        immediately-free slots.  Passing a ``kv_pool`` (``serving.kv_pool.
-        KVPool``) switches the slot cache to the block-paged layout —
-        ``horizon`` must then stay None (admission is page-gated).
+        immediately-free slots.  The slot cache is block-paged over
+        ``kv_pool`` (``serving.kv_pool.KVPool``; None = a pool holding
+        every slot's worst case at once, ``worst_case_pool``).
         """
-        if kv_pool is not None and horizon is not None:
-            raise ValueError("horizon and kv_pool are mutually exclusive: "
-                             "paged admission is gated on free pages")
         return SlotRun(self, tokens, lengths=lengths, tags=tags,
-                       segment_len=segment_len, horizon=horizon, rng=rng,
-                       kv_pool=kv_pool, kv_kernel=kv_kernel)
+                       segment_len=segment_len, rng=rng, kv_pool=kv_pool,
+                       kv_kernel=kv_kernel)
 
     def predict_batch(self, prompts: List[List[int]], *,
                       prompt_lens=None, temperature: float = 0.0,

@@ -14,7 +14,7 @@ retraining — and serves a batch of queries under a chosen routing policy.
   PYTHONPATH=src python -m repro.launch.serve --stream-ticks 12 \
       --refill --segment-len 4
   PYTHONPATH=src python -m repro.launch.serve --stream-ticks 12 \
-      --refill --kv-paged --kv-page-size 16
+      --refill --kv-page-size 16
   PYTHONPATH=src python -m repro.launch.serve --stream-ticks 12 \
       --max-pending 2
   PYTHONPATH=src python -m repro.launch.serve --stream-ticks 12 \
@@ -90,27 +90,24 @@ def main(argv=None):
                          "overlap, 0 without; 2 interleaves prefill of "
                          "N+1 with decode of N on real accelerators)")
     ap.add_argument("--refill", action="store_true",
-                    help="segment-chunked continuous batching: refill "
+                    help="paged continuous batching: refill "
                          "drained-at-EOS decode slots from the queue "
                          "between scan segments instead of retiring "
-                         "microbatches whole")
+                         "microbatches whole; the decode KV cache is a "
+                         "block-paged pool and admission gates on free "
+                         "pages")
     ap.add_argument("--segment-len", type=int, default=None,
                     help="decode steps per scan segment in --refill mode "
                          "(default 4; drained slots admit new prompts at "
                          "segment boundaries)")
-    ap.add_argument("--kv-paged", action="store_true",
-                    help="block-paged decode KV cache (--refill only): "
-                         "pool-backed pages instead of a dense per-slot "
-                         "horizon — KV memory scales with live tokens and "
-                         "admission gates on free pages")
     ap.add_argument("--kv-page-size", type=int, default=16,
-                    help="token positions per KV page in --kv-paged mode "
+                    help="token positions per KV page in --refill mode "
                          "(default 16; smaller pages = less last-page "
                          "waste, bigger page tables)")
     ap.add_argument("--kv-pool-pages", type=int, default=None,
-                    help="KV pool size in pages in --kv-paged mode "
+                    help="KV pool size in pages in --refill mode "
                          "(default: auto-size each slot state to its "
-                         "bucket's dense worst case)")
+                         "bucket's worst case)")
     ap.add_argument("--mesh", action="store_true",
                     help="shard the estimator over the local serve mesh "
                          "(multiply CPU devices with XLA_FLAGS="
@@ -175,9 +172,6 @@ def main(argv=None):
                                seed=args.seed)
         params, _ = train_sft(params, cfg, ds, steps=250, batch_size=64)
 
-    if args.kv_paged and not args.refill:
-        ap.error("--kv-paged requires --refill (the whole-retire runtime "
-                 "keeps dense per-microbatch caches)")
     if args.kv_page_size < 1:
         ap.error(f"--kv-page-size must be >= 1, got {args.kv_page_size}")
 
@@ -209,8 +203,7 @@ def main(argv=None):
     engine = ScopeEngine.build(EngineConfig(
         estimator=estimator, retriever=retr,
         library=lib, models_meta={m: world.models[m] for m in data.models},
-        kv_paged=args.kv_paged, kv_page_size=args.kv_page_size,
-        kv_pool_pages=args.kv_pool_pages,
+        kv_page_size=args.kv_page_size, kv_pool_pages=args.kv_pool_pages,
         max_retries=args.max_retries, deadline_ms=args.deadline_ms,
         degrade=not args.no_degrade, fault_plan=fault_plan,
         tier0=tier0_head,
@@ -218,7 +211,7 @@ def main(argv=None):
         drift_detect=args.drift_detect,
         drift_threshold=args.drift_threshold))
 
-    if args.kv_paged and args.kv_pool_pages is not None:
+    if args.refill and args.kv_pool_pages is not None:
         # a request admitted at a boundary may decode its whole budget:
         # a pool that cannot page even a minimal such row can never admit
         seg = args.segment_len or 4

@@ -1,15 +1,14 @@
 """Block-paged KV-cache pool: fixed-size pages, per-row page tables,
-free-list allocation (no page sharing in v1).
+free-list allocation (no page sharing in v1).  It is the one layout a
+continuous-batching decode state has.
 
-Dense decode allocates every slot's worst case up front — KV memory is
-O(slots x horizon) even when most rows drain at EOS after a handful of
-tokens.  The pool converts that to O(live tokens): KV storage is a flat
-array of ``n_pages`` fixed-size pages plus one **trash page**, and each
-decode row owns a page table mapping its logical page index to a physical
-page.  Pages are allocated on demand as positions advance (prompt pages at
-admission, decode pages per segment) and released when the row retires at
-EOS/parse, so a drained slot's memory is immediately reusable by the next
-queued prompt — slot admission checks free pages, not remaining horizon.
+KV storage is a flat array of ``n_pages`` fixed-size pages plus one
+**trash page**, and each decode row owns a page table mapping its logical
+page index to a physical page.  Pages are allocated on demand as
+positions advance (prompt pages at admission, decode pages per segment)
+and released when the row retires at EOS/parse, so a drained slot's
+memory is immediately reusable by the next queued prompt — slot admission
+checks free pages.  ``worst_case_pool`` sizes a pool for a slot batch.
 
 Layout per attention layer-stack cache leaf:
 
@@ -198,6 +197,16 @@ class KVPool:
                kernel: KernelType = KernelType.XLA) -> "PagedKV":
         return PagedKV(self, batch, kv_cap=kv_cap,
                        budget_steps=budget_steps, kernel=kernel)
+
+
+def worst_case_pool(batch: int, width: int, budget_steps: int,
+                    page_size: int = 16) -> KVPool:
+    """A pool that holds every one of ``batch`` slots at its worst case at
+    once — a ``width``-token prompt plus ``budget_steps`` decode steps,
+    page-rounded — so admission never waits on pages while a slot is
+    free."""
+    return KVPool(n_pages=batch * _ceil_div(width + budget_steps, page_size),
+                  page_size=page_size)
 
 
 @dataclasses.dataclass

@@ -26,7 +26,7 @@ handles) runs overlapped; one returning a finished ``ParsedBatch`` directly
 (duck-typed test estimators) degrades to the synchronous path.
 
 ``SlotRuntime`` is the segment-chunked counterpart: instead of retiring
-microbatches whole, it drives a live decode-slot state
+microbatches whole, it drives a live paged decode-slot state
 (``ReasoningEstimator.open_slots`` -> ``SlotRun``) in fixed scan segments
 and refills drained-at-EOS slots mid-batch from the scheduler queue.
 """
@@ -183,8 +183,9 @@ class SlotRuntime:
 
     The refill counterpart of ``ServeRuntime``: one live slot state at a
     time (device work is serialized on one executable anyway).  Whole
-    scheduler microbatches *open* a state via ``open_slots``; between scan
-    segments, rows that drained at EOS (or exhausted their budget) hand
+    scheduler microbatches *open* a state via ``open_slots``, which
+    supplies its page pool (the engine's open function sizes it); between
+    scan segments, rows that drained at EOS (or exhausted their budget) hand
     their parse group to ``on_parsed`` and their slot admits the oldest
     queued prompt (``scheduler.pop_one``) — a row that finishes early
     serves the next request instead of idling until the batch retires.
@@ -206,19 +207,14 @@ class SlotRuntime:
 
     def __init__(self, open_slots: Callable[..., Any], scheduler, *,
                  segment_len: int, on_parsed: Callable[[list, Any], None],
-                 horizon: Optional[int] = None, rng: Any = None,
-                 kv_pool: Any = None, kv_kernel: Any = None,
-                 injector: Any = None,
+                 rng: Any = None, injector: Any = None,
                  on_failed: Optional[Callable[[list, Optional[Exception]],
                                               None]] = None):
         self._open_slots = open_slots
         self._sched = scheduler
         self._segment_len = int(segment_len)
         self._on_parsed = on_parsed
-        self._horizon = horizon
         self._rng = rng
-        self._kv_pool = kv_pool
-        self._kv_kernel = kv_kernel
         self._injector = injector
         self._on_failed = on_failed
         self._open_queue: Deque[Microbatch] = deque()
@@ -232,22 +228,17 @@ class SlotRuntime:
     def _admit(self, run) -> None:
         """Pop queued prompts into the run's free slots (as many as fit).
 
-        ``can_admit`` is re-checked per item — each paged admission draws
-        down the pool, so the first one can succeed and the next defer.  A
+        ``can_admit`` is re-checked per item — each admission draws down
+        the page pool, so the first one can succeed and the next defer.  A
         boundary that leaves a free slot idle while the queue holds work is
         *counted*, not silently swallowed: the deferral shows up in
-        ``SchedulerStats`` under the resource it waited on (pool pages in
-        paged mode, the slot horizon in dense mode).
+        ``SchedulerStats.admissions_deferred_on_pages``.
         """
         items = []
         for _ in run.free_rows():
             if not run.can_admit():
                 if self._sched.peek_one(run.width):
-                    stats = self._sched.stats
-                    if run.deferral_reason == "pages":
-                        stats.admissions_deferred_on_pages += 1
-                    else:
-                        stats.admissions_deferred_on_horizon += 1
+                    self._sched.stats.admissions_deferred_on_pages += 1
                 break
             item = self._sched.pop_one(run.width)
             if item is None:
@@ -280,7 +271,7 @@ class SlotRuntime:
         if inj is not None:
             inj.tick("stall")
             spec = inj.tick("pool")
-            if spec is not None and run.paged:
+            if spec is not None:
                 self._fail_row(run, run.pick_live_row(int(spec.arg)))
             spec = inj.tick("segment")
             if spec is not None:
@@ -320,15 +311,10 @@ class SlotRuntime:
                 if not self._open_queue:
                     return
                 mb = self._open_queue.popleft()
-                kw = {}
-                if self._kv_pool is not None:
-                    kw = {"kv_pool": self._kv_pool,
-                          "kv_kernel": self._kv_kernel}
                 with TraceAnnotation("scope.open"):
                     self._run = self._open_slots(
                         mb.tokens, lengths=mb.lengths, tags=mb.tags,
-                        segment_len=self._segment_len, horizon=self._horizon,
-                        rng=self._rng, **kw)
+                        segment_len=self._segment_len, rng=self._rng)
                 # a partially-filled opening bucket's pad rows are free
                 # slots: refill them before the first segment launches
                 with TraceAnnotation("scope.admit"):

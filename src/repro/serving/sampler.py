@@ -2,23 +2,24 @@
 
 Decode is organised around an explicit ``DecodeState`` (caches, per-row
 positions, done-mask, carried sampling key) so the serve runtime can run
-decode in **chunked scan segments** and refill a drained-at-EOS slot with a
-freshly prefilled prompt between segments (continuous batching) instead of
-idling the slot until the batch finishes:
+decode in **chunked scan segments**.  Two layouts back it:
 
-  state = prefill_state(params, cfg, prompts, max_new_tokens=12)
-  state, gen, dec = decode_segment(params, cfg, state, 4)
-  state = refill_slot(params, cfg, state, row=2, prompt=new_prompt)
+* dense (``prefill_state``): one batch prefilled at once and decoded to
+  the end — the one-shot path (``generate``, whole-retire microbatches);
+* paged (``open_state`` over a ``kv_pool.KVPool``): continuous batching.
+  A drained-at-EOS slot is refilled with a fresh prompt between segments
+  instead of idling until the batch finishes, and the refill rides the
+  next segment's launch:
+
+  state = open_state(params, cfg, slots, max_new_tokens=12, kv_pool=pool)
+  state, gen, dec = decode_segment(params, cfg, state, 4,
+                                   refill=(mask, prompts, lens))
   state, gen2, dec2 = decode_segment(params, cfg, state, 4)
 
-``refill_slots`` is the batched form: every slot drained at one segment
-boundary refills with a single prefill call, padded to a warmed (p, L)
-executable shape with true per-prompt lengths.  The serve runtime fuses
-that prefill into the next segment (``decode_segment(refill=...)``),
-which prefills the admitted rows only, compacted into the smallest row
-bucket (b/4, b/2 or b rows) that holds them; a paged state opens with
-nothing prefilled (``open_state``), so its opening rows ride its first
-launch the same way.
+A fused refill prefills the admitted rows only, compacted into the
+smallest row bucket (b/4, b/2 or b rows) that holds them; a state opens
+with nothing prefilled, so its opening rows ride its first launch the same
+way.
 
 Positions are **per row**: rows at different sequence offsets (ragged
 prompt lengths under a bucket grid, refilled slots mid-decode) share one
@@ -53,7 +54,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 from collections import Counter
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -95,10 +96,6 @@ def _prefill(params, cfg: ModelConfig, tokens, lens):
 # decode-time sequence axis and must never be grown, whatever its shape.
 CACHE_SEQ_AXIS = {"k": 3, "v": 3, "c_kv": 2, "k_rope": 2}
 
-# every decode-cache leaf carries batch on axis 1 (behind the layer stack);
-# ``refill_slot`` relies on this to scatter one prefilled row into place
-CACHE_BATCH_AXIS = 1
-
 
 def _leaf_name(path) -> str:
     entry = path[-1]
@@ -134,8 +131,8 @@ def _pad_caches(caches, max_len: int, prompt_len: int):
 def _run_scan(params, cfg: ModelConfig, last_logits, caches, key,
               steps: int, temperature: float, stop_at_eos: bool,
               positions, done, paged=None):
-    """Traced scan body shared by ``_scan_decode`` / ``_refill_scan_decode``
-    and their paged twins: sample -> emit (token, YES/NO) -> step, for
+    """Traced scan body shared by ``_scan_decode`` and the paged
+    executables: sample -> emit (token, YES/NO) -> step, for
     ``steps`` steps.  ``paged`` = (PagedSpec, page table) reroutes the KV
     writes/reads through the block-paged layout; the sampling math is
     byte-for-byte the same code path.  Returns (gen, dec, last, caches,
@@ -176,9 +173,9 @@ def _run_scan(params, cfg: ModelConfig, last_logits, caches, key,
     return gen.T, dec.transpose(1, 0, 2), last, kv, done, key, stats
 
 
-# the dense executables keep their caches undonated: a dense state may be
-# decoded more than once (the tests branch segments from one state), and no
-# serve path runs them.  The paged twins below donate theirs.
+# the dense executable keeps its caches undonated: a dense state may be
+# decoded more than once (the tests branch segments from one state).  The
+# paged executables below donate theirs.
 @functools.partial(jax.jit, static_argnums=(1, 5, 6, 7))
 def _scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
                  steps: int, temperature: float, stop_at_eos: bool,
@@ -194,36 +191,6 @@ def _scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
     COMPILE_COUNTS["scan_decode"] += 1      # traced once per compilation
     return _run_scan(params, cfg, last_logits, caches, key, steps,
                      temperature, stop_at_eos, positions, done)
-
-
-def _grow_to(path, leaf, ref):
-    """Pad a prefill cache leaf's seq axis up to ``ref``'s (traced-safe)."""
-    ax = CACHE_SEQ_AXIS.get(_leaf_name(path))
-    if ax is None:
-        return leaf
-    widths = [(0, 0)] * leaf.ndim
-    widths[ax] = (0, ref.shape[ax] - leaf.shape[ax])
-    return jnp.pad(leaf, widths)
-
-
-def _check_refill_lens(cfg: ModelConfig, state: "DecodeState", width: int,
-                       lens: np.ndarray) -> None:
-    """Shared refill-prompt guards (fused and unfused paths must accept
-    exactly the same inputs): true lengths in [1, width], attention-only
-    backbones when padded, and decode room left in the slot cache."""
-    if lens.min() < 1 or lens.max() > width:
-        raise ValueError(
-            f"prompt_lens must lie in [1, {width}], got "
-            f"[{lens.min()}, {lens.max()}]")
-    if lens.min() < width and cfg.has_ssm():
-        raise ValueError(
-            "padded refill requires an attention-only backbone: "
-            f"{cfg.name!r} has SSM/conv layers whose prefill state consumes "
-            "right-pad tokens — refill at the exact prompt length instead")
-    if lens.max() >= state.max_len or width > state.max_len:
-        raise ValueError(
-            f"refill prompt of {lens.max()} tokens (padded to {width}) "
-            f"leaves no decode room in a {state.max_len}-slot cache")
 
 
 # ---------------------------------------------------------------------------
@@ -258,7 +225,7 @@ def _row_shards(x) -> int:
 
 def _admit_rows(params, cfg: ModelConfig, rows, prompts, lens,
                 last_logits, positions, done):
-    """Traced refill prefill shared by the fused executables: prefill the
+    """Traced refill prefill of the fused refill executable: prefill the
     bucketed prompts (R, W) at their true lengths, and reset the slots
     ``rows`` (R,) to them — last logits, position, not done; filler
     entries (== b) are dropped.  Returns the prompts' prompt-sized caches
@@ -278,44 +245,8 @@ def _admit_rows(params, cfg: ModelConfig, rows, prompts, lens,
     return new, last_logits, positions, done, stats
 
 
-@functools.partial(jax.jit, static_argnums=(1, 5, 6, 7))
-def _refill_scan_decode(params, cfg: ModelConfig, last_logits, caches, key,
-                        steps: int, temperature: float, stop_at_eos: bool,
-                        positions, done, refill_rows, refill_prompts,
-                        refill_lens):
-    """``_scan_decode`` with slot refill fused into the same executable.
-
-    ``refill_prompts`` (R, W) are the admitted prompts compacted into a
-    row bucket and ``refill_rows`` (R,) their slot rows (``bucket_rows``;
-    filler entries equal b); ``refill_lens`` (R,) gives each prompt's
-    true length.  The prompts are prefilled, their caches grown to decode
-    capacity and written to their slots, and those slots' position /
-    done / last-logits reset — then the segment scan runs over all b
-    slots.  One executable launch admits every slot drained at a boundary
-    *and* decodes the next segment; the per-row math is identical to a
-    separate ``refill_slots`` + ``_scan_decode`` pair (asserted
-    bit-exactly in the tests), the fusion only removes per-boundary
-    launch overhead.
-    """
-    COMPILE_COUNTS["refill_scan_decode"] += 1   # traced once per compile
-
-    def merge(old, new):
-        return old.at[:, refill_rows].set(new.astype(old.dtype), mode="drop")
-
-    with jax.named_scope("prefill"):
-        new_caches, last_logits, positions, done, admitted = _admit_rows(
-            params, cfg, refill_rows, refill_prompts, refill_lens,
-            last_logits, positions, done)
-        new_caches = jax.tree_util.tree_map_with_path(_grow_to, new_caches,
-                                                      caches)
-        caches = jax.tree.map(merge, caches, new_caches)
-    out = _run_scan(params, cfg, last_logits, caches, key, steps,
-                    temperature, stop_at_eos, positions, done)
-    return out[:-1] + (positions, {**out[-1], **admitted})
-
-
 # ---------------------------------------------------------------------------
-# Paged twins: prefill-scatter + decode over the block-paged KV layout
+# Paged executables: prefill-scatter + decode over the block-paged KV layout
 # ---------------------------------------------------------------------------
 def _layers(count: int) -> jax.Array:
     """(count, 1) layer indices for a page scatter into stacked storage:
@@ -431,33 +362,6 @@ def _paged_open(params, cfg: ModelConfig, batch: int, n_pages_total: int,
                                                      page_size), caches))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 3))
-def _paged_refill_prefill(params, cfg: ModelConfig, tokens, page_size: int,
-                          page_ids, caches, lens):
-    """Prefill + scatter into **existing** paged storage (unfused refill).
-
-    Refilled rows' destinations are freshly allocated pages and everything
-    else targets trash, so live rows' pages are untouched — the paged
-    analogue of the dense per-row cache merge.
-    """
-    COMPILE_COUNTS["paged_refill_prefill"] += 1
-    flat_cache = {}
-
-    def name_leaf(path, leaf):
-        flat_cache[jax.tree_util.keystr(path)] = leaf
-        return leaf
-
-    jax.tree_util.tree_map_with_path(name_leaf, caches)
-
-    def storage_of(path, leaf):
-        return flat_cache[jax.tree_util.keystr(path)]
-
-    with jax.named_scope("prefill"):
-        logits, new = M.prefill(params, cfg, {"tokens": tokens}, lens)
-        return logits.astype(jnp.float32), _scatter_prefill_caches(
-            new, storage_of, page_ids, page_size)
-
-
 # The paged executables donate the page pool (``caches``, argument 3): the
 # layer scan carries it and writes each new token into it in place, so the
 # pool returned aliases the pool passed and a launch copies none of it.
@@ -485,13 +389,16 @@ def _paged_refill_scan_decode(params, cfg: ModelConfig, last_logits, caches,
                               stop_at_eos: bool, spec, table, positions,
                               done, refill_rows, refill_prompts,
                               refill_lens, refill_page_ids):
-    """``_refill_scan_decode`` over the paged layout, prefilling only the
-    admitted rows: ``refill_prompts`` (R, W) are the admitted prompts
-    compacted into a row bucket, ``refill_rows`` (R,) their slot rows
-    (``bucket_rows``; filler entries equal b).  Prefill the R rows,
-    scatter their page blocks into the pool storage (filler rows scatter
-    to trash), reset the admitted slots, then run the segment over all b
-    slots."""
+    """``_paged_scan_decode`` with slot refill fused into the same
+    executable, prefilling only the admitted rows: ``refill_prompts``
+    (R, W) are the admitted prompts compacted into a row bucket,
+    ``refill_rows`` (R,) their slot rows (``bucket_rows``; filler entries
+    equal b) and ``refill_lens`` (R,) their true lengths.  Prefill the R
+    rows, scatter their page blocks into the pool storage at
+    ``refill_page_ids`` (filler rows scatter to trash), reset the admitted
+    slots' position / done / last logits, then run the segment over all
+    b slots: one launch admits every slot drained at a boundary and
+    decodes the next segment."""
     COMPILE_COUNTS["paged_refill_scan_decode"] += 1
     flat_cache = {}
 
@@ -663,7 +570,7 @@ def _refill_operands(rows: np.ndarray, prompts: np.ndarray,
     """The bucketed slot rows ``rows`` (``bucket_rows``) with their
     prompts and true lengths compacted from the slot-aligned (b, W)
     ``prompts`` and (b,) ``lens`` — filler entries take the last slot's —
-    as the fused refill executables take them."""
+    as the fused refill executable takes them."""
     take = functools.partial(np.take, indices=rows, axis=0, mode="clip")
     return (jnp.asarray(rows), jnp.asarray(take(prompts)),
             jnp.asarray(take(lens), jnp.int32))
@@ -731,30 +638,33 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
     the returned state only.
 
     ``refill`` = (mask (b,), prompts (b, W), prompt_lens (b,)) admits new
-    requests into the masked slots **in the same executable launch**: the
-    masked rows' prompts are compacted into the smallest row bucket that
-    holds them (``bucket_rows``) and prefilled (right-padded to width W,
-    true lengths in ``prompt_lens``), and the masked rows reset to decode
-    from their own prompt before the segment runs — bit-identical to
-    ``refill_slots`` of the same rows followed by a plain segment, minus
-    the per-boundary launch overhead.  The same attention-backbone
-    restriction applies to padded refill prompts.
+    requests into the masked slots of a paged state **in the same
+    executable launch**: the masked rows' prompts are compacted into the
+    smallest row bucket that holds them (``bucket_rows``) and prefilled
+    (right-padded to width W, true lengths in ``prompt_lens``) into fresh
+    pages, and the masked rows reset to decode from their own prompt
+    before the segment runs; the other rows decode on untouched.  A dense
+    state decodes its one batch to the end and takes no refill.
     """
     steps = int(steps)
     pg = state.paged
     if steps <= 0:
         raise ValueError(f"steps must be positive, got {steps}")
-    if pg is not None:
-        # per-row bound: a paged batch has no shared horizon, each live
-        # row just needs `steps` more slots under its own kv_cap.  With a
-        # refill the guard runs again after the drained rows are retired
-        # and re-admitted below.
-        if refill is None:
-            pg.check_steps(steps)
-    elif state.used + steps > state.max_len:
-        raise ValueError(
-            f"segment of {steps} steps overruns the cache: "
-            f"{state.used} used of {state.max_len} slots")
+    if pg is None:
+        if refill is not None:
+            raise ValueError(
+                "a refill needs a paged state (open_state, or "
+                "prefill_state with a kv_pool): a dense state decodes its "
+                "one batch to the end")
+        if state.used + steps > state.max_len:
+            raise ValueError(
+                f"segment of {steps} steps overruns the cache: "
+                f"{state.used} used of {state.max_len} slots")
+    elif refill is None:
+        # per-row bound: each live row needs `steps` more slots under its
+        # own kv_cap.  With a refill the guard runs after the drained rows
+        # are retired and re-admitted below.
+        pg.check_steps(steps)
     if temperature > 0.0 and state.key is None:
         raise ValueError(
             "stochastic decoding (temperature > 0) requires an explicit "
@@ -793,39 +703,38 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
         if not mask.any():
             raise ValueError("refill mask selects no rows — pass "
                              "refill=None for a plain segment")
-        _check_refill_lens(cfg, state, width, lens[mask])
         mlens = lens[mask]
+        if mlens.min() < 1 or mlens.max() > width:
+            raise ValueError(
+                f"prompt_lens must lie in [1, {width}], got "
+                f"[{mlens.min()}, {mlens.max()}]")
+        if mlens.max() >= state.max_len or width > state.max_len:
+            raise ValueError(
+                f"refill prompt of {mlens.max()} tokens (padded to {width}) "
+                f"leaves no decode room in a {state.max_len}-slot cache")
         lens = np.where(mask, lens, 1)      # filler rows: any valid length
         # prefill the admitted rows only, in the smallest bucket that holds
         # them
         rows = bucket_rows(np.flatnonzero(mask), b, state.row_shards)
         operands = _refill_operands(rows, prompts, lens)
-        if pg is not None:
-            # host-side admission before the launch: release whatever the
-            # refilled slots still hold (no-op if the serve loop retired
-            # them at sync), then allocate their prompt pages
-            for i in np.flatnonzero(mask):
-                if pg.row_preadmitted[i]:
-                    pg.row_preadmitted[i] = False   # reserved at admit time
-                else:
-                    pg.retire_row(int(i))
-                    pg.admit_row(int(i), int(lens[i]))
-            pg.check_steps(steps)
-            npg = _ceil_div(width, pg.page_size)
-            ids = jnp.asarray(pg.prompt_page_ids(rows, npg).reshape(-1))
-            pg.ensure(steps)
-            (gen, dec, last, caches, done, key, positions,
-             stats) = _paged_refill_scan_decode(
-                params, cfg, state.last_logits, state.caches, key, steps,
-                float(temperature), bool(stop_at_eos), pg.spec,
-                pg.device_table(), state.positions, state.done, *operands,
-                ids)
-        else:
-            gen, dec, last, caches, done, key, positions, stats = \
-                _refill_scan_decode(
-                    params, cfg, state.last_logits, state.caches, key, steps,
-                    float(temperature), bool(stop_at_eos), state.positions,
-                    state.done, *operands)
+        # host-side admission before the launch: release whatever the
+        # refilled slots still hold (no-op if the serve loop retired them
+        # at sync), then allocate their prompt pages
+        for i in np.flatnonzero(mask):
+            if pg.row_preadmitted[i]:
+                pg.row_preadmitted[i] = False   # reserved at admit time
+            else:
+                pg.retire_row(int(i))
+                pg.admit_row(int(i), int(lens[i]))
+        pg.check_steps(steps)
+        npg = _ceil_div(width, pg.page_size)
+        ids = jnp.asarray(pg.prompt_page_ids(rows, npg).reshape(-1))
+        pg.ensure(steps)
+        (gen, dec, last, caches, done, key, positions,
+         stats) = _paged_refill_scan_decode(
+            params, cfg, state.last_logits, state.caches, key, steps,
+            float(temperature), bool(stop_at_eos), pg.spec,
+            pg.device_table(), state.positions, state.done, *operands, ids)
         used = max(state.used, int(mlens.max()))
     new = DecodeState(caches, last, positions + steps, done,
                       key if state.key is not None else None,
@@ -833,93 +742,6 @@ def decode_segment(params, cfg: ModelConfig, state: DecodeState, steps: int,
                       prefill_rows=0 if refill is None else len(rows),
                       row_shards=state.row_shards, stats=stats)
     return new, gen, dec
-
-
-def refill_slots(params, cfg: ModelConfig, state: DecodeState,
-                 rows: Sequence[int], prompts, *,
-                 prompt_lens: Optional[Sequence[int]] = None) -> DecodeState:
-    """Admit new prompts into slots ``rows`` between decode segments.
-
-    ``prompts`` is a (p, W) int token matrix with p >= r = len(rows): the
-    first r rows are the refilled prompts (right-padded to a common width
-    W), trailing rows are all-PAD filler so the matrix can match a warmed
-    prefill shape — the slot batch's own (b, L) is always warm, so a refill
-    boundary costs **one** executable launch however many slots drain
-    together.  ``prompt_lens`` gives each refilled prompt's true length
-    (None = exactly W).  Each prompt's caches are scattered
-    into the batch state at its row (every decode-cache leaf carries batch
-    on ``CACHE_BATCH_AXIS``) and the row's position/done/logits reset —
-    the other rows are untouched, so the refilled batch keeps decoding
-    them bit-identically.  A refilled row decodes from its true length
-    with attention masked there, so pad garbage in the cache tail is never
-    attended (attention backbones only — SSM prefill state consumes the
-    pads, exactly as in ``prefill_state``).
-    """
-    arr = np.asarray(prompts, np.int32)
-    if arr.ndim != 2:
-        raise ValueError(f"prompts must be (p, W), got {arr.shape}")
-    p, width = arr.shape
-    rows = np.asarray(rows, np.int32).reshape(-1)
-    r = rows.shape[0]
-    if r > p:
-        raise ValueError(f"{r} rows for only {p} prompts")
-    if r == 0:
-        return state
-    if len({int(x) for x in rows}) != r:
-        raise ValueError(f"duplicate refill rows: {rows.tolist()}")
-    if rows.min() < 0 or rows.max() >= state.batch:
-        raise ValueError(
-            f"rows {rows.tolist()} out of range [0, {state.batch})")
-    lens = (np.full((r,), width, np.int64) if prompt_lens is None
-            else np.asarray(prompt_lens, np.int64).reshape(-1))
-    if lens.shape != (r,):
-        raise ValueError(f"prompt_lens shape {lens.shape} != ({r},)")
-    _check_refill_lens(cfg, state, width, lens)
-    ridx = jnp.asarray(rows)
-    # filler prompt rows (j >= r) are all-PAD: any in-range length does
-    plens = jnp.asarray(np.concatenate([lens, np.full(p - r, width)]),
-                        jnp.int32)
-    if state.paged is not None:
-        pg = state.paged
-        for j, row in enumerate(rows):
-            if pg.row_preadmitted[row]:
-                pg.row_preadmitted[row] = False   # reserved at admit time
-            else:
-                pg.retire_row(int(row))
-                pg.admit_row(int(row), int(lens[j]))
-        npg = _ceil_div(width, pg.page_size)
-        # prompt-row j's page blocks land in slot rows[j]'s fresh pages;
-        # filler prompt rows (j >= r) scatter to trash
-        ids = pg.prompt_page_ids(
-            np.concatenate([rows, np.full(p - r, state.batch, np.int32)]),
-            npg)
-        last, merged = _paged_refill_prefill(
-            params, cfg, jnp.asarray(arr), pg.page_size,
-            jnp.asarray(ids.reshape(-1)), state.caches, plens)
-    else:
-        last, caches = _prefill(params, cfg, jnp.asarray(arr), plens)
-        caches = _pad_caches(caches, state.max_len, width)
-        merged = jax.tree.map(
-            lambda full, new: full.at[:, ridx].set(
-                new[:, :r].astype(full.dtype)),
-            state.caches, caches)
-    return dataclasses.replace(
-        state,
-        caches=merged,
-        last_logits=state.last_logits.at[ridx].set(last[:r]),
-        positions=state.positions.at[ridx].set(plens[:r]),
-        done=state.done.at[ridx].set(False),
-        used=max(state.used, int(lens.max())))
-
-
-def refill_slot(params, cfg: ModelConfig, state: DecodeState, row: int,
-                prompt: Sequence[int], *,
-                prompt_len: Optional[int] = None) -> DecodeState:
-    """Single-slot ``refill_slots``: admit one prompt into slot ``row``."""
-    arr = np.asarray(prompt, np.int32).reshape(1, -1)
-    return refill_slots(params, cfg, state, [row], arr,
-                        prompt_lens=None if prompt_len is None
-                        else [int(prompt_len)])
 
 
 # ---------------------------------------------------------------------------

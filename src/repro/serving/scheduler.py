@@ -65,14 +65,10 @@ def decode_compile_counts() -> Dict[str, int]:
     from repro.serving import sampler
     return {"prefill": int(sampler.COMPILE_COUNTS["prefill"]),
             "scan_decode": int(sampler.COMPILE_COUNTS["scan_decode"]),
-            "refill_scan_decode":
-                int(sampler.COMPILE_COUNTS["refill_scan_decode"]),
             "paged_prefill": int(sampler.COMPILE_COUNTS["paged_prefill"]),
             "paged_open": int(sampler.COMPILE_COUNTS["paged_open"]),
             "paged_scan_decode":
                 int(sampler.COMPILE_COUNTS["paged_scan_decode"]),
-            "paged_refill_prefill":
-                int(sampler.COMPILE_COUNTS["paged_refill_prefill"]),
             "paged_refill_scan_decode":
                 int(sampler.COMPILE_COUNTS["paged_refill_scan_decode"]),
             "tier0": int(tier0.COMPILE_COUNTS["tier0"])}
@@ -157,8 +153,6 @@ class SchedulerStats:
     # paged-KV accounting (segment granularity, folded in by
     # SlotRun.account / SlotRuntime._admit).  pages_in_use / kv_live_tokens
     # are gauges (last retire's snapshot); the peaks are monotonic maxima.
-    # kv_peak_tokens is also set on the dense path (batch x max_len per
-    # run), so paged-vs-dense KV footprints compare through one counter.
     kv_page_size: int = 0
     pages_in_use: int = 0
     pages_peak: int = 0
@@ -166,8 +160,6 @@ class SchedulerStats:
     kv_peak_tokens: int = 0
     admissions_deferred_on_pages: int = 0    # boundaries that idled a free
     #                                          slot waiting for pool pages
-    admissions_deferred_on_horizon: int = 0  # dense counterpart (remaining
-    #                                          horizon below one budget)
     # fault tolerance (bounded retry / quarantine / SLO deadlines /
     # degraded answers).  ``submitted`` counts each prompt once, so
     # exactly-once accounting reads: every submitted prompt ends either
@@ -306,9 +298,7 @@ class SchedulerStats:
                              "fragmentation":
                                  round(self.page_fragmentation, 4),
                              "deferred_on_pages":
-                                 self.admissions_deferred_on_pages,
-                             "deferred_on_horizon":
-                                 self.admissions_deferred_on_horizon},
+                                 self.admissions_deferred_on_pages},
                 "faults": {"retries": self.retries,
                            "requeued": self.requeued,
                            "quarantined": self.quarantined,

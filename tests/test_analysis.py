@@ -196,7 +196,7 @@ def test_jaxpr_pass_callback_inside_scan_body_is_found():
 
 def test_registered_hot_path_executables_are_clean():
     """Acceptance: fused decode, paged segment scan (both kernels) and the
-    fused refills trace with abstract inputs and contain no host callbacks,
+    paged fused refill trace with abstract inputs and contain no host callbacks,
     f64 promotions, or staged host transfers."""
     findings = run_jaxpr_pass()
     assert findings == [], [f.render() for f in findings]

@@ -168,7 +168,7 @@ def test_the_stream_never_reads_a_donated_pool(params, world, retriever,
     engine = ScopeEngine.build(EngineConfig(
         estimator=est, retriever=retriever, library=library,
         models_meta={m: world.models[m] for m in data.models},
-        refill=True, segment_len=3, kv_paged=True, kv_page_size=8))
+        refill=True, segment_len=3, kv_page_size=8))
     sched = MicrobatchScheduler(BucketConfig(batch_sizes=(8,)))
     ticks = [queries[:1], queries[1:3], [], queries[3:], []]
     pools = list(engine.predict_stream(iter([RouteRequest(t) for t in ticks]),

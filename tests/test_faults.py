@@ -420,7 +420,7 @@ def test_refill_segment_and_pool_faults_recover(chaos_engine):
     fault-free answers and the kv_exhausted_rows counter records the
     row-level failure."""
     mk, data = chaos_engine
-    paged = {"kv_paged": True, "kv_page_size": 8}
+    paged = {"kv_page_size": 8}
     _, _, ref = _run(mk, data, refill=True, **paged)
     plan = FaultPlan([FaultSpec("segment", 1), FaultSpec("pool", 2)])
     _, sched, got = _run(mk, data, refill=True, max_retries=2,

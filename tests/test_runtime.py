@@ -120,19 +120,43 @@ def test_decode_segments_match_one_shot_temperature(tiny_trained):
     np.testing.assert_array_equal(np.concatenate(gs, axis=1), g1)
 
 
+def _paged_state(params, cfg, prompts, *, budget, lens=None, page_size=8):
+    """A paged state over ``prompts``, prefilled, on a pool that holds every
+    row's worst case twice over (refilled rows never wait on pages)."""
+    from repro.serving.kv_pool import KVPool
+    b, width = np.shape(prompts)
+    pool = KVPool(n_pages=2 * b * -(-(width + budget) // page_size),
+                  page_size=page_size)
+    return sampler.prefill_state(params, cfg, prompts, max_new_tokens=budget,
+                                 prompt_lens=lens, kv_pool=pool)
+
+
+def _refill(b, rows, prompts, lens, width):
+    """A ``decode_segment`` refill admitting ``prompts`` (their true
+    ``lens``) into slot ``rows`` of a b-slot state, padded to ``width``."""
+    mask = np.zeros(b, bool)
+    mat = np.zeros((b, width), np.int32)
+    full = np.ones(b, np.int64)
+    for row, p, n in zip(rows, prompts, lens, strict=True):
+        mask[row] = True
+        mat[row, :n] = np.asarray(p)[:n]
+        full[row] = n
+    return mask, mat, full
+
+
 def test_refill_slot_between_segments(tiny_trained):
     """A drained slot refilled with a fresh prompt decodes exactly like a
     standalone run of that prompt, and the other rows are untouched."""
     cfg, params, _ = tiny_trained
     rng = np.random.default_rng(4)
     prompts = rng.integers(3, 100, size=(4, 18)).astype(np.int32)
-    state = sampler.prefill_state(params, cfg, prompts, max_new_tokens=8)
+    state = _paged_state(params, cfg, prompts, budget=8)
     state, _, _ = sampler.decode_segment(params, cfg, state, 4)
 
     new_prompt = rng.integers(3, 100, size=18).astype(np.int32)
-    state = sampler.refill_slot(params, cfg, state, 2, new_prompt)
-    assert int(state.positions[2]) == 18 and not bool(state.done[2])
-    state, g, d = sampler.decode_segment(params, cfg, state, 4)
+    state, g, d = sampler.decode_segment(
+        params, cfg, state, 4, refill=_refill(4, [2], [new_prompt], [18], 18))
+    assert int(state.positions[2]) == 18 + 4
 
     # reference at the same batch size (a b=1 run picks a different gemm
     # path whose accumulation differs in the last ulp): token stream must
@@ -145,101 +169,74 @@ def test_refill_slot_between_segments(tiny_trained):
                                atol=5e-6, rtol=1e-6)
 
     # untouched rows continue bit-identically vs a no-refill run
-    s2 = sampler.prefill_state(params, cfg, prompts, max_new_tokens=8)
+    s2 = _paged_state(params, cfg, prompts, budget=8)
     s2, _, _ = sampler.decode_segment(params, cfg, s2, 4)
-    s2, g2, _ = sampler.decode_segment(params, cfg, s2, 4)
+    s2, g2, d2 = sampler.decode_segment(params, cfg, s2, 4)
     np.testing.assert_array_equal(
         np.asarray(g)[[0, 1, 3]], np.asarray(g2)[[0, 1, 3]])
+    np.testing.assert_array_equal(
+        np.asarray(d)[[0, 1, 3]], np.asarray(d2)[[0, 1, 3]])
 
 
 def test_refill_slot_padded_prompt_matches_exact(tiny_trained):
     """A refill prompt padded to the warmed bucket width (with its true
-    prompt_len) decodes bit-identically to an exact-length refill: pad
-    garbage in the cache tail is masked out by the per-row valid length."""
+    prompt_len) decodes like an exact-length refill: pad garbage in the
+    cache tail is masked out by the per-row valid length."""
     cfg, params, _ = tiny_trained
     rng = np.random.default_rng(11)
     prompts = rng.integers(3, 100, size=(4, 18)).astype(np.int32)
-    state = sampler.prefill_state(params, cfg, prompts, max_new_tokens=8)
-    state, _, _ = sampler.decode_segment(params, cfg, state, 4)
-
     new_prompt = rng.integers(3, 100, size=12).astype(np.int32)
-    padded = np.zeros(18, np.int32)
-    padded[:12] = new_prompt
-    s_exact = sampler.refill_slot(params, cfg, state, 2, new_prompt)
-    s_pad = sampler.refill_slot(params, cfg, state, 2, padded,
-                                prompt_len=12)
-    assert int(s_pad.positions[2]) == 12
-    _, g_e, d_e = sampler.decode_segment(params, cfg, s_exact, 4)
-    _, g_p, d_p = sampler.decode_segment(params, cfg, s_pad, 4)
-    np.testing.assert_array_equal(np.asarray(g_p), np.asarray(g_e))
-    np.testing.assert_allclose(np.asarray(d_p), np.asarray(d_e),
-                               atol=5e-6, rtol=1e-6)
+    out = {}
+    for width in (12, 18):
+        state = _paged_state(params, cfg, prompts, budget=8)
+        state, _, _ = sampler.decode_segment(params, cfg, state, 4)
+        state, g, d = sampler.decode_segment(
+            params, cfg, state, 4,
+            refill=_refill(4, [2], [new_prompt], [12], width))
+        assert int(state.positions[2]) == 12 + 4
+        out[width] = (np.asarray(g), np.asarray(d))
+    np.testing.assert_array_equal(out[18][0], out[12][0])
+    np.testing.assert_allclose(out[18][1], out[12][1], atol=5e-6, rtol=1e-6)
 
 
 def test_refill_slots_batched_matches_sequential(tiny_trained):
-    """One batched refill_slots call (padded to the warmed (b, L) prefill
-    shape) equals sequential single-slot refills."""
+    """One two-row refill equals two one-row refills in consecutive
+    launches: each refilled row decodes the same window from its own
+    admission on."""
     cfg, params, _ = tiny_trained
     rng = np.random.default_rng(12)
     prompts = rng.integers(3, 100, size=(4, 18)).astype(np.int32)
     fresh = rng.integers(3, 100, size=(2, 14)).astype(np.int32)
 
-    state = sampler.prefill_state(params, cfg, prompts, max_new_tokens=8)
-    state, _, _ = sampler.decode_segment(params, cfg, state, 4)
+    def run(admissions):
+        """Launch one 4-step segment per entry of ``admissions`` (the
+        rows refilled by it), then one more; returns (gen, dec) per
+        launch."""
+        state = _paged_state(params, cfg, prompts, budget=16)
+        state, _, _ = sampler.decode_segment(params, cfg, state, 4)
+        outs = []
+        for rows in admissions + [[]]:
+            refill = (_refill(4, rows, [fresh[r // 2] for r in rows],
+                              [14] * len(rows), 18) if rows else None)
+            state, g, d = sampler.decode_segment(params, cfg, state, 4,
+                                                 refill=refill)
+            outs.append((np.asarray(g), np.asarray(d)))
+        return outs
 
-    mat = np.zeros((4, 18), np.int32)           # padded to (b, L)
-    mat[0, :14] = fresh[0]
-    mat[1, :14] = fresh[1]
-    s_batch = sampler.refill_slots(params, cfg, state, [1, 3], mat,
-                                   prompt_lens=[14, 14])
-    s_seq = sampler.refill_slot(params, cfg, state, 1, fresh[0])
-    s_seq = sampler.refill_slot(params, cfg, s_seq, 3, fresh[1])
-    _, g_b, d_b = sampler.decode_segment(params, cfg, s_batch, 4)
-    _, g_s, d_s = sampler.decode_segment(params, cfg, s_seq, 4)
-    np.testing.assert_array_equal(np.asarray(g_b), np.asarray(g_s))
-    np.testing.assert_allclose(np.asarray(d_b), np.asarray(d_s),
-                               atol=5e-6, rtol=1e-6)
-
-
-def test_decode_segment_fused_refill_matches_unfused(tiny_trained):
-    """decode_segment(refill=(mask, prompts, lens)) — prefill + merge +
-    scan in one executable — is bit-identical to refill_slots followed by
-    a plain segment (tokens AND decision logits: same math, one launch).
-    The fused launch prefills the two admitted rows as a 2-row bucket, so
-    the unfused prefill gets the same two rows."""
-    cfg, params, _ = tiny_trained
-    rng = np.random.default_rng(13)
-    prompts = rng.integers(3, 100, size=(4, 18)).astype(np.int32)
-    fresh = rng.integers(3, 100, size=(2, 12)).astype(np.int32)
-
-    state = sampler.prefill_state(params, cfg, prompts, max_new_tokens=16)
-    state, _, _ = sampler.decode_segment(params, cfg, state, 4)
-
-    mat = np.zeros((4, 18), np.int32)
-    mat[1, :12] = fresh[0]
-    mat[3, :12] = fresh[1]
-    s_ref = sampler.refill_slots(params, cfg, state, [1, 3],
-                                 np.concatenate([mat[1:2], mat[3:4]]),
-                                 prompt_lens=[12, 12])
-    s_ref, g_ref, d_ref = sampler.decode_segment(params, cfg, s_ref, 4)
-
-    mask = np.array([False, True, False, True])
-    s_fus, g_fus, d_fus = sampler.decode_segment(
-        params, cfg, state, 4, refill=(mask, mat, [1, 12, 1, 12]))
-    np.testing.assert_array_equal(np.asarray(g_fus), np.asarray(g_ref))
-    np.testing.assert_array_equal(np.asarray(d_fus), np.asarray(d_ref))
-    np.testing.assert_array_equal(np.asarray(s_fus.positions),
-                                  np.asarray(s_ref.positions))
-    # continuation stays aligned too
-    _, g2f, _ = sampler.decode_segment(params, cfg, s_fus, 4)
-    _, g2r, _ = sampler.decode_segment(params, cfg, s_ref, 4)
-    np.testing.assert_array_equal(np.asarray(g2f), np.asarray(g2r))
+    batch = run([[1, 3]])
+    seq = run([[1], [3]])
+    for row, at in ((1, 0), (3, 1)):        # the launch admitting the row
+        for k in range(2):
+            np.testing.assert_array_equal(seq[at + k][0][row],
+                                          batch[k][0][row])
+            np.testing.assert_allclose(seq[at + k][1][row],
+                                       batch[k][1][row],
+                                       atol=5e-6, rtol=1e-6)
 
 
 def test_decode_segment_refill_guards(tiny_trained):
     cfg, params, _ = tiny_trained
-    state = sampler.prefill_state(params, cfg, np.ones((2, 10), np.int32),
-                                  max_new_tokens=8)
+    state = _paged_state(params, cfg, np.ones((2, 10), np.int32), budget=8)
     mat = np.ones((2, 8), np.int32)
     with pytest.raises(ValueError, match="no rows"):
         sampler.decode_segment(params, cfg, state, 4,
@@ -250,36 +247,53 @@ def test_decode_segment_refill_guards(tiny_trained):
     with pytest.raises(ValueError, match="prompt_lens"):
         sampler.decode_segment(params, cfg, state, 4,
                                refill=([True, False], mat, [0, 8]))
+    with pytest.raises(ValueError, match="prompt_lens"):
+        sampler.decode_segment(params, cfg, state, 4,
+                               refill=([True, False], mat, [9, 8]))
 
 
-def test_refill_slots_guards(tiny_trained):
+def test_decode_segment_refill_needs_paged_state(tiny_trained):
+    """A dense state decodes its one batch to the end: a refill raises."""
     cfg, params, _ = tiny_trained
-    prompts = np.ones((3, 10), np.int32)
-    state = sampler.prefill_state(params, cfg, prompts, max_new_tokens=4)
-    mat = np.ones((2, 8), np.int32)
-    with pytest.raises(ValueError, match="duplicate"):
-        sampler.refill_slots(params, cfg, state, [1, 1], mat)
-    with pytest.raises(ValueError, match="out of range"):
-        sampler.refill_slots(params, cfg, state, [0, 5], mat)
-    with pytest.raises(ValueError, match="rows for only"):
-        sampler.refill_slots(params, cfg, state, [0, 1, 2], mat)
-    with pytest.raises(ValueError, match="prompt_len"):
-        sampler.refill_slots(params, cfg, state, [0, 1], mat,
-                             prompt_lens=[0, 8])
+    state = sampler.prefill_state(params, cfg, np.ones((2, 10), np.int32),
+                                  max_new_tokens=8)
+    with pytest.raises(ValueError, match="paged state"):
+        sampler.decode_segment(params, cfg, state, 4,
+                               refill=([True, False], np.ones((2, 8),
+                                                              np.int32),
+                                       [8, 8]))
+
+
+@pytest.mark.parametrize("width,length", [(14, 14), (16, 8)])
+def test_fused_refill_without_decode_room_raises(tiny_trained, width,
+                                                 length):
+    """A refill prompt as long as the row capacity (kv_cap = 10 + 4), or
+    padded wider than it, leaves no decode room: refused before any page
+    moves."""
+    cfg, params, _ = tiny_trained
+    state = _paged_state(params, cfg, np.ones((2, 10), np.int32), budget=4)
+    pool = state.paged.pool
+    in_use = pool.pages_in_use
+    with pytest.raises(ValueError, match="no decode room"):
+        sampler.decode_segment(
+            params, cfg, state, 2,
+            refill=_refill(2, [0], [[5] * length], [length], width))
+    assert pool.pages_in_use == in_use
 
 
 def test_refill_and_segment_guards(tiny_trained):
     cfg, params, _ = tiny_trained
     prompts = np.ones((2, 10), np.int32)
     state = sampler.prefill_state(params, cfg, prompts, max_new_tokens=4)
-    with pytest.raises(ValueError, match="out of range"):
-        sampler.refill_slot(params, cfg, state, 5, [1] * 8)
-    with pytest.raises(ValueError, match="no decode room"):
-        sampler.refill_slot(params, cfg, state, 0, [1] * 14)
     with pytest.raises(ValueError, match="overruns the cache"):
         sampler.decode_segment(params, cfg, state, 5)
     with pytest.raises(ValueError, match="positive"):
         sampler.decode_segment(params, cfg, state, 0)
+    # the refill guards, on the paged state a refill needs
+    paged = _paged_state(params, cfg, prompts, budget=4)
+    with pytest.raises(ValueError, match="no decode room"):
+        sampler.decode_segment(params, cfg, paged, 2,
+                               refill=_refill(2, [0], [[1] * 14], [14], 14))
 
 
 def test_generate_requires_rng_for_stochastic_decoding(tiny_trained):
@@ -552,7 +566,7 @@ def _drive_slot_run(est, prompts, tags, extra, *, segment_len):
             n = min(len(queue), len(run.free_rows()))
             run.admit([(t, p, len(p)) for t, p in queue[:n]])
             del queue[:n]
-        assert not run.finished, "queue left but horizon exhausted"
+        assert not run.finished, "queue left but the run finished"
         tags_done, batch = run.step()
         for i, t in enumerate(tags_done):
             results[t] = {f: getattr(batch, f)[i] for f in
@@ -768,51 +782,32 @@ def test_paged_prefill_and_segments_bit_identical(tiny_trained):
 
 
 def test_paged_refill_segment_bit_identical(tiny_trained):
-    """The fused refill+decode executable matches dense under paging: the
-    refilled row restarts from its true length in fresh pages, the
-    untouched rows keep decoding bit-identically."""
+    """The fused refill+decode executable under paging: the refilled row
+    restarts from its true length in fresh pages and decodes as
+    ``generate`` does for its prompt; the untouched rows keep decoding
+    bit-identically to a paged run with no refill."""
     cfg, params, _ = tiny_trained
     rng = np.random.default_rng(11)
     prompts = rng.integers(3, 100, size=(3, 16)).astype(np.int32)
-    dense, paged, pool = _paged_pair(cfg, params, prompts, [16, 11, 16],
-                                     budget=14)
-    dense, g0, _ = sampler.decode_segment(params, cfg, dense, 4)
-    paged, g1, _ = sampler.decode_segment(params, cfg, paged, 4)
-    np.testing.assert_array_equal(np.asarray(g0), np.asarray(g1))
     fresh = rng.integers(3, 100, size=(16,)).astype(np.int32)
-    mask = np.array([False, True, False])
-    mat = np.zeros((3, 16), np.int32)
-    mat[1] = fresh
-    refill = (mask, mat, np.array([1, 12, 1], np.int64))
-    dense, g0, d0 = sampler.decode_segment(params, cfg, dense, 4,
-                                           refill=refill)
-    paged, g1, d1 = sampler.decode_segment(params, cfg, paged, 4,
-                                           refill=refill)
-    np.testing.assert_array_equal(np.asarray(g0), np.asarray(g1))
-    np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
-
-
-def test_paged_refill_slots_bit_identical(tiny_trained):
-    """``refill_slots`` (the standalone prefill-merge path) re-pages the
-    refilled rows and matches the dense scatter bit-for-bit."""
-    cfg, params, _ = tiny_trained
-    rng = np.random.default_rng(12)
-    prompts = rng.integers(3, 100, size=(3, 14)).astype(np.int32)
-    dense, paged, _ = _paged_pair(cfg, params, prompts, None, budget=10)
-    dense, _, _ = sampler.decode_segment(params, cfg, dense, 3)
-    paged, _, _ = sampler.decode_segment(params, cfg, paged, 3)
-    fresh = rng.integers(3, 100, size=(2, 14)).astype(np.int32)
-    dense = sampler.refill_slots(params, cfg, dense, [0, 2], fresh,
-                                 prompt_lens=[14, 9])
-    paged = sampler.refill_slots(params, cfg, paged, [0, 2], fresh,
-                                 prompt_lens=[14, 9])
-    np.testing.assert_array_equal(np.asarray(dense.last_logits),
-                                  np.asarray(paged.last_logits))
-    for steps in (4, 3):
-        dense, g0, d0 = sampler.decode_segment(params, cfg, dense, steps)
-        paged, g1, d1 = sampler.decode_segment(params, cfg, paged, steps)
-        np.testing.assert_array_equal(np.asarray(g0), np.asarray(g1))
-        np.testing.assert_array_equal(np.asarray(d0), np.asarray(d1))
+    lens = [16, 11, 16]
+    outs = {}
+    for refill in (False, True):
+        state = _paged_state(params, cfg, prompts, budget=14, lens=lens)
+        state, _, _ = sampler.decode_segment(params, cfg, state, 4)
+        state, g, d = sampler.decode_segment(
+            params, cfg, state, 4,
+            refill=_refill(3, [1], [fresh], [12], 16) if refill else None)
+        outs[refill] = (np.asarray(g), np.asarray(d))
+    keep = [0, 2]
+    np.testing.assert_array_equal(outs[True][0][keep], outs[False][0][keep])
+    np.testing.assert_array_equal(outs[True][1][keep], outs[False][1][keep])
+    g_ref, d_ref = sampler.generate(params, cfg,
+                                    np.repeat(fresh[None, :12], 3, 0),
+                                    max_new_tokens=4)
+    np.testing.assert_array_equal(outs[True][0][1], g_ref[0])
+    np.testing.assert_allclose(outs[True][1][1], d_ref[0],
+                               atol=5e-6, rtol=1e-6)
 
 
 def test_paged_pallas_kernel_matches_dense(tiny_trained):
@@ -886,48 +881,8 @@ def test_paged_guards(tiny_trained):
     check_paged_support(get_config("deepseek-v2-lite-16b").reduced())
 
 
-def test_slot_run_paged_matches_dense(tiny_trained):
-    """A paged SlotRun serves the same request set as the dense-horizon
-    run with identical parses, and drains the pool on retirement."""
-    cfg, params, _ = tiny_trained
-    from repro.serving.kv_pool import KVPool
-    est = ReasoningEstimator(cfg, params, max_new_tokens=8)
-    rng = np.random.default_rng(16)
-    prompts = rng.integers(3, 100, size=(4, 18)).astype(np.int32)
-    extra = [("e", list(rng.integers(3, 100, size=18).astype(np.int32))),
-             ("f", list(rng.integers(3, 100, size=18).astype(np.int32)))]
-
-    def drive(**kw):
-        run = est.open_slots(prompts, tags=["a", "b", "c", "d"],
-                             segment_len=4, **kw)
-        queue = list(extra)
-        results = {}
-        while not run.finished or queue:
-            if queue and run.free_rows() and run.can_admit():
-                n = min(len(queue), len(run.free_rows()))
-                run.admit([(t, p, len(p)) for t, p in queue[:n]])
-                del queue[:n]
-            tags_done, batch = run.step()
-            for i, t in enumerate(tags_done):
-                results[t] = (batch.y_hat[i], batch.len_hat[i],
-                              batch.pred_tokens[i], batch.p_conf[i])
-        return results
-
-    dense = drive()
-    pool = KVPool(n_pages=32, page_size=8)
-    paged = drive(kv_pool=pool)
-    assert set(dense) == set(paged) == set("abcdef")
-    for t in dense:
-        assert dense[t][:3] == paged[t][:3], t
-        np.testing.assert_allclose(dense[t][3], paged[t][3],
-                                   atol=1e-6, rtol=1e-6, err_msg=t)
-    # every page returned once the run retired
-    assert pool.pages_in_use == 0 and pool.reserved == 0
-    assert pool.pages_peak > 0 and pool.tokens_peak > 0
-
-
 def test_slot_run_paged_admission_gates_on_pages(tiny_trained):
-    """can_admit() in paged mode reflects the pool, not a horizon: a pool
+    """can_admit() reflects the page pool: a pool
     sized for the opening rows only defers further admissions until a row
     retires and frees its pages."""
     cfg, params, _ = tiny_trained
@@ -938,7 +893,7 @@ def test_slot_run_paged_admission_gates_on_pages(tiny_trained):
     # exactly two worst-case rows: ceil((16+8)/8) = 3 pages each
     pool = KVPool(n_pages=6, page_size=8)
     run = est.open_slots(prompts, tags=["a"], kv_pool=pool, segment_len=4)
-    assert run.horizon is None and run.deferral_reason == "pages"
+    assert run.kv_pool is pool and run.state.paged.pool is pool
     # rows 1-2 are free, but the live row's reservation leaves only 3
     # pages — one more worst-case row: admit it, then the pool is dry
     # even though a free slot remains
@@ -1019,13 +974,11 @@ def test_row_bucket_prefills_match_slot_aligned(tiny_trained, n):
     np.testing.assert_array_equal(np.asarray(got.positions)[admitted],
                                   np.asarray(want.positions)[admitted])
 
-    # fused refill of the same slots on a full state, against the unfused
-    # refill of a slot-aligned (b, W) matrix followed by a plain segment
+    # fused refill of the same slots on a full state: the admitted rows
+    # against a prefill of all b fresh prompts and a plain segment, the
+    # others against the full state decoding on with no refill
     fresh = rng.integers(3, 100, size=(b, width)).astype(np.int32)
     flens = rng.integers(width // 2, width + 1, size=b)
-    rows = np.flatnonzero(admitted)
-    full = np.zeros((b, width), np.int32)
-    full[: len(rows)] = fresh[rows]
     states = []
     for _ in range(2):
         st = sampler.prefill_state(params, cfg, prompts, max_new_tokens=12,
@@ -1034,14 +987,21 @@ def test_row_bucket_prefills_match_slot_aligned(tiny_trained, n):
         states.append(st)
     fused, g1, d1 = sampler.decode_segment(
         params, cfg, states[0], 4, refill=(admitted, fresh, flens))
-    ref = sampler.refill_slots(params, cfg, states[1], rows, full,
-                               prompt_lens=flens[rows])
-    ref, g2, d2 = sampler.decode_segment(params, cfg, ref, 4)
     assert fused.prefill_rows == bucket
-    np.testing.assert_array_equal(np.asarray(g1), np.asarray(g2))
-    close(np.asarray(d1), np.asarray(d2))
-    np.testing.assert_array_equal(np.asarray(fused.positions),
-                                  np.asarray(ref.positions))
+    ref = sampler.prefill_state(params, cfg, fresh, max_new_tokens=12,
+                                prompt_lens=flens, kv_pool=_pool(b, width))
+    ref, g2, d2 = sampler.decode_segment(params, cfg, ref, 4)
+    np.testing.assert_array_equal(np.asarray(g1)[admitted],
+                                  np.asarray(g2)[admitted])
+    close(np.asarray(d1)[admitted], np.asarray(d2)[admitted])
+    np.testing.assert_array_equal(np.asarray(fused.positions)[admitted],
+                                  np.asarray(ref.positions)[admitted])
+    rest, g3, d3 = sampler.decode_segment(params, cfg, states[1], 4)
+    np.testing.assert_array_equal(np.asarray(g1)[~admitted],
+                                  np.asarray(g3)[~admitted])
+    close(np.asarray(d1)[~admitted], np.asarray(d3)[~admitted])
+    np.testing.assert_array_equal(np.asarray(fused.positions)[~admitted],
+                                  np.asarray(rest.positions)[~admitted])
 
 
 def test_row_buckets_warm_at_first_open(tiny_trained):
@@ -1096,47 +1056,86 @@ def test_row_buckets_warm_at_first_open(tiny_trained):
         {k: after_open[k] for k in opened}
 
 
-def test_stream_paged_matches_dense_refill(real_engine):
-    """kv_paged engine streams route identically to the dense refill
-    stream, account page stats at segment granularity, and never exceed
-    the dense KV footprint."""
+# ---------------------------------------------------------------------------
+# One slot layout: a continuous-batching state is always paged
+# ---------------------------------------------------------------------------
+def test_open_slots_default_pool_is_engine_worst_case(real_engine):
+    """open_slots() with no pool pages the slots over the pool the engine
+    builds when kv_pool_pages is None: every slot's prompt width plus its
+    budget steps, page-rounded, at the default page size of 16."""
+    from repro.serving.kv_pool import worst_case_pool
     mk, data = real_engine
-    queries = [data.queries[int(q)] for q in data.test_qids[:7]]
-    ticks = [queries[:2], queries[2:3], queries[3:7]]
+    engine = mk(refill=True)
+    est = engine.estimator
+    opened = []
 
-    pools, scheds = {}, {}
-    for paged in (False, True):
-        sched = MicrobatchScheduler(BucketConfig(batch_sizes=(1, 2, 4, 8)))
-        kw = ({"kv_paged": True, "kv_page_size": 8} if paged else {})
-        pools[paged] = list(mk(refill=True, **kw).predict_stream(
-            (RouteRequest(t) for t in ticks), scheduler=sched,
-            segment_len=3))
-        scheds[paged] = sched
-    for field in ("y_hat", "len_hat", "well_formed", "cost_hat",
-                  "pred_overhead"):
-        np.testing.assert_array_equal(
-            np.concatenate([np.asarray(getattr(p, field)) for p in
-                            pools[True]]),
-            np.concatenate([np.asarray(getattr(p, field)) for p in
-                            pools[False]]), err_msg=field)
-    np.testing.assert_allclose(
-        np.concatenate([p.p_hat for p in pools[True]]),
-        np.concatenate([p.p_hat for p in pools[False]]),
-        atol=1e-6, rtol=1e-6)
-    st = scheds[True].stats
-    assert st.kv_page_size == 8 and st.pages_peak > 0
-    assert st.kv_peak_tokens > 0
+    def open_slots(tokens, **kw):
+        run = type(est).open_slots(est, tokens, **kw)
+        opened.append((np.shape(tokens), kw["kv_pool"], run))
+        return run
+
+    est.open_slots = open_slots
+    queries = [data.queries[int(q)] for q in data.test_qids[:2]]
+    list(engine.predict_stream(
+        iter([RouteRequest(queries)]), segment_len=4,
+        scheduler=MicrobatchScheduler(BucketConfig(batch_sizes=(8,)))))
+    assert opened
+    budget_steps = -(-est.max_new_tokens // 4) * 4
+    for (b, width), pool, run in opened:
+        want = worst_case_pool(b, width, budget_steps)
+        assert (pool.n_pages, pool.page_size) == (want.n_pages, 16)
+        assert pool.n_pages == b * -(-(width + budget_steps) // 16)
+        own = type(est).open_slots(est, np.ones((b, width), np.int32),
+                                   segment_len=4)
+        assert (own.kv_pool.n_pages, own.kv_pool.page_size) == \
+            (pool.n_pages, pool.page_size)
+        assert own.state.paged is not None and run.state.paged is not None
+
+
+@pytest.mark.parametrize("horizon", [None, 16])
+def test_slot_run_horizon_must_be_none(tiny_trained, horizon):
+    """A slot state has no dense horizon: the keyword stays for callers
+    that pass it, and only None is accepted."""
+    from repro.core.estimator import SlotRun
+    cfg, params, _ = tiny_trained
+    est = ReasoningEstimator(cfg, params, max_new_tokens=8)
+    prompts = np.ones((2, 10), np.int32)
+    if horizon is None:
+        run = SlotRun(est, prompts, tags=["a"], segment_len=4,
+                      horizon=horizon)
+        assert run.kv_pool.page_size == 16 and run.can_admit()
+    else:
+        with pytest.raises(ValueError, match="horizon"):
+            SlotRun(est, prompts, segment_len=4, horizon=horizon)
+
+
+@pytest.mark.parametrize("knob,value", [("kv_paged", True),
+                                        ("refill_horizon", 48)])
+def test_engine_config_rejects_removed_knobs(knob, value):
+    """The layout knobs are gone: refill=True alone selects paged slots."""
+    import dataclasses
+    names = {f.name for f in dataclasses.fields(EngineConfig)}
+    assert knob not in names
+    assert {"refill", "kv_page_size", "kv_pool_pages", "kv_kernel"} <= names
+    with pytest.raises(TypeError, match=knob):
+        EngineConfig(estimator=None, retriever=None, library=None,
+                     **{knob: value})
+
+
+def test_stream_refill_reports_pages(real_engine):
+    """A predict_stream(refill=True) run pages its KV: SchedulerStats
+    carries the pool's page size, peak pages and peak live tokens, and
+    every page is back in the pool when the stream ends."""
+    mk, data = real_engine
+    queries = [data.queries[int(q)] for q in data.test_qids[:5]]
+    sched = MicrobatchScheduler(BucketConfig(batch_sizes=(1, 2, 4, 8)))
+    pools = list(mk(refill=True).predict_stream(
+        (RouteRequest(t) for t in (queries[:2], queries[2:])),
+        scheduler=sched, segment_len=3))
+    assert len(pools) == 2
+    st = sched.stats
+    assert st.kv_page_size == 16 and st.pages_peak > 0
+    assert st.kv_peak_tokens > 0 and st.pages_in_use == 0
     assert 0.0 <= st.page_fragmentation < 1.0
-    # paged peak KV never exceeds the dense whole-horizon commitment
-    assert st.kv_peak_tokens <= scheds[False].stats.kv_peak_tokens
-    d = st.as_dict()
-    assert d["kv_pages"]["peak"] == st.pages_peak
-
-
-def test_stream_paged_requires_refill(real_engine):
-    mk, data = real_engine
-    engine = mk(kv_paged=True)
-    with pytest.raises(ValueError, match="refill"):
-        list(engine.predict_stream(
-            iter([RouteRequest([data.queries[int(data.test_qids[0])]])]),
-            refill=False))
+    kv = st.as_dict()["kv_pages"]
+    assert kv["peak"] == st.pages_peak and "deferred_on_horizon" not in kv

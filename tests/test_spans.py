@@ -68,7 +68,7 @@ def stream_setup(tiny_params, world, retriever, library):
         return est, ScopeEngine.build(EngineConfig(
             estimator=est, retriever=retriever, library=library,
             models_meta={m: world.models[m] for m in data.models},
-            refill=True, segment_len=3, kv_paged=True, kv_page_size=8))
+            refill=True, segment_len=3, kv_page_size=8))
 
     # one query fills most of an 8-slot state; later ones queue and ride
     # in as refills; empty requests advance the live state
