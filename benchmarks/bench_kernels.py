@@ -61,14 +61,15 @@ def run(bundle=None) -> List[Tuple[str, float, str]]:
     q = jax.random.normal(ks[0], (b, hq, 1, d))
     kc = jax.random.normal(ks[1], (b, hkv, S, d))
     vc = jax.random.normal(ks[2], (b, hkv, S, d))
-    kp = jnp.zeros((n_pages, hkv, page, d))
-    vp = jnp.zeros((n_pages, hkv, page, d))
+    kp = jnp.zeros((n_pages, page, hkv, d))       # token-major pages
+    vp = jnp.zeros((n_pages, page, hkv, d))
     table = np.full((b, S // page), n_pages - 1, np.int32)
     nxt = 0
     for i in range(b):                           # scatter rows into pages
         for j in range(S // page):
-            kp = kp.at[nxt].set(kc[i, :, j * page:(j + 1) * page])
-            vp = vp.at[nxt].set(vc[i, :, j * page:(j + 1) * page])
+            sl = slice(j * page, (j + 1) * page)
+            kp = kp.at[nxt].set(kc[i, :, sl].swapaxes(0, 1))
+            vp = vp.at[nxt].set(vc[i, :, sl].swapaxes(0, 1))
             table[i, j] = nxt
             nxt += 1
     lens = jnp.full((b,), S, jnp.int32)

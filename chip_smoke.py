@@ -342,11 +342,12 @@ def phase_kernels(seed, out):
     dq = normal(3, (b, hq, 1, d))
     dk, dv = normal(4, (b, hkv, S, d)), normal(5, (b, hkv, S, d))
     dlen = jax.random.randint(ks[6], (b,), 1, S + 1)
-    # paged decode attention: shuffled page table, trash page last
+    # paged decode attention: token-major pages, shuffled page table,
+    # trash page last
     n_pages = b * n_w + 1
     table = jax.random.permutation(ks[7], b * n_w).reshape(b, n_w)
-    pk = normal(8, (n_pages, hkv, PAGE, d))
-    pv = normal(9, (n_pages, hkv, PAGE, d))
+    pk = normal(8, (n_pages, PAGE, hkv, d))
+    pv = normal(9, (n_pages, PAGE, hkv, d))
     plen = jax.random.randint(ks[10], (b,), 1, kv_cap + 1)
     # SSD: Mamba2 heads (p 64, state 128), two 128-token chunks
     sx = normal(11, (1, 256, 8, 64), jnp.float32)

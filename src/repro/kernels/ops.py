@@ -230,10 +230,11 @@ def _paged_decode_attention_xla(q, k_pages, v_pages, cache_len, page_table,
                                 softcap: float = 0.0,
                                 scale: Optional[float] = None,
                                 layer=None) -> jax.Array:
-    """XLA paged path: gather each row's pages into a dense per-row view,
-    slice to ``kv_cap``, then run the exact dense masked-softmax above.
-    With ``layer`` the gather indexes the stacked pages as
-    ``pages[layer, table]``, so no per-layer slice of them is made.
+    """XLA paged path: gather each row's token-major pages into a dense
+    per-row view, slice to ``kv_cap``, then run the exact dense
+    masked-softmax above.  With ``layer`` the gather indexes the stacked
+    pages as ``pages[layer, table]``, so no per-layer slice of them is
+    made.
 
     Every valid cache position holds the same value as the dense layout
     (the scatter wrote it there) and every position past ``cache_len``
@@ -243,17 +244,16 @@ def _paged_decode_attention_xla(q, k_pages, v_pages, cache_len, page_table,
     """
     if layer is None:
         k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
-    b, hq, _, d = q.shape
-    hkv = k_pages.shape[2]
-    n_w = page_table.shape[1]
-    kd = k_pages[layer, page_table]             # (b, W, hkv, page, hd)
-    vd = v_pages[layer, page_table]
-    kd = kd.transpose(0, 2, 1, 3, 4).reshape(
-        b, hkv, n_w * page_size, d)[:, :, :kv_cap]
-    vd = vd.transpose(0, 2, 1, 3, 4).reshape(
-        b, hkv, n_w * page_size, -1)[:, :, :kv_cap]
-    return decode_attention(q, kd, vd, cache_len, softcap=softcap,
-                            scale=scale, impl="xla")
+    b = q.shape[0]
+    width = page_table.shape[1] * page_size
+
+    def view(pages):                # (b, hkv, kv_cap, hd)
+        rows = pages[layer, page_table]           # (b, W, page, hkv, hd)
+        return rows.reshape(b, width, *rows.shape[3:])[:, :kv_cap].transpose(
+            0, 2, 1, 3)
+
+    return decode_attention(q, view(k_pages), view(v_pages), cache_len,
+                            softcap=softcap, scale=scale, impl="xla")
 
 
 def _paged_decode_attention_pallas(q, k_pages, v_pages, cache_len,
@@ -282,12 +282,12 @@ def paged_decode_attention(q, k_pages, v_pages, cache_len, page_table, *,
                            layer=None) -> jax.Array:
     """Single-token attention against a block-paged cache.
 
-    q: (b, hq, 1, d); k_pages/v_pages: (n_pages, hkv, page_size, d)
-    physical page storage (the last page is the trash page), or with
-    ``layer`` (an int32 scalar) every layer's storage stacked (L, n_pages,
-    hkv, page_size, d), read at that layer in place; page_table: (b, W)
-    int32; cache_len: scalar or (b,) valid lengths INCLUDING the current
-    token.
+    q: (b, hq, 1, d); k_pages/v_pages: (n_pages, page_size, hkv, d)
+    token-major physical page storage (the last page is the trash page),
+    or with ``layer`` (an int32 scalar) every layer's storage stacked (L,
+    n_pages, page_size, hkv, d), read at that layer in place; page_table:
+    (b, W) int32; cache_len: scalar or (b,) valid lengths INCLUDING the
+    current token.
     """
     return KernelTypeMapping[kernel](
         q, k_pages, v_pages, cache_len, page_table, page_size=page_size,

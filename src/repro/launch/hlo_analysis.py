@@ -123,6 +123,29 @@ def ops_shaped_like(text: str, ops, shapes) -> List[str]:
                                      for _, d in _shapes_in(ins.type_str))]
 
 
+def scatter_update_counts(text: str, shapes) -> List[int]:
+    """How many updates (index tuples) each ``scatter`` makes, fused
+    computations included, whose result has one of ``shapes``: its index
+    operand's element count over the index vector's length."""
+    want = {tuple(s) for s in shapes}
+    comps, _ = parse_hlo(text)
+    counts = []
+    for instrs in comps.values():
+        types = {ins.name: ins.type_str for ins in instrs}
+        for ins in instrs:
+            if ins.op != "scatter" or not any(
+                    tuple(d) in want for _, d in _shapes_in(ins.type_str)):
+                continue
+            operands = re.findall(r"%([\w.\-]+)", ins.rest.split(")")[0])
+            dims = _shapes_in(types[operands[1]])[0][1]
+            ivd = int(re.search(r"index_vector_dim=(\d+)", ins.rest).group(1))
+            n = 1
+            for i, d in enumerate(dims):
+                n *= d if i != ivd else 1
+            counts.append(n)
+    return counts
+
+
 def analyze(text: str) -> Dict:
     comps, entry = parse_hlo(text)
 

@@ -143,19 +143,21 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
                      ) -> Tuple[jax.Array, Dict]:
     """Single-token GQA decode against a block-paged cache.
 
-    cache["k"/"v"]: (L, n_pages, hkv, page_size, hd), every layer's
-    physical pages shared by the whole batch, of which layer ``layer`` is
-    this one's; ``table``: (b, W) int32 page table; ``spec``: a
-    ``PagedSpec`` (static page_size / kv_cap / kernel).  The new KV
-    scatters into slot ``pos % page_size`` of physical page
-    ``table[row, pos // page_size]`` of the layer, and attention reads the
-    layer's pages through the table with the layer index folded in.  The
-    logical page index is clamped to the table width: live rows never
-    pass ``kv_cap`` (the sampler guards each segment), so the clamp only
-    fires for retired/done rows whose table points at the trash page —
-    their PAD writes collide there harmlessly, which is also why the
-    scatter must NOT claim unique indices.  No ring-buffer mode: paged
-    layers are full-window only (``kv_pool.check_paged_support``).
+    cache["k"/"v"]: (L, n_pages, page_size, hkv, hd), token-major: every
+    layer's physical pages shared by the whole batch, of which layer
+    ``layer`` is this one's, each token slot holding its whole (hkv, hd)
+    slab; ``table``: (b, W) int32 page table; ``spec``: a ``PagedSpec``
+    (static page_size / kv_cap / kernel).  The new K and V each scatter
+    one (hkv, hd) slab a row (the ``kv_write`` scope) into slot ``pos %
+    page_size`` of physical page ``table[row, pos // page_size]`` of the
+    layer, and attention reads the layer's pages through the table with
+    the layer index folded in.  The logical page index is clamped to the
+    table width: live rows never pass ``kv_cap`` (the sampler guards each
+    segment), so the clamp only fires for retired/done rows whose table
+    points at the trash page — their PAD writes collide there harmlessly,
+    which is also why the scatter must NOT claim unique indices.  No
+    ring-buffer mode: paged layers are full-window only
+    (``kv_pool.check_paged_support``).
     """
     b = x.shape[0]
     hd = cfg.resolved_head_dim
@@ -164,9 +166,11 @@ def gqa_decode_paged(p, cfg: ModelConfig, x, cos, sin, cache: Dict, pos,
     lp = jnp.minimum(pos // spec.page_size, table.shape[1] - 1)
     pid = table[jnp.arange(b), lp]                       # (b,)
     slot = pos % spec.page_size
-    at = (layer, pid[:, None], _heads(cfg), slot[:, None])
-    kc = cache["k"].at[at].set(k[:, 0].astype(cache["k"].dtype))
-    vc = cache["v"].at[at].set(v[:, 0].astype(cache["v"].dtype))
+    with jax.named_scope("kv_write"):
+        kc = cache["k"].at[layer, pid, slot].set(
+            k[:, 0].astype(cache["k"].dtype))
+        vc = cache["v"].at[layer, pid, slot].set(
+            v[:, 0].astype(cache["v"].dtype))
     out = ops.paged_decode_attention(
         q.transpose(0, 2, 1, 3), kc, vc, pos + 1, table,
         page_size=spec.page_size, kv_cap=spec.kv_cap,

@@ -13,13 +13,15 @@ checks free pages.  ``worst_case_pool`` sizes a pool for a slot batch.
 Layout per attention layer-stack cache leaf:
 
   dense  k/v    : (count, b, hkv, S, hd)              S = max_len slots
-  paged  k/v    : (count, n_pages + 1, hkv, page, hd) physical pages
+  paged  k/v    : (count, n_pages + 1, page, hkv, hd) physical pages
   dense  latent : (count, b, S, r)                    MLA c_kv / k_rope
   paged  latent : (count, n_pages + 1, page, r)       r = kv_lora_rank or
                                                       qk_rope_head_dim
 
-GQA K/V pages and MLA latent pages live side by side, each layer stack in
-its own layout, under one page id space.
+Pages are token-major: a token slot holds its whole (hkv, hd) K or V slab
+(or its latent row), so a decode step writes one slab a row, not one row
+per KV head.  GQA K/V pages and MLA latent pages live side by side, each
+layer stack in its own layout, under one page id space.
 
 A *page id* spans **all** layers: allocating page p grants the row
 ``page_size`` token slots in every layer's storage at physical index p.

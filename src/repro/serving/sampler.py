@@ -271,19 +271,20 @@ def _paged_latent_scatter(leaf, storage, page_ids, page_size: int):
 
 def _paged_storage(leaf, n_pages_total: int, page_size: int):
     """Zeroed page storage for a prefill cache leaf: K/V (count, b, hkv,
-    L, hd) -> (count, n_pages_total, hkv, page_size, hd); an MLA latent
-    (count, b, L, r) -> (count, n_pages_total, page_size, r)."""
+    L, hd) -> token-major (count, n_pages_total, page_size, hkv, hd); an
+    MLA latent (count, b, L, r) -> (count, n_pages_total, page_size, r)."""
     if leaf.ndim == 4:
         count, _, _, r = leaf.shape
         return jnp.zeros((count, n_pages_total, page_size, r), leaf.dtype)
     count, _, hkv, _, hd = leaf.shape
-    return jnp.zeros((count, n_pages_total, hkv, page_size, hd), leaf.dtype)
+    return jnp.zeros((count, n_pages_total, page_size, hkv, hd), leaf.dtype)
 
 
 def _paged_leaf_scatter(leaf, storage, page_ids, page_size: int):
-    """Scatter a dense prefill leaf (count, b, hkv, L, hd) into paged
-    storage (count, n_pages + 1, hkv, page_size, hd) at the flattened
-    (b * ceil(L / page_size),) physical destinations ``page_ids``.
+    """Scatter a dense prefill leaf (count, b, hkv, L, hd) into token-major
+    paged storage (count, n_pages + 1, page_size, hkv, hd) at the
+    flattened (b * ceil(L / page_size),) physical destinations
+    ``page_ids``: only the b prefilled rows are brought token-major.
 
     Pad/filler blocks all target the trash page; their writes collide
     there in nondeterministic order, which is unobservable — trash reads
@@ -296,8 +297,8 @@ def _paged_leaf_scatter(leaf, storage, page_ids, page_size: int):
     if pad:
         leaf = jnp.pad(leaf, [(0, 0), (0, 0), (0, 0), (0, pad), (0, 0)])
     blocks = leaf.reshape(count, b, hkv, npg, page_size, hd)
-    blocks = blocks.transpose(0, 1, 3, 2, 4, 5).reshape(
-        count, b * npg, hkv, page_size, hd)
+    blocks = blocks.transpose(0, 1, 3, 4, 2, 5).reshape(
+        count, b * npg, page_size, hkv, hd)
     return storage.at[_layers(count), page_ids].set(
         blocks.astype(storage.dtype))
 
@@ -330,9 +331,9 @@ def _paged_prefill(params, cfg: ModelConfig, tokens, n_pages_total: int,
 
     ``page_ids`` (b * npg,) maps each row's prompt page blocks to the
     physical pages its table owns (trash for inactive rows / pad blocks).
-    Storage is (count, n_pages_total, hkv, page_size, hd) per leaf with
-    the trash page at index n_pages_total - 1.  Returns the rows' last
-    valid logits (b, V) f32, as ``_prefill``.
+    Storage is ``_paged_storage``'s per leaf, with the trash page at
+    index n_pages_total - 1.  Returns the rows' last valid logits (b, V)
+    f32, as ``_prefill``.
     """
     COMPILE_COUNTS["paged_prefill"] += 1    # traced once per compilation
 

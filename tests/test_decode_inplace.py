@@ -1,4 +1,5 @@
-"""The paged decode executables update the page pool in place.
+"""The paged decode executables update the page pool in place, and a
+refill's prefill lands in the token-major pages where decode reads it.
 
 The layer scan carries the stacked pool and each layer writes its new
 token into its own index of it, and each launch donates the pool it takes:
@@ -177,3 +178,45 @@ def test_the_stream_never_reads_a_donated_pool(params, world, retriever,
         len(queries) * len(data.models)
     assert len(given) > 2
     assert all(x.is_deleted() for c in given for x in jax.tree.leaves(c))
+
+
+def test_prefill_pages_hold_the_dense_prefill(params):
+    """A refill's page scatter (``_scatter_prefill_caches``, the fused
+    refill's own) puts each admitted row's dense prefill K and V at token
+    slot ``t % PAGE`` of its page ``table[row, t // PAGE]``, the whole
+    (hkv, hd) slab; the bucket's filler row writes only the trash page and
+    no other page is touched."""
+    cfg, p = GQA, params["gqa"]
+    prompts, lens = _prompts(cfg, 4)
+    st = sampler.open_state(p, cfg, np.zeros((B, WIDTH), np.int32),
+                            max_new_tokens=BUDGET, kv_pool=_pool())
+    pg = st.paged
+    admitted = [0, 2, 3]
+    for row in admitted:
+        pg.admit_row(row, int(lens[row]))
+    rows = sampler.bucket_rows(admitted, B)
+    assert list(rows) == admitted + [B]             # one filler entry
+    npg = -(-WIDTH // PAGE)
+    ids = pg.prompt_page_ids(rows, npg).reshape(-1)
+    take = np.minimum(rows, B - 1)
+    _, dense = M.prefill(p, cfg, {"tokens": jnp.asarray(prompts[take])},
+                         jnp.asarray(lens[take]))
+    n_total = pg.pool.n_pages + 1
+    pages = sampler._scatter_prefill_caches(
+        dense, lambda path, leaf: sampler._paged_storage(leaf, n_total, PAGE),
+        jnp.asarray(ids), PAGE)
+    owned = {int(pg.table[r, w]) for r in admitted
+             for w in range(-(-int(lens[r]) // PAGE))}
+    untouched = sorted(set(range(pg.pool.n_pages)) - owned)
+    pairs = list(zip(jax.tree.leaves(dense), jax.tree.leaves(pages)))
+    assert len(pairs) == 2                      # K and V of the one stack
+    for leaf, paged in pairs:
+        leaf, paged = np.asarray(leaf), np.asarray(paged)
+        assert paged.shape == (cfg.num_layers, n_total, PAGE,
+                               cfg.num_kv_heads, cfg.resolved_head_dim)
+        for i, row in enumerate(admitted):
+            for t in range(int(lens[row])):
+                np.testing.assert_array_equal(
+                    paged[:, pg.table[row, t // PAGE], t % PAGE],
+                    leaf[:, i, :, t])
+        assert not paged[:, untouched].any()

@@ -205,9 +205,9 @@ def test_decode_attention_pallas_per_batch_lengths():
 # Pallas paged kernel vs both (interpret tolerance)
 # ---------------------------------------------------------------------------
 def _paged_layout(key, b, hkv, S, d, page_size, n_pages, perm_seed=0):
-    """A dense (b, hkv, S, d) cache scattered into a (n_pages+1, ...) page
-    store under a deliberately permuted page table — physical order must
-    not matter."""
+    """A dense (b, hkv, S, d) cache scattered into a token-major
+    (n_pages+1, page_size, hkv, d) page store under a deliberately
+    permuted page table — physical order must not matter."""
     kk, kv = jax.random.split(key)
     kc = jax.random.normal(kk, (b, hkv, S, d)) * 0.5
     vc = jax.random.normal(kv, (b, hkv, S, d)) * 0.5
@@ -215,13 +215,13 @@ def _paged_layout(key, b, hkv, S, d, page_size, n_pages, perm_seed=0):
     assert b * n_w <= n_pages
     rng = np.random.default_rng(perm_seed)
     phys = rng.permutation(n_pages)[: b * n_w].reshape(b, n_w)
-    k_pages = jnp.zeros((n_pages + 1, hkv, page_size, d))
-    v_pages = jnp.zeros((n_pages + 1, hkv, page_size, d))
+    k_pages = jnp.zeros((n_pages + 1, page_size, hkv, d))
+    v_pages = jnp.zeros((n_pages + 1, page_size, hkv, d))
     for i in range(b):
         for w in range(n_w):
             sl = slice(w * page_size, (w + 1) * page_size)
-            k_pages = k_pages.at[phys[i, w]].set(kc[i, :, sl])
-            v_pages = v_pages.at[phys[i, w]].set(vc[i, :, sl])
+            k_pages = k_pages.at[phys[i, w]].set(kc[i, :, sl].swapaxes(0, 1))
+            v_pages = v_pages.at[phys[i, w]].set(vc[i, :, sl].swapaxes(0, 1))
     return kc, vc, k_pages, v_pages, jnp.asarray(phys, jnp.int32)
 
 
@@ -230,6 +230,8 @@ PAGED_CASES = [
     (2, 4, 2, 128, 32, 16, (100, 128), 0.0),
     (1, 8, 4, 64, 64, 8, (40,), 50.0),
     (3, 4, 2, 96, 32, 32, (10, 77, 96), 0.0),
+    # qwen3-4b's geometry: a page holds 16 tokens of 8 heads x 128, g 4
+    (2, 32, 8, 80, 128, 16, (61, 80), 0.0),
 ]
 
 
@@ -271,7 +273,7 @@ def test_paged_decode_pallas_vs_xla(case):
 @pytest.mark.parametrize("kernel", ["XLA", "PALLAS"])
 def test_paged_decode_reads_one_layer_of_stacked_pages(kernel):
     """Given a layer index, each paged path reads that layer of the stacked
-    storage (L, n_pages + 1, hkv, page, d) in place, exactly as it reads
+    storage (L, n_pages + 1, page, hkv, d) in place, exactly as it reads
     that layer's storage alone."""
     from repro.kernels.decode_attention import KernelType
     b, hq, hkv, S, d, page, lens, cap = PAGED_CASES[0]
